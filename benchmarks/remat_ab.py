@@ -60,7 +60,7 @@ def main():
             # keep every dW live (one element each — a DCE'd backward matmul
             # would otherwise make remat look free); params must flow in as
             # ARGUMENTS (closing over them would bake 1.7GB of literals into
-            # the HLO and stall the remote compiler)
+            # the HLO and stall the compiler)
             probe = sum(gg.ravel()[0].astype(jnp.float32) for gg in g)
             ps = [p_ + 0.0 * gg.astype(p_.dtype) for p_, gg in zip(ps, g)]
             return ps, l.astype(jnp.float32) + 0.0 * probe

@@ -56,7 +56,6 @@ def train_step_comm(mesh_axes: Dict[str, int], *, batch_per_shard: int = 2,
     import numpy as np
     from jax import lax
 
-    from ...framework.jax_compat import shard_map
     from ...static.comm.mesh import abstract_mesh, mesh_spec
     from ..utils.moe_utils import global_gather, global_scatter
 
@@ -135,10 +134,10 @@ def train_step_comm(mesh_axes: Dict[str, int], *, batch_per_shard: int = 2,
     w1_spec = mesh_spec(axes, "fsdp", "tp")
     w2_spec = mesh_spec(axes, "tp", "fsdp")
     act_spec = mesh_spec(axes, data_axes or None, "sep", None)
-    fn = shard_map(step, mesh=mesh,
-                   in_specs=(w1_spec, w2_spec, act_spec, act_spec),
-                   out_specs=(w1_spec, w2_spec, mesh_spec(axes)),
-                   check_vma=False)
+    fn = jax.shard_map(step, mesh=mesh,
+                       in_specs=(w1_spec, w2_spec, act_spec, act_spec),
+                       out_specs=(w1_spec, w2_spec, mesh_spec(axes)),
+                       check_vma=False)
     sd = jax.ShapeDtypeStruct
     structs = (sd((D, H), np_dtype), sd((H, D), np_dtype),
                sd((B, S, D), np_dtype), sd((B, S, D), np_dtype))
@@ -158,7 +157,6 @@ def moe_combine_comm(ep: int, *, tokens_per_rank: int = 16,
     from jax import nn as jnn
     from jax.sharding import PartitionSpec as P
 
-    from ...framework.jax_compat import shard_map
     from ...static.comm.mesh import abstract_mesh
     from ..utils.moe_utils import global_gather, global_scatter
 
@@ -174,9 +172,9 @@ def moe_combine_comm(ep: int, *, tokens_per_rank: int = 16,
         return global_gather(h, axis_name="ep")  # tokens -> home ranks
 
     mesh = abstract_mesh({"ep": ep})
-    fn = shard_map(combine, mesh=mesh,
-                   in_specs=(P("ep", None), P(None, None)),
-                   out_specs=P("ep", None), check_vma=False)
+    fn = jax.shard_map(combine, mesh=mesh,
+                       in_specs=(P("ep", None), P(None, None)),
+                       out_specs=P("ep", None), check_vma=False)
     sd = jax.ShapeDtypeStruct
     structs = (sd((ep * tokens_per_rank, D), np_dtype),
                sd((D, D), np_dtype))

@@ -247,6 +247,12 @@ class Engine:
                              for a, n in zip(self.params, self._param_names)]
             self.opt_state, self._opt_state_shardings = self._init_opt_state()
         self.step_count = jnp.zeros((), jnp.int32)
+        if self.mesh is not None:
+            # placed like the step's own output: an unplaced scalar has a
+            # different type from the mesh-replicated one that comes back,
+            # and the second step would retrace and recompile
+            self.step_count = jax.device_put(
+                self.step_count, NamedSharding(self.mesh, P()))
         self._jit_step = None
         self._jit_loss = None
 

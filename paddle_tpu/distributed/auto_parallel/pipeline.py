@@ -621,9 +621,7 @@ def pipeline_call(
         pipeline = gpipe_schedule(stage_fn, n_stages, axis_name, with_aux=with_aux)
     n_params = len(stacked_params)
     out_specs = (P(), P()) if with_aux else P()
-    from ...framework.jax_compat import shard_map
-
-    smapped = shard_map(
+    smapped = jax.shard_map(
         pipeline,
         mesh=mesh,
         in_specs=(tuple(P(axis_name) for _ in range(n_params)), P())

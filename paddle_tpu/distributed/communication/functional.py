@@ -96,10 +96,8 @@ def _mp_all_reduce(x, op, ranks):
                 r = jnp.exp(lax.psum(jnp.log(v), "r"))
             return r[None]
 
-        from ...framework.jax_compat import shard_map as _shard_map
-
-        fn = jax.jit(_shard_map(body, mesh=mesh, in_specs=P("r"),
-                                out_specs=P("r")))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("r"),
+                                   out_specs=P("r")))
         entry = (fn, mesh, by_proc[jax.process_index()], len(devs))
         _mp_reduce_cache[key] = entry
     fn, mesh, mine, n = entry

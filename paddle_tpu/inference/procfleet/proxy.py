@@ -343,6 +343,13 @@ class ProcReplica:
         if hello.mtype != "HELLO":
             self.kill()
             self._reap()
+            if hello.mtype == "ERROR":
+                # the worker could not come up and said why (no backend,
+                # a factory that raised) — the cause, not the symptom
+                raise WorkerDead(
+                    f"PT-PROC-002: replica {idx} worker failed before "
+                    f"HELLO ({hello.payload['etype']}: "
+                    f"{hello.payload['msg']})")
             raise WorkerDead(
                 f"PT-PROC-002: replica {idx} opened with {hello.mtype}, "
                 "not HELLO")

@@ -344,7 +344,6 @@ def _attention(q, k, v, config, attn_bias=None):
             from ...ops.ring_attention import ring_attention
 
             return ring_attention(q, k, v, mesh, axis_name="sep", causal=True)
-        from ...framework.jax_compat import shard_map
         from ...distributed.auto_parallel.logical_sharding import logical_to_spec
 
         tp = mesh.shape.get("tp", 1)
@@ -352,7 +351,7 @@ def _attention(q, k, v, config, attn_bias=None):
         if q.shape[0] % dbatch == 0 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0:
             qspec = logical_to_spec(("batch", None, "heads", None), mesh)
             kspec = logical_to_spec(("batch", None, "kv_heads", None), mesh)
-            f = shard_map(
+            f = jax.shard_map(
                 lambda a, b, c: fa(a, b, c, causal=True),
                 mesh=mesh,
                 in_specs=(qspec, kspec, kspec),

@@ -47,12 +47,9 @@ def _xla_attention(q, k, v, bias=None, causal=False, scale=None, dropout=0.0, dr
 def _attention_impl(q, k, v, bias, causal, scale, dropout, dropout_key):
     use_pallas = flags.get_flag("use_pallas_attention") and bias is None and dropout == 0.0
     if use_pallas:
-        try:
-            from ...ops.flash_attention import flash_attention_fwd
+        from ...ops.flash_attention import flash_attention_fwd
 
-            return flash_attention_fwd(q, k, v, causal=causal, scale=scale)
-        except Exception:
-            pass
+        return flash_attention_fwd(q, k, v, causal=causal, scale=scale)
     return _xla_attention(q, k, v, bias, causal, scale, dropout, dropout_key)
 
 

@@ -197,39 +197,19 @@ def _pgmm_bwd(tile_m, interpret, res, g):
 pgmm.defvjp(_pgmm_fwd, _pgmm_bwd)
 
 
-_GMM_FALLBACK_WARNED = [False]
-
-# what a missing/unsupported megablox path legitimately raises: the import
-# itself, shape/dtype validation, or an unimplemented lowering. Anything
-# else (a genuine kernel bug, a TPU runtime error) must propagate — a bare
-# ``except Exception`` was silently converting those into the slower
-# ragged_dot path (ADVICE low).
-_GMM_FALLBACK_ERRORS = (ImportError, AttributeError, NotImplementedError,
-                        TypeError, ValueError)
-
-
 def grouped_dot(x, w, group_sizes):
     """Grouped matmul over rows sorted by group (group_sizes [E] row
     counts): jax's megablox ``gmm`` Pallas kernel on TPU (the tuned
     megablocks-class kernel — weight-stationary tiling, no padding),
     ``lax.ragged_dot`` elsewhere. Both differentiate w.r.t. x and w."""
     if jax.default_backend() == "tpu":
-        try:
-            from jax.experimental.pallas.ops.tpu.megablox import gmm as _mb
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-            k, n = w.shape[1], w.shape[2]
-            tiling = (512, _fit_tile(512, k), _fit_tile(512, n))
-            return _mb.gmm(x, w, group_sizes,
-                           preferred_element_type=x.dtype, tiling=tiling)
-        except _GMM_FALLBACK_ERRORS as e:
-            if not _GMM_FALLBACK_WARNED[0]:
-                _GMM_FALLBACK_WARNED[0] = True
-                import warnings
-
-                warnings.warn(
-                    f"megablox gmm unavailable, falling back to "
-                    f"lax.ragged_dot: {type(e).__name__}: {e}",
-                    RuntimeWarning, stacklevel=2)
+        k, n = w.shape[1], w.shape[2]
+        tiling = (512, _fit_tile(512, k), _fit_tile(512, n))
+        # preferred_element_type and tiling are positional: they are the
+        # custom_vjp's non-differentiable arguments
+        return gmm(x, w, group_sizes, x.dtype, tiling)
     return jax.lax.ragged_dot(x, w, group_sizes)
 
 

@@ -32,8 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..framework.jax_compat import shard_map
-
 NEG_INF = -1e30
 
 
@@ -252,13 +250,13 @@ def ring_attention(q, k, v, mesh, axis_name: str = "sep", causal: bool = True,
         perm = jnp.asarray(zigzag_perm(s_global, n))
         inv = jnp.asarray(zigzag_inverse(s_global, n))
         q, k, v = q[:, perm], k[:, perm], v[:, perm]
-        f = shard_map(
+        f = jax.shard_map(
             lambda a, b, c: _ring_body_zigzag(a, b, c, axis_name,
                                               float(scale), n),
             mesh=mesh, in_specs=(qspec, kspec, kspec), out_specs=qspec,
             check_vma=False)
         return f(q, k, v)[:, inv]
-    f = shard_map(
+    f = jax.shard_map(
         lambda a, b, c: _ring_body_contiguous(a, b, c, axis_name, causal,
                                               float(scale), n),
         mesh=mesh, in_specs=(qspec, kspec, kspec), out_specs=qspec,

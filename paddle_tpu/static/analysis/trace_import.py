@@ -19,6 +19,7 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax.extend.core import Literal
 
 from ...core.static_graph import Operation, Program, Variable
 from ...core.tensor import Tensor
@@ -72,7 +73,7 @@ def trace_to_program(fn, *input_structs, input_names: Optional[Sequence[str]] = 
     for eqn in jaxpr.eqns:
         args = []
         for iv in eqn.invars:
-            if isinstance(iv, jax.core.Literal):
+            if isinstance(iv, Literal):
                 args.append(np.asarray(iv.val) if hasattr(iv.val, "shape")
                             else iv.val)
             else:
@@ -84,7 +85,11 @@ def trace_to_program(fn, *input_structs, input_names: Optional[Sequence[str]] = 
         # primitive eqns that differ only in params (e.g. two reshapes)
         def make_kernel(prim, params):
             def kernel(*xs):
-                out = prim.bind(*xs, **params)
+                # the rebind eval_jaxpr does: call-like primitives
+                # (custom_vjp_call, jit) take their inner jaxprs as
+                # leading callables, not as keyword params
+                subfuns, bind_params = prim.get_bind_params(params)
+                out = prim.bind(*subfuns, *xs, **bind_params)
                 return tuple(out) if prim.multiple_results else out
             # random_* eqns replay a PRNG key BAKED into the jaxpr — they are
             # deterministic, so the trace linter must not flag them unseeded
@@ -107,7 +112,7 @@ def trace_to_program(fn, *input_structs, input_names: Optional[Sequence[str]] = 
 
     outs = []
     for ov in jaxpr.outvars:
-        if isinstance(ov, jax.core.Literal):
+        if isinstance(ov, Literal):
             continue
         o = env.get(ov)
         if isinstance(o, Variable):

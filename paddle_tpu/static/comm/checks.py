@@ -73,10 +73,10 @@ def check_replication(program_or_jaxpr, name: str = "program",
                       min_bytes: int = _REPLICATION_MIN_BYTES
                       ) -> List[Diagnostic]:
     """PT-COMM-001: for each shard_map over a >1-device mesh whose
-    ``in_names`` shard at least one operand, flag every operand of
-    ``min_bytes`` or more entering with NO sharded dim (an empty names
-    dict, or only size-1 axes) — full replication that is almost always
-    an annotation accident on a mesh that shards its consumers."""
+    ``in_specs`` shard at least one operand, flag every operand of
+    ``min_bytes`` or more entering with NO sharded dim (an empty spec,
+    or only size-1 axes) — full replication that is almost always an
+    annotation accident on a mesh that shards its consumers."""
     findings: List[Diagnostic] = []
     for eqn, scope in _shard_map_eqns(closed_jaxpr_of(program_or_jaxpr)):
         sizes = mesh_axis_sizes(eqn.params.get("mesh"))
@@ -85,16 +85,18 @@ def check_replication(program_or_jaxpr, name: str = "program",
             world *= max(int(v), 1)
         if world <= 1:
             continue
-        in_names = eqn.params.get("in_names") or ()
+        in_specs = eqn.params["in_specs"]
 
-        def effective(names_dict):
-            return any(sizes.get(str(a), 1) > 1
-                       for axs in (names_dict or {}).values() for a in axs)
-        sharded = [i for i, nm in enumerate(in_names) if effective(nm)]
-        if not sharded:
+        def effective(spec):
+            # a PartitionSpec entry is None, an axis name or a tuple of them
+            return any(sizes.get(str(a), 1) > 1 for entry in spec
+                       if entry is not None
+                       for a in (entry if isinstance(entry, tuple)
+                                 else (entry,)))
+        if not any(effective(sp) for sp in in_specs):
             continue
-        for i, nm in enumerate(in_names):
-            if effective(nm) or i >= len(eqn.invars):
+        for i, sp in enumerate(in_specs):
+            if effective(sp):
                 continue
             shape, dtype = _aval_of(eqn.invars[i])
             nb = _nbytes(shape, dtype)

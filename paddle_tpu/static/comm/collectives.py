@@ -22,10 +22,11 @@ all_to_all          ``(n-1)/n * b``                 keeps 1/n locally
 ppermute            ``b``                           one neighbour send
 ==================  ==============================  =====================
 
-``psum2`` (the check_rep rewrite's name for psum) is normalized to
-``psum`` so contracts do not depend on the ``check_vma`` flag;
-``pbroadcast2`` is a replication *marker* the rewrite inserts — zero
-wire bytes, not censused.
+``psum_invariant`` / ``all_gather_invariant`` (what ``check_vma=True``
+traces psum / a replicated-output all_gather as) are normalized to
+``psum`` / ``all_gather`` so contracts do not depend on the
+``check_vma`` flag; ``pvary`` is a varying-ness *marker* the check
+inserts — zero wire bytes, not censused.
 
 Loop-invariance (PT-COMM-002's input) is a taint walk: inside a scan
 body the carries and the per-step slices are "varying", the scan consts
@@ -47,19 +48,19 @@ __all__ = ["CollectiveInfo", "COLLECTIVE_PRIMS", "iter_collectives",
 
 #: jaxpr primitive names that move bytes between mesh participants
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmin", "pmax", "all_gather", "reduce_scatter",
-    "all_to_all", "ppermute",
+    "psum", "psum_invariant", "pmin", "pmax", "all_gather",
+    "all_gather_invariant", "reduce_scatter", "all_to_all", "ppermute",
 })
 
-#: normalization: the check_rep rewrite renames psum -> psum2
-_NORMALIZE = {"psum2": "psum"}
+#: normalization: check_vma=True traces the *_invariant spellings
+_NORMALIZE = {"psum_invariant": "psum", "all_gather_invariant": "all_gather"}
 
 
 @dataclass
 class CollectiveInfo:
     """One collective equation (possibly nested), censused."""
 
-    prim: str                     # normalized (psum2 -> psum)
+    prim: str                     # normalized (psum_invariant -> psum)
     raw_prim: str
     axes: Tuple[str, ...]         # mesh axes the collective spans
     group_size: int               # product of the named axes' sizes

@@ -23,10 +23,10 @@ def abstract_mesh(axes: Mapping[str, int]):
     the caller's spec names stay valid."""
     from jax.sharding import AbstractMesh
 
-    items = tuple((str(k), int(v)) for k, v in axes.items())
-    if not items:
+    if not axes:
         raise ValueError("abstract_mesh needs at least one axis")
-    return AbstractMesh(items)
+    return AbstractMesh(tuple(int(v) for v in axes.values()),
+                        tuple(str(k) for k in axes))
 
 
 def mesh_axis_sizes(mesh) -> Dict[str, int]:

@@ -3,7 +3,7 @@
 A traced hot-path program (``trace_to_program`` keeps the ClosedJaxpr on the
 imported Program as ``_closed_jaxpr``) is walked equation by equation,
 RECURSING into container primitives — ``scan`` bodies multiply by their
-trip count, ``pjit``/``remat``/``custom_*_call`` inline at 1x, ``while``
+trip count, ``jit``/``remat``/``custom_*_call`` inline at 1x, ``while``
 bodies count ONCE (trip count is data-dependent; the manifest records how
 many unknown-trip loops the estimate leaves out), ``cond`` counts every
 branch (a deliberate upper bound). Each equation yields an :class:`EqnInfo`
@@ -40,7 +40,7 @@ _CONTAINER_KEYS = {
     "shard_map": ("jaxpr",),
     "while": ("cond_jaxpr", "body_jaxpr"),
     "cond": ("branches",),
-    "pjit": ("jaxpr",),
+    "jit": ("jaxpr",),
     "xla_call": ("call_jaxpr",),
     "closed_call": ("call_jaxpr",),
     "core_call": ("call_jaxpr",),

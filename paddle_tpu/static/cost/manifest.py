@@ -3,7 +3,7 @@
 ``compute_manifest`` folds the walker stream (flops.py) into one JSON-able
 :class:`CostManifest`: FLOPs per op family, byte traffic + arithmetic
 intensity, a full dtype census, host-sync / scatter / gather / upcast
-counts, the donation audit (read from the traced ``pjit`` equation's
+counts, the donation audit (read from the traced ``jit`` equation's
 ``donated_invars`` — the actual donation the jitted callable declares, not
 a hand-maintained list), and, once :func:`scaling_verdict` has seen the
 same program at two slot widths, the slot-scaling law record.
@@ -98,15 +98,15 @@ class CostManifest:
 
 
 def _donation_audit(closed, carries: Dict[str, Tuple[int, int]]):
-    """Read the ACTUAL donation off the outermost ``pjit`` equation of a
+    """Read the ACTUAL donation off the outermost ``jit`` equation of a
     traced jitted callable. A carry is donated iff every flat invar in its
     range is marked in ``donated_invars``. Programs traced from a bare
-    function (no jit wrapper) have no pjit equation — nothing is donated."""
+    function (no jit wrapper) have no jit equation — nothing is donated."""
     donated_invars = None
     if closed is not None:
         jaxpr = getattr(closed, "jaxpr", closed)
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pjit":
+            if eqn.primitive.name == "jit":
                 donated_invars = eqn.params.get("donated_invars")
                 break
     names, donated, missing = [], [], []

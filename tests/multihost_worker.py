@@ -51,9 +51,7 @@ def main():
 
     @jax.jit
     def reduce_all(x):
-        from paddle_tpu.framework.jax_compat import shard_map
-
-        return shard_map(
+        return jax.shard_map(
             lambda s: jax.lax.psum(s, "dp"), mesh=mesh,
             in_specs=P("dp"), out_specs=P("dp"))(x)
 

@@ -13,7 +13,6 @@ tools/audit_collectives.py.
 import json
 import os
 import sys
-import types
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +21,6 @@ import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from paddle_tpu.framework import jax_compat
 from paddle_tpu.static.analysis import run_analysis, trace_to_program
 from paddle_tpu.static.comm import (CollectiveCommPass, CommManifest,
                                     CommPathSpec, abstract_mesh,
@@ -48,8 +46,8 @@ def _trace(fn, *structs, names=None):
 
 def _sharded(body, width=4, in_specs=None, out_specs=P(), axes=None):
     mesh = abstract_mesh(axes or {"x": width})
-    return jax_compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_vma=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -349,72 +347,6 @@ def test_moe_combine_comm_census():
                               name="moe", spec=CommPathSpec("moe", mesh=axes))
     assert m.collectives == {"all_to_all": 2}   # dispatch + combine
     assert m.per_axis["ep"]["eqns"] == 2
-
-
-# ---------------------------------------------------------------------------
-# jax_compat shard_map resolution (satellite: both orders by injection)
-# ---------------------------------------------------------------------------
-
-def test_resolve_shard_map_prefers_promoted_api():
-    sentinel = object()
-    fake_jax = types.SimpleNamespace(shard_map=sentinel)
-    fn, origin = jax_compat._resolve_shard_map(jax_module=fake_jax)
-    assert fn is sentinel and origin == "jax"   # used as-is, unwrapped
-
-
-def test_resolve_shard_map_falls_back_to_experimental_wrapped():
-    calls = {}
-
-    def legacy(f, mesh=None, in_specs=None, out_specs=None, **kw):
-        calls.update(kw, mesh=mesh)
-        return f
-
-    fake_jax = types.SimpleNamespace()          # no shard_map attribute
-
-    def fake_import(path):
-        assert path == "jax.experimental.shard_map"
-        return types.SimpleNamespace(shard_map=legacy)
-
-    fn, origin = jax_compat._resolve_shard_map(jax_module=fake_jax,
-                                               import_module=fake_import)
-    assert origin == "experimental"
-    mesh = abstract_mesh({"x": 2, "y": 2})
-    fn(lambda v: v, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-       check_vma=False)
-    # the wrapper translated the promoted kwarg names to the legacy ones
-    assert calls["check_rep"] is False and "check_vma" not in calls
-    assert calls["mesh"] is mesh
-
-
-def test_resolve_shard_map_neither_location_names_both():
-    def no_import(path):
-        raise ImportError(path)
-
-    with pytest.raises(ImportError, match="jax.shard_map"):
-        jax_compat._resolve_shard_map(jax_module=types.SimpleNamespace(),
-                                      import_module=no_import)
-
-
-def test_wrap_legacy_translates_axis_names_to_auto():
-    seen = {}
-
-    def legacy(f, mesh=None, in_specs=None, out_specs=None, **kw):
-        seen.update(kw)
-        return f
-
-    wrapped = jax_compat._wrap_legacy_shard_map(legacy)
-    mesh = abstract_mesh({"x": 2, "y": 2})
-    wrapped(lambda v: v, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-            axis_names={"x"})
-    # manual over {x} == automatic over the complement {y}
-    assert seen["auto"] == frozenset({"y"})
-
-
-def test_module_shard_map_resolved_and_usable():
-    """Whatever origin this jax picked, the module-level symbol traces."""
-    assert jax_compat._SHARD_MAP_ORIGIN in ("jax", "experimental")
-    prog = _census_prog(width=2)
-    assert compute_comm_manifest(prog).collective_eqns == 3
 
 
 # ---------------------------------------------------------------------------

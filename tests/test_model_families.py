@@ -143,6 +143,10 @@ class TestUNet:
         assert gnorm > 0
 
 
+@pytest.mark.slow   # two full UNet forward+backward compiles, 70-115 s on CPU
+#                     (it was a standing failure through PR 20, passes on jax
+#                     0.9, and alone then costs an eighth of the 870 s budget);
+#                     TestUNet.test_forward_shape_and_loss stays the fast pin
 def test_unet_bf16_matches_fp32():
     """bf16 params/activations (round 4): loss within bf16 tolerance of the
     fp32 model on identical weights, grads finite — the bench's SD-UNet

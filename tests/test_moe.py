@@ -186,10 +186,6 @@ class TestLlamaMoE:
             l = float(eng.step(ids_d, lbl_d))
         assert np.isfinite(l) and l < l0
 
-    @pytest.mark.skipif(
-        not hasattr(jax, "shard_map"),
-        reason="pp schedule needs jax>=0.5 shard_map manual-axis lowering "
-               "(old jaxlib: PartitionId unsupported under SPMD partitioning)")
     def test_moe_llama_pp_trains_with_aux(self):
         """MoE + pipeline parallelism: aux loss threads through the schedule."""
         from paddle_tpu.distributed.auto_parallel import Engine

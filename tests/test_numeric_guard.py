@@ -131,13 +131,18 @@ class TestGuardStep:
 
 class TestEngineGuard:
     def test_skip_preserves_params_and_moments_bit_identical(self):
+        # ONE fixed batch for every healthy step: its loss only falls, so
+        # the spike bit cannot fire. Fresh batches move the loss by ~20%
+        # against a 10% band after a 2-step warmup — whether step 4 then
+        # read healthy depended on the init's random stream (it stopped
+        # under jax 0.9's PRNG defaults: loss 1.91 vs ema 1.51, word 8).
         eng = _engine(GuardPolicy(action="skip_step", warmup_steps=2))
         for s in range(3):
-            eng.step(*_data_fn(s))
+            eng.step(*_data_fn(0))
         p0 = [np.asarray(a) for a in eng.params]
         m0 = [np.asarray(a) for a in eng.m]
         v0 = [np.asarray(a) for a in eng.v]
-        x, y = _data_fn(3)
+        x, y = _data_fn(0)
         x[0, 0] = np.nan                       # poisoned batch -> NaN grads
         eng.step(x, y)
         word = int(eng.last_health)
@@ -148,7 +153,7 @@ class TestEngineGuard:
         assert all(np.array_equal(a, np.asarray(b)) for a, b in zip(v0, eng.v))
         assert int(eng.step_count) == 4        # counter advances on a skip
         # and the next healthy step trains normally
-        loss = eng.step(*_data_fn(4))
+        loss = eng.step(*_data_fn(0))
         assert np.isfinite(float(loss)) and int(eng.last_health) == 0
 
     def test_warn_policy_applies_the_update(self):

@@ -16,15 +16,6 @@ from paddle_tpu.distributed.auto_parallel import Engine, axis_rules, make_mesh
 from paddle_tpu.distributed.auto_parallel.pipeline import pipeline_call
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
-# The pp schedules lower through shard_map with manual axis_index; the old
-# experimental shard_map (jax<0.5, no top-level jax.shard_map) hits
-# "UNIMPLEMENTED: PartitionId instruction is not supported for SPMD
-# partitioning" in this container's jaxlib when compiling them on CPU.
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="pipeline schedules need jax>=0.5 shard_map manual-axis lowering "
-           "(old jaxlib: PartitionId unsupported under SPMD partitioning)")
-
 
 def _toy_block_fn(params, x):
     (w,) = params

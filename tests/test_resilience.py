@@ -628,15 +628,23 @@ def _data_fn(step, b=8, d=8):
 class TestResilientTrainer:
     def test_resume_continues_training(self, tmp_path):
         build = _toy_builder()
-        t1 = ResilientTrainer(build, str(tmp_path), save_every=2)
-        out1 = t1.fit(_data_fn, 4)
-        t2 = ResilientTrainer(build, str(tmp_path), save_every=2)
+        ref = ResilientTrainer(build, str(tmp_path / "ref"),
+                               save_every=100).fit(_data_fn, 6)
+        t1 = ResilientTrainer(build, str(tmp_path / "job"), save_every=2)
+        t1.fit(_data_fn, 4)
+        t2 = ResilientTrainer(build, str(tmp_path / "job"), save_every=2)
         out2 = t2.fit(_data_fn, 6)
         assert t2.latest_step() == 6
         # steps 1-4 were not re-run: resume started at the recorded step
         assert sorted(out2["losses"]) == [5, 6]
-        # and the resumed step-5 loss continues the step-4 trajectory
-        assert out2["losses"][5] < out1["losses"][1]
+        # and the resumed steps continue the step-4 trajectory: they equal
+        # the uninterrupted run's. (This used to read "step-5 loss < step-1
+        # loss", but every step draws a fresh batch whose targets are noise,
+        # so which of two batches scores lower is the random stream's
+        # choice — it flipped under jax 0.9's PRNG defaults.)
+        for step in (5, 6):
+            np.testing.assert_allclose(out2["losses"][step],
+                                       ref["losses"][step], rtol=1e-6)
 
     def test_corrupt_latest_falls_back_to_older(self, tmp_path):
         build = _toy_builder()

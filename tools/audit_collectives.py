@@ -439,7 +439,6 @@ def _fixture(width=2, replicated=False, loop_regather=False,
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.framework.jax_compat import shard_map
     from paddle_tpu.static.analysis import trace_to_program
     from paddle_tpu.static.comm import CommPathSpec, abstract_mesh
 
@@ -470,9 +469,9 @@ def _fixture(width=2, replicated=False, loop_regather=False,
         return h.sum() + r[0, 0] * jnp.float32(0)
 
     mesh = abstract_mesh({"x": width})
-    fn = shard_map(step, mesh=mesh,
-                   in_specs=(P("x", None), P(None, None), P(None, None)),
-                   out_specs=P(), check_vma=False)
+    fn = jax.shard_map(step, mesh=mesh,
+                       in_specs=(P("x", None), P(None, None), P(None, None)),
+                       out_specs=P(), check_vma=False)
     prog = trace_to_program(
         fn, _spec((8 * width, 16), np.float32), _spec((8, 16), np.float32),
         _spec(r_shape, np.float32), input_names=["w", "x", "r"])
