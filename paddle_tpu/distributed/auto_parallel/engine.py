@@ -18,6 +18,7 @@ Buffers are donated so params/opt-state update in-place in HBM.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable, Optional, Union
 
@@ -27,7 +28,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...core.tensor import Tensor
 from ...framework import numeric_guard
+from ...framework.compile_cache import first_call
 from ...nn.layer.layers import Layer
+from ...observability.tracing import program_span
 from .logical_sharding import (
     DEFAULT_RULES,
     axis_rules,
@@ -394,6 +397,7 @@ class Engine:
             out = fn(input_ids, labels)
         return out._data if isinstance(out, Tensor) else out
 
+    @functools.partial(jax.named_call, name="pt.optimizer")
     def _adamw(self, params, m, v, grads, step, lr_scale=None):
         b1, b2, eps, wd = self.beta1, self.beta2, self.epsilon, self.weight_decay
         lr = self.lr(step) if callable(self.lr) else self.lr
@@ -419,7 +423,7 @@ class Engine:
         return new_p, new_m, new_v
 
     def _build_step(self):
-        def train_step(params, m, v, step, input_ids, labels):
+        def pt_train_step(params, m, v, step, input_ids, labels):
             step = step + 1
             loss, grads = jax.value_and_grad(self._pure_loss)(params, input_ids, labels)
             new_p, new_m, new_v = self._adamw(params, m, v, grads, step)
@@ -434,7 +438,7 @@ class Engine:
             kw["out_shardings"] = (sh, sh, sh, rep, rep)
         if self._donate:
             kw["donate_argnums"] = (0, 1, 2, 3)
-        return jax.jit(train_step, **kw)
+        return jax.jit(pt_train_step, **kw)
 
     def _build_guard_step(self):
         """Guarded train step: same fused fwd/bwd/clip/AdamW program plus a
@@ -449,8 +453,8 @@ class Engine:
         skip_mask = pol.skip_mask
         ng = numeric_guard
 
-        def train_step(params, m, v, step, gstate, input_ids, labels,
-                       inject, lr_scale):
+        def pt_train_step(params, m, v, step, gstate, input_ids, labels,
+                          inject, lr_scale):
             step = step + 1
 
             def lossf(ps):
@@ -485,7 +489,7 @@ class Engine:
             kw["out_shardings"] = (sh, sh, sh, rep, rep, rep, rep)
         if self._donate:
             kw["donate_argnums"] = (0, 1, 2, 3, 4)
-        return jax.jit(train_step, **kw)
+        return jax.jit(pt_train_step, **kw)
 
     def _build_opt_step(self):
         """Train step around a pluggable ``paddle_tpu.optimizer.Optimizer``:
@@ -495,15 +499,17 @@ class Engine:
         opt = self._optimizer
         id2idx = {id(p): i for i, p in enumerate(self._proxies)}
 
-        def train_step(params, opt_state, step, lr, input_ids, labels):
+        def pt_train_step(params, opt_state, step, lr, input_ids, labels):
             step = step + 1
             loss, grads = jax.value_and_grad(self._pure_loss)(params, input_ids, labels)
-            grads = self._clip_grads(grads)
-            grads = [g.astype(jnp.float32) for g in grads]
-            acc = {name: {id(self._proxies[i]): a for i, a in d.items()}
-                   for name, d in opt_state.items()}
-            new_p, new_acc = opt._functional_update(
-                grads, params, self._proxies, acc, lr, step.astype(jnp.float32))
+            with jax.named_scope("pt.optimizer"):
+                grads = self._clip_grads(grads)
+                grads = [g.astype(jnp.float32) for g in grads]
+                acc = {name: {id(self._proxies[i]): a for i, a in d.items()}
+                       for name, d in opt_state.items()}
+                new_p, new_acc = opt._functional_update(
+                    grads, params, self._proxies, acc, lr,
+                    step.astype(jnp.float32))
             new_state = {name: {id2idx[pid]: a for pid, a in d.items()}
                          for name, d in new_acc.items()}
             return new_p, new_state, step, loss
@@ -518,7 +524,7 @@ class Engine:
             kw["out_shardings"] = (sh, osh, rep, rep)
         if self._donate:
             kw["donate_argnums"] = (0, 1, 2)
-        return jax.jit(train_step, **kw)
+        return jax.jit(pt_train_step, **kw)
 
     # ---- public API ----
     def shard_batch(self, *arrays):
@@ -536,17 +542,32 @@ class Engine:
                 "Engine was built with abstract_state=True (AOT-lowering "
                 "mode): optimizer state is ShapeDtypeStructs, step() cannot "
                 "execute — use _build_step().lower(...) instead")
+        self._host_step += 1
+        # the host's part of a step on the profiler's clock (an inactive
+        # TraceMe without a profiler session): docs/OBSERVABILITY.md
+        with program_span("train.step", note=jax.profiler.StepTraceAnnotation,
+                          step_num=self._host_step):
+            return self._step(input_ids, labels)
+
+    def _call_step(self, build, *args):
+        """The jitted step; its first call (trace, compile or cache load)
+        goes under ``pt.train.build``."""
+        if self._jit_step is not None:
+            return self._jit_step(*args)
+        self._jit_step = build()
+        with program_span("train.build", program="pt_train_step"):
+            return first_call(self._jit_step, *args)
+
+    def _step(self, input_ids, labels):
         ids = input_ids._data if isinstance(input_ids, Tensor) else jnp.asarray(input_ids)
         lbl = labels._data if isinstance(labels, Tensor) else jnp.asarray(labels)
         if self.guard is not None:
-            if self._jit_step is None:
-                self._jit_step = self._build_guard_step()
-            self._host_step += 1
             from ..resilience.faults import numeric_inject_code
 
             inject = numeric_inject_code(str(self._host_step))
             (self.params, self.m, self.v, self.step_count, self.guard_state,
-             loss, health) = self._jit_step(
+             loss, health) = self._call_step(
+                self._build_guard_step,
                 self.params, self.m, self.v, self.step_count,
                 self.guard_state, ids, lbl,
                 jnp.asarray(inject, jnp.int32),
@@ -554,15 +575,13 @@ class Engine:
             self.last_health = health
             return loss
         if self._optimizer is not None:
-            if self._jit_step is None:
-                self._jit_step = self._build_opt_step()
             lr = jnp.asarray(self._current_lr(), jnp.float32)
-            self.params, self.opt_state, self.step_count, loss = self._jit_step(
+            self.params, self.opt_state, self.step_count, loss = self._call_step(
+                self._build_opt_step,
                 self.params, self.opt_state, self.step_count, lr, ids, lbl)
             return loss
-        if self._jit_step is None:
-            self._jit_step = self._build_step()
-        self.params, self.m, self.v, self.step_count, loss = self._jit_step(
+        self.params, self.m, self.v, self.step_count, loss = self._call_step(
+            self._build_step,
             self.params, self.m, self.v, self.step_count, ids, lbl)
         return loss
 
@@ -572,7 +591,11 @@ class Engine:
             if self.mesh is not None:
                 bsh = _batch_sharding(self.mesh)
                 kw["in_shardings"] = (self._shardings, bsh, bsh)
-            self._jit_loss = jax.jit(self._pure_loss, **kw)
+
+            def pt_eval_loss(params, input_ids, labels):
+                return self._pure_loss(params, input_ids, labels)
+
+            self._jit_loss = jax.jit(pt_eval_loss, **kw)
         ids = input_ids._data if isinstance(input_ids, Tensor) else jnp.asarray(input_ids)
         lbl = labels._data if isinstance(labels, Tensor) else jnp.asarray(labels)
         return self._jit_loss(self.params, ids, lbl)

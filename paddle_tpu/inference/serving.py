@@ -94,6 +94,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time as _time
 import weakref
 from typing import Dict, List, Optional, Sequence, Union
@@ -103,12 +104,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.tensor import Tensor
+from ..framework.compile_cache import first_call
+from ..observability.tracing import program_span
 
 
 # THE sampler lives in generation_utils so generate() and the engine share one
 # implementation; re-exported here for the serving-facing API surface.
 from ..models.generation_utils import (fold_keys as _fold_keys,
                                        sample_rows, validate_sampling)
+
+
+@functools.partial(jax.named_call, name="pt.sampler")
+def _greedy(logits):
+    """The sampler's arm for all-greedy batches, under its scope."""
+    return jnp.argmax(logits, -1).astype(jnp.int32)
+
+
 # host-side page bookkeeping lives next to the paged kernels; re-exported
 # here as the serving-facing API surface
 from ..ops.paged_attention import BlockAllocator, RadixPrefixCache
@@ -152,6 +163,23 @@ def __getattr__(name):
 
         return StepWatchdog
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+class _wait_span(program_span):
+    """``pt.serve.wait``: the host blocks on device values. Its wall time is
+    the engine's ``stats["device_wait_s"]``."""
+
+    __slots__ = ("_stats",)
+
+    def __init__(self, engine, what: str):
+        super().__init__("serve.wait", engine.tracer, engine.trace_tags,
+                         what=what)
+        self._stats = engine.stats
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        self._stats["device_wait_s"] += self.elapsed_s
+        return False
 
 
 class MeshDegraded(RuntimeError):
@@ -764,13 +792,26 @@ class ContinuousBatchingEngine:
         self._fault_hook = None
         self._device_loss_hook = None
         self._retry_stats_fn = None
-        # host-side accounting: admission vs decode dispatch time (the
-        # admission-stall share is stats["admit_host_s"] / wall) plus the
-        # prefix-cache counters (docs/SERVING.md: hit_tokens / miss_tokens
-        # feed serving_prefix_hit_rate; cow_copies / evictions expose block
-        # lifecycle; compile_cache_entries is the bounded-compile-cache
-        # telemetry, warned past ``compile_cache_cap``)
+        # host-side accounting: wall time of admission and of the decode
+        # block as seen from the host. admit_host_s / decode_host_s /
+        # prefill_host_s INCLUDE the waits on device values inside them; the
+        # host's own work is step_wall_s less device_wait_s (the time inside
+        # ``pt.serve.wait`` spans). steps / decode_blocks /
+        # decode_block_steps (sum of block lengths) count dispatches;
+        # programs_built counts every program variant's first call (the
+        # n_steps variants in jax's own cache included, which
+        # compile_cache_entries leaves out); step_max_s / step_max_wait_s
+        # are the wall and the wait of the longest step so far that built
+        # no program (a build is minutes of compiling, not a stall).
+        # Plus the prefix-cache counters (docs/SERVING.md: hit_tokens /
+        # miss_tokens feed serving_prefix_hit_rate; cow_copies / evictions
+        # expose block lifecycle; compile_cache_entries is the
+        # bounded-compile-cache telemetry, warned past ``compile_cache_cap``)
         self.stats = {"admit_host_s": 0.0, "decode_host_s": 0.0,
+                      "steps": 0, "step_wall_s": 0.0, "device_wait_s": 0.0,
+                      "decode_blocks": 0, "decode_block_steps": 0,
+                      "programs_built": 0, "step_max_s": 0.0,
+                      "step_max_wait_s": 0.0,
                       "compile_cache_entries": 0, "shed": 0,
                       "retry_attempts": 0, "retry_giveups": 0,
                       "fused_updates": 0,
@@ -789,6 +830,13 @@ class ContinuousBatchingEngine:
         # filled lazily as each sharded program first dispatches — feeds
         # the serving collector and mirrors the PT-COMM contract entries
         self._mesh_programs: Dict[str, float] = {}
+        # (program, key) of every program variant called so far: a first
+        # call goes under ``pt.serve.build`` (_call_built)
+        self._built = set()
+        # terminal stamps of requests whose token values are still on the
+        # device (the path without eos): _drain_pending writes them once
+        # the values are on the host
+        self._finish_marks: List["Request"] = []
         # int8 block-format occupancy gauge (pt_kv_quant_blocks): pool
         # pages held in quantized form — 0 on fp engines
         self._kv_quant_blocks = (int(self.caches["kv"][0][0].shape[0])
@@ -837,6 +885,24 @@ class ContinuousBatchingEngine:
         tags = dict(self.trace_tags)
         tags["tenant"] = req.tenant
         return tags
+
+    def _span(self, name: str, **args):
+        """A ``pt.<name>`` program span of this engine
+        (observability/tracing.py ``program_span``)."""
+        return program_span(name, self.tracer, self.trace_tags, **args)
+
+    def _call_built(self, program: str, key, fn, *args, **kw):
+        """Call a jitted program. The first call of each (program, key) —
+        a trace and a compile or a cache load — runs under
+        ``pt.serve.build`` and counts in ``stats["programs_built"]``."""
+        k = (program, key)
+        if k in self._built:
+            return fn(*args, **kw)
+        with self._span("serve.build", program=program, key=str(key)):
+            out = first_call(fn, *args, **kw)
+        self._built.add(k)
+        self.stats["programs_built"] += 1
+        return out
 
     # ---- public API ----
     def add_request(self, req: Request) -> int:
@@ -955,8 +1021,9 @@ class ContinuousBatchingEngine:
         ``generate()``'s async dispatch. eos-carrying batches pace at
         ``block_size`` and materialize each block (early exit needs the
         values). Host-side time is accounted in ``self.stats``
-        (admit_host_s / decode_host_s) so the admission share is measurable
-        at any workload."""
+        (admit_host_s / decode_host_s, device waits included; step_wall_s
+        and device_wait_s split the step into host work and waiting) and
+        written as ``pt.serve.*`` program spans (docs/OBSERVABILITY.md)."""
         if self._fault_hook is None:
             from ..distributed.resilience.faults import (device_loss,
                                                          maybe_inject)
@@ -982,13 +1049,24 @@ class ContinuousBatchingEngine:
                 f"at step {self._step_idx} ({survivors} surviving) — "
                 f"engine must reshard to a narrower mesh",
                 lost=lost, survivors=survivors)
+        stats = self.stats
+        wait0, built0 = stats["device_wait_s"], stats["programs_built"]
         t0 = _time.perf_counter()
         sched0 = self._sched_tokens
         self._deferred_step = False
         try:
-            self._step_inner()
+            with self._span("serve.step", step=self._step_idx,
+                            occupied=len(self._occupied),
+                            queued=len(self._queue)):
+                self._step_inner()
         finally:
             dt = _time.perf_counter() - t0
+            stats["steps"] += 1
+            stats["step_wall_s"] += dt
+            if dt > stats["step_max_s"] and \
+                    stats["programs_built"] == built0:
+                stats["step_max_s"] = dt
+                stats["step_max_wait_s"] = stats["device_wait_s"] - wait0
             d = self._sched_tokens - sched0
             if d > 0 and dt > 0:
                 rate = d / dt
@@ -1040,7 +1118,7 @@ class ContinuousBatchingEngine:
             if decoding:
                 self._decode_block()
             t0 = _time.perf_counter()
-            self._admit()
+            self._admit_span()
             self._prefill_tick()
             self.stats["admit_host_s"] += _time.perf_counter() - t0
             if not decoding:
@@ -1048,14 +1126,21 @@ class ContinuousBatchingEngine:
             return
         if not self._occupied:
             t0 = _time.perf_counter()
-            self._admit()
+            self._admit_span()
             self.stats["admit_host_s"] += _time.perf_counter() - t0
             self._decode_block()
             return
         self._decode_block()
         t0 = _time.perf_counter()
-        self._admit()
+        self._admit_span()
         self.stats["admit_host_s"] += _time.perf_counter() - t0
+
+    def _admit_span(self):
+        with self._span("serve.admit") as sp:
+            q0 = len(self._queue)
+            self._admit()
+            sp.set(admitted=q0 - len(self._queue),
+                   deferred=int(self._deferred_step))
 
     def _evict_expired(self):
         """Deadline enforcement: fail-and-free requests past ``deadline_s``
@@ -1066,6 +1151,10 @@ class ContinuousBatchingEngine:
         deadline-carrying request is in the system."""
         if not self._n_deadlined:
             return
+        with self._span("serve.admit", what="evict_expired"):
+            self._evict_expired_scan()
+
+    def _evict_expired_scan(self):
         now = _time.monotonic()
 
         def expired(r):
@@ -1103,40 +1192,55 @@ class ContinuousBatchingEngine:
             self.stats["decode_host_s"] += _time.perf_counter() - t0
 
     def _decode_block_inner(self):
-        if self._fused:
-            # device-resident state: every admission/release queued since
-            # the last block lands as ONE traced scatter program — the host
-            # never rebuilds or re-uploads a [max_batch, pages] table
-            self._flush_updates()
-        elif self.prefix_cache is not None and self._tables_dirty:
-            # dynamic block tables: rows for decode-ready slots map their
-            # allocated (possibly shared) pages; free and still-prefilling
-            # rows point at the parking page so the scan's dummy append can
-            # never touch a block another request shares. The .copy() is
-            # LOAD-BEARING: jax borrows the host buffer for an async
-            # transfer, and _release_slot mutates _tables_host — without a
-            # private snapshot the scan can observe post-mutation rows
-            # (measured ~1/30 runs decoding against parking-page tables)
-            self.caches = {"kv": self.caches["kv"],
-                           "tables": jnp.asarray(self._tables_host.copy())}
-            self._tables_dirty = False
-        # O(active): the decode set comes from the occupied dict (sorted for
-        # the legacy path's deterministic slot order), never a max_batch scan
-        live = [(i, r) for i, r in sorted(self._occupied.items())
-                if not (self.prefix_cache is not None
-                        and i in self._prefill_next)]
-        if not live:
-            return
-        if (self._spec is not None
-                and not any(r.temperature > 0.0 for _, r in live)
-                and all(self.max_len - int(self._pos[i]) >= self._spec.k
-                        for i, _ in live)):
+        with self._span("serve.decode.dispatch") as sp:
+            if self._fused:
+                # device-resident state: every admission/release queued
+                # since the last block lands as ONE traced scatter program —
+                # the host never rebuilds or re-uploads a [max_batch, pages]
+                # table
+                self._flush_updates()
+            elif self.prefix_cache is not None and self._tables_dirty:
+                # dynamic block tables: rows for decode-ready slots map their
+                # allocated (possibly shared) pages; free and still-prefilling
+                # rows point at the parking page so the scan's dummy append
+                # can never touch a block another request shares. The .copy()
+                # is LOAD-BEARING: jax borrows the host buffer for an async
+                # transfer, and _release_slot mutates _tables_host — without
+                # a private snapshot the scan can observe post-mutation rows
+                # (measured ~1/30 runs decoding against parking-page tables)
+                self.caches = {"kv": self.caches["kv"],
+                               "tables": jnp.asarray(self._tables_host.copy())}
+                self._tables_dirty = False
+            # O(active): the decode set comes from the occupied dict (sorted
+            # for the legacy path's deterministic slot order), never a
+            # max_batch scan
+            live = [(i, r) for i, r in sorted(self._occupied.items())
+                    if not (self.prefix_cache is not None
+                            and i in self._prefill_next)]
+            if not live:
+                return
             # all-greedy block with verify-window headroom on every row
             # (the K+1 window writes k/v at positions pos-1 .. pos-1+K):
             # one speculative dispatch replaces the scan block. Sampling
             # rows keep the legacy sampled mega-step; rows at the max_len
             # boundary finish on ordinary blocks.
+            spec = (self._spec is not None
+                    and not any(r.temperature > 0.0 for _, r in live)
+                    and all(self.max_len - int(self._pos[i]) >= self._spec.k
+                            for i, _ in live))
+            if not spec:
+                n, async_ok, do_sample = self._block_plan(live)
+                out = self._dispatch_block(live, n, do_sample)
+                sp.set(n_steps=n, rows=len(live), do_sample=do_sample)
+                self.stats["decode_blocks"] += 1
+                self.stats["decode_block_steps"] += n
+        if spec:
             return self._decode_spec_block(live)
+        self._book_block(live, n, async_ok, out)
+
+    def _block_plan(self, live):
+        """(scan length, whether no row carries an eos id, whether any row
+        samples) of the next decode block."""
         # block length: never decode past a request's max_new_tokens or the
         # engine max_len (pages beyond the table would clamp-corrupt)
         cap = min(min(r.max_new_tokens - r._n_out for _, r in live),
@@ -1152,9 +1256,12 @@ class ContinuousBatchingEngine:
                 stretch *= 2
             n = max(n, cap if cap <= self.block_size else stretch)
         n = max(1, n)
-        do_sample = bool(any(r.temperature > 0.0 for _, r in live))
+        return n, async_ok, bool(any(r.temperature > 0.0 for _, r in live))
+
+    def _dispatch_block(self, live, n: int, do_sample: bool):
+        """Dispatch ``pt_decode_block`` for ``n`` token steps; returns the
+        device array of the block's tokens [slots, n]."""
         toks = self._last_tok
-        t0_tr = None if self.tracer is None else self.tracer.now()
         if self._fused:
             # ONE jitted mega-step over all rows: decode + sampling +
             # position advance in-graph, inactive rows masked by the
@@ -1163,116 +1270,125 @@ class ContinuousBatchingEngine:
                 self._jit_mega = self._build_mega_jit()
                 self._note_compiled()
             seeds_d, temps_d, tops_d, topks_d = self._dev_samp
-            out, self._last_tok, new_kv, self._dev_pos = self._jit_mega(
+            out, self._last_tok, new_kv, self._dev_pos = self._call_built(
+                "pt_decode_block", (n, do_sample), self._jit_mega,
                 self._params, toks, self.caches["kv"],
                 self.caches["tables"], self._dev_pos, self._dev_act,
                 seeds_d, temps_d, tops_d, topks_d, n_steps=n,
                 do_sample=do_sample)
             self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
-        else:
-            active = np.zeros(self.max_batch, bool)
-            for i, _ in live:
-                active[i] = True
-            # parked rows decode at position 0 over slot-local pages —
-            # harmless
-            pos_vec = jnp.asarray(np.where(active, self._pos, 1) - 1)
-            if self._jit_step is None:
-                from ..core import autograd_engine
-                from ..jit.api import _Swap
+            return out
+        active = np.zeros(self.max_batch, bool)
+        for i, _ in live:
+            active[i] = True
+        # parked rows decode at position 0 over slot-local pages — harmless
+        pos_vec = jnp.asarray(np.where(active, self._pos, 1) - 1)
+        if self._jit_step is None:
+            from ..core import autograd_engine
+            from ..jit.api import _Swap
 
-                def run(params, toks, caches, pos_vec, seeds, temps, tops,
-                        topks, n_steps, do_sample):
-                    def body(carry, _):
-                        tok, cs, pos = carry
-                        with autograd_engine.no_grad(), _Swap(self._tensors,
-                                                              params):
-                            logits, cs = self.model.paged_token_step(
-                                tok, cs, pos)
-                        if do_sample:
-                            keys = _fold_keys(seeds, pos + 1)
-                            nxt = sample_rows(logits, keys, temps, tops,
-                                              topks)
-                        else:
-                            # all-greedy batches skip the sampler: its
-                            # vocab-wide argsort costs ~10 ms/token at 32k
-                            # vocab (measured 150x engine slowdown before
-                            # this gate)
-                            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-                        return (nxt, cs, pos + 1), nxt
+            def pt_decode_block(params, toks, caches, pos_vec, seeds, temps,
+                                tops, topks, n_steps, do_sample):
+                def body(carry, _):
+                    tok, cs, pos = carry
+                    with autograd_engine.no_grad(), _Swap(self._tensors,
+                                                          params):
+                        logits, cs = self.model.paged_token_step(
+                            tok, cs, pos)
+                    if do_sample:
+                        keys = _fold_keys(seeds, pos + 1)
+                        nxt = sample_rows(logits, keys, temps, tops, topks)
+                    else:
+                        # all-greedy batches skip the sampler: its
+                        # vocab-wide argsort costs ~10 ms/token at 32k
+                        # vocab (measured 150x engine slowdown before
+                        # this gate)
+                        nxt = _greedy(logits)
+                    return (nxt, cs, pos + 1), nxt
 
-                    (tok, cs, _), out = jax.lax.scan(
-                        body, (toks, caches, pos_vec), None, length=n_steps)
-                    return jnp.swapaxes(out, 0, 1), tok, cs
+                (tok, cs, _), out = jax.lax.scan(
+                    body, (toks, caches, pos_vec), None, length=n_steps)
+                return jnp.swapaxes(out, 0, 1), tok, cs
 
-                self._jit_step = jax.jit(
-                    run, static_argnames=("n_steps", "do_sample"))
-                self._note_compiled()
-            if self._samp_dev is None:
-                # private snapshots: jax borrows host buffers for async
-                # transfers and these arrays mutate on admission/slot-release
-                self._samp_dev = (jnp.asarray(self._seeds.copy()),
-                                  jnp.asarray(self._temps.copy()),
-                                  jnp.asarray(self._tops.copy()),
-                                  jnp.asarray(self._topks.copy()))
-            seeds_d, temps_d, tops_d, topks_d = self._samp_dev
-            out, self._last_tok, self.caches = self._jit_step(
-                self._params, toks, self.caches, pos_vec,
-                seeds_d, temps_d, tops_d, topks_d, n_steps=n,
-                do_sample=do_sample)
-        t1_tr = None if self.tracer is None else self.tracer.now()
+            self._jit_step = jax.jit(
+                pt_decode_block, static_argnames=("n_steps", "do_sample"))
+            self._note_compiled()
+        if self._samp_dev is None:
+            # private snapshots: jax borrows host buffers for async
+            # transfers and these arrays mutate on admission/slot-release
+            self._samp_dev = (jnp.asarray(self._seeds.copy()),
+                              jnp.asarray(self._temps.copy()),
+                              jnp.asarray(self._tops.copy()),
+                              jnp.asarray(self._topks.copy()))
+        seeds_d, temps_d, tops_d, topks_d = self._samp_dev
+        out, self._last_tok, self.caches = self._call_built(
+            "pt_decode_block", (n, do_sample), self._jit_step,
+            self._params, toks, self.caches, pos_vec,
+            seeds_d, temps_d, tops_d, topks_d, n_steps=n,
+            do_sample=do_sample)
+        return out
+
+    def _book_block(self, live, n: int, async_ok: bool, out):
+        """Book a dispatched block's tokens: by the schedule alone where no
+        row carries an eos id (values stay on the device until
+        ``_drain_pending``), else from the values, read back here."""
         if async_ok:
             entries = []
             tok_marks = [] if self.tracer is not None else None
-            for i, req in live:
-                took = min(n, req.max_new_tokens - req._n_out)
-                entries.append((i, req, took))
-                req._n_out += took
-                self._sched_tokens += took
-                if tok_marks is not None:
-                    tok_marks.append((req.rid, req._n_out))
-                self._pos[i] += took
-                if req._n_out >= req.max_new_tokens:
-                    req.done = True
-                    self._mark_done(req)
-                    self._release_slot(i)   # slot + its pages are free again
-            if self.tracer is not None:
-                # ONE lock acquisition for the whole block's stamps — the
-                # PR 9 recorder RLock must not serialize a 256-row step
-                self.tracer.decode_block_batch(
-                    t0_tr, n, len(live), tok_marks, t1=t1_tr,
-                    tags=self.trace_tags,
-                    tokens=sum(e[2] for e in entries))
-            self._pending.append((out, entries))
+            with self._span("serve.emit") as sp:
+                finished = 0
+                for i, req in live:
+                    took = min(n, req.max_new_tokens - req._n_out)
+                    entries.append((i, req, took))
+                    req._n_out += took
+                    self._sched_tokens += took
+                    if tok_marks is not None:
+                        tok_marks.append((req.rid, req._n_out))
+                    self._pos[i] += took
+                    if req._n_out >= req.max_new_tokens:
+                        req.done = True
+                        finished += 1
+                        self._mark_done(req)
+                        self._release_slot(i)   # slot + pages are free again
+                sp.set(tokens=sum(e[2] for e in entries), finished=finished,
+                       scheduled=True)
+            # the rows' token progress is stamped when the values reach the
+            # host (_drain_pending), never at dispatch
+            self._pending.append((out, entries, tok_marks, False))
             return
         # eos path: materialize (in generation order — drain older pendings
         # first so req.output stays ordered across an async->sync transition)
         self._drain_pending()
-        out = np.asarray(out)
+        with _wait_span(self, "decode_block"):
+            out = np.asarray(out)
         tok_marks = [] if self.tracer is not None else None
         block_tokens = 0
-        for i, req in live:
-            took = 0
-            for j in range(n):
-                tok = int(out[i, j])
-                req.output.append(tok)
-                req._n_out += 1
-                took = j + 1
-                if ((req.eos_token_id is not None and tok == req.eos_token_id)
-                        or req._n_out >= req.max_new_tokens):
-                    req.done = True
-                    break
-            self._pos[i] += took
-            self._sched_tokens += took
-            block_tokens += took
-            if tok_marks is not None:
-                tok_marks.append((req.rid, req._n_out))
-            if req.done:
-                self._mark_done(req)
-                self._release_slot(i)       # slot + its pages are free again
-        if self.tracer is not None:
-            self.tracer.decode_block_batch(t0_tr, n, len(live), tok_marks,
-                                           t1=t1_tr, tags=self.trace_tags,
-                                           tokens=block_tokens)
+        with self._span("serve.emit") as sp:
+            finished = 0
+            for i, req in live:
+                took = 0
+                for j in range(n):
+                    tok = int(out[i, j])
+                    req.output.append(tok)
+                    req._n_out += 1
+                    took = j + 1
+                    if ((req.eos_token_id is not None
+                         and tok == req.eos_token_id)
+                            or req._n_out >= req.max_new_tokens):
+                        req.done = True
+                        break
+                self._pos[i] += took
+                self._sched_tokens += took
+                block_tokens += took
+                if tok_marks is not None:
+                    tok_marks.append((req.rid, req._n_out))
+                if req.done:
+                    finished += 1
+                    self._mark_done(req)
+                    self._release_slot(i)   # slot + its pages are free again
+            sp.set(tokens=block_tokens, finished=finished)
+        if tok_marks:
+            self.tracer.tokens_batch(tok_marks, tags=self.trace_tags)
 
     def run_until_done(self, max_steps: int = 100000):
         steps = 0
@@ -1309,8 +1425,18 @@ class ContinuousBatchingEngine:
             self._n_deadlined = max(0, self._n_deadlined - 1)
         self._finished[req.rid] = req
         if self.tracer is not None:
-            self.tracer.finish(req.rid, req._n_out, failed=req.failed,
-                               error=req.error, tags=self.trace_tags)
+            if len(req.output) < req._n_out:
+                # path without eos: the request is done by the schedule but
+                # its last tokens are still on the device — the terminal
+                # stamp (and the inter-token latency it closes) waits for
+                # _drain_pending, behind the first-token stamp
+                self._finish_marks.append(req)
+            else:
+                self._stamp_finish(req)
+
+    def _stamp_finish(self, req: "Request"):
+        self.tracer.finish(req.rid, req._n_out, failed=req.failed,
+                           error=req.error, tags=self.trace_tags)
 
     def withdraw_queued(self, rid: int) -> bool:
         """Remove a still-WAITING request from the queue (never an admitted
@@ -1441,19 +1567,35 @@ class ContinuousBatchingEngine:
         readback stalls the host until that one transfer lands, so serial
         np.asarray calls would pay the transfers one after another (cost
         per readback not measured on the direct runtime)."""
-        for arr_dev, _ in self._pending:
+        if not self._pending and not self._finish_marks:
+            return
+        for arr_dev, *_ in self._pending:
             try:
                 arr_dev.copy_to_host_async()
             except AttributeError:
                 pass
-        for arr_dev, entries in self._pending:
-            arr = np.asarray(arr_dev)
+        tracer = self.tracer
+        for arr_dev, entries, marks, first in self._pending:
+            with _wait_span(self, "pending"):
+                arr = np.asarray(arr_dev)
             for row, req, took in entries:
                 if arr.ndim == 1:           # prefill firsts [g]
                     req.output.append(int(arr[row]))
                 else:                       # decode block [slots, n]
                     req.output.extend(int(t) for t in arr[row, :took])
+            if marks and tracer is not None:
+                # the stamps of values that were dispatched without a read:
+                # TTFT and token progress mean "on the host"
+                if first:
+                    tracer.first_tokens(marks, tags=self.trace_tags)
+                else:
+                    tracer.tokens_batch(marks, tags=self.trace_tags)
         self._pending.clear()
+        if self._finish_marks:
+            marks, self._finish_marks = self._finish_marks, []
+            if tracer is not None:
+                for req in marks:
+                    self._stamp_finish(req)
 
     # ---- internals ----
     def _release_slot(self, i: int):
@@ -1516,9 +1658,9 @@ class ContinuousBatchingEngine:
         if self._jit_apply is None:
             with_tables = self.prefix_cache is not None
 
-            def apply(tables, pos, act, seeds, temps, tops, topks, hist,
-                      hlen, idx, urows, upos, uact, useeds, utemps, utops,
-                      utopks, uhist, uhlen):
+            def pt_slot_update(tables, pos, act, seeds, temps, tops, topks,
+                               hist, hlen, idx, urows, upos, uact, useeds,
+                               utemps, utops, utopks, uhist, uhlen):
                 if with_tables:
                     tables = tables.at[idx].set(urows)
                 if with_spec:
@@ -1529,7 +1671,7 @@ class ContinuousBatchingEngine:
                         temps.at[idx].set(utemps), tops.at[idx].set(utops),
                         topks.at[idx].set(utopks), hist, hlen)
 
-            self._jit_apply = jax.jit(apply)
+            self._jit_apply = jax.jit(pt_slot_update)
             self._note_compiled()
         W = self._upd_width
         with_tables = self.prefix_cache is not None
@@ -1570,7 +1712,8 @@ class ContinuousBatchingEngine:
                                                                 jnp.int32)
             hlen_d = self._dev_hlen if with_spec else jnp.zeros(1, jnp.int32)
             tables, self._dev_pos, self._dev_act, s, t, p, k, hist_d, \
-                hlen_d = self._jit_apply(
+                hlen_d = self._call_built(
+                    "pt_slot_update", (), self._jit_apply,
                     self.caches["tables"], self._dev_pos, self._dev_act,
                     seeds_d, temps_d, tops_d, topks_d, hist_d, hlen_d, idx,
                     urows, upos, uact, useeds, utemps, utops, utopks,
@@ -1715,6 +1858,8 @@ class ContinuousBatchingEngine:
                 with serving_shard_axis(axis):
                     return fn(*args)
 
+            # "XLA Modules" reads jit_<name>: the program's own pt_ name
+            body.__name__ = body.__qualname__ = run.__name__
             sm = jax.shard_map(body, mesh=self._mesh, in_specs=in_specs,
                                out_specs=out_specs, check_vma=False)
             return jax.jit(sm, donate_argnums=donate)
@@ -1768,8 +1913,8 @@ class ContinuousBatchingEngine:
         from ..core import autograd_engine
         from ..jit.api import _Swap
 
-        def run(params, toks, kv, tables, pos, act, seeds, temps, tops,
-                topks, n_steps, do_sample):
+        def pt_decode_block(params, toks, kv, tables, pos, act, seeds, temps,
+                            tops, topks, n_steps, do_sample):
             caches = {"kv": kv, "tables": tables}
             pos_vec = jnp.where(act, pos, 1) - 1
 
@@ -1781,7 +1926,7 @@ class ContinuousBatchingEngine:
                     keys = _fold_keys(seeds, p + 1)
                     nxt = sample_rows(logits, keys, temps, tops, topks)
                 else:
-                    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                    nxt = _greedy(logits)
                 return (nxt, cs, p + 1), nxt
 
             (tok, cs, _), out = jax.lax.scan(
@@ -1789,7 +1934,7 @@ class ContinuousBatchingEngine:
             new_pos = jnp.where(act, pos + n_steps, pos)
             return jnp.swapaxes(out, 0, 1), tok, cs["kv"], new_pos
 
-        return run
+        return pt_decode_block
 
     # -- speculative multi-token decoding (docs/SERVING.md) ----------------
     def _build_spec_jit(self):
@@ -1834,7 +1979,8 @@ class ContinuousBatchingEngine:
         K, N, H = spec.k, spec.ngram, spec.history
         accept_all = spec._unsafe_accept_all
 
-        def run(params, toks, kv, tables, pos, act, hist, hlen, caps):
+        def pt_spec_block(params, toks, kv, tables, pos, act, hist, hlen,
+                          caps):
             pos_vec = jnp.where(act, pos, 1) - 1
             drafts = ngram_draft(hist, hlen, toks, K, N)
             window = jnp.concatenate([toks[:, None], drafts], axis=1)
@@ -1866,7 +2012,7 @@ class ContinuousBatchingEngine:
             new_pos = jnp.where(act, pos + emit, pos)
             return out, emit, last, caches["kv"], new_pos, hist, hlen
 
-        return run
+        return pt_spec_block
 
     def _decode_spec_block(self, live):
         """Dispatch one speculative verify step and book its variable
@@ -1877,21 +2023,24 @@ class ContinuousBatchingEngine:
         unless an eos-carrying row needs the values."""
         spec = self._spec
         K = spec.k
-        caps = np.zeros(self.max_batch, np.int32)
-        for i, r in live:
-            caps[i] = min(r.max_new_tokens - r._n_out,
-                          self.max_len - int(self._pos[i]))
-        t0_tr = None if self.tracer is None else self.tracer.now()
-        if self._jit_spec is None:
-            self._jit_spec = self._build_spec_jit()
-            self._note_compiled()
-        (out_dev, emit_dev, self._last_tok, new_kv, self._dev_pos,
-         self._dev_hist, self._dev_hlen) = self._jit_spec(
-            self._params, self._last_tok, self.caches["kv"],
-            self.caches["tables"], self._dev_pos, self._dev_act,
-            self._dev_hist, self._dev_hlen, jnp.asarray(caps))
-        self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
-        emit = np.asarray(emit_dev)         # the one sync read ([B] int32)
+        with self._span("serve.decode.dispatch", n_steps=K + 1,
+                        rows=len(live), do_sample=False):
+            caps = np.zeros(self.max_batch, np.int32)
+            for i, r in live:
+                caps[i] = min(r.max_new_tokens - r._n_out,
+                              self.max_len - int(self._pos[i]))
+            if self._jit_spec is None:
+                self._jit_spec = self._build_spec_jit()
+                self._note_compiled()
+            (out_dev, emit_dev, self._last_tok, new_kv, self._dev_pos,
+             self._dev_hist, self._dev_hlen) = self._call_built(
+                "pt_spec_block", (), self._jit_spec,
+                self._params, self._last_tok, self.caches["kv"],
+                self.caches["tables"], self._dev_pos, self._dev_act,
+                self._dev_hist, self._dev_hlen, jnp.asarray(caps))
+            self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
+        with _wait_span(self, "spec_emit"):
+            emit = np.asarray(emit_dev)     # the one sync read ([B] int32)
         # proposal counter derives from the already-synced emit vector —
         # never a second device readback per dispatch (each one stalls
         # the host on the device); the ACCEPTED counter is
@@ -1899,15 +2048,29 @@ class ContinuousBatchingEngine:
         # acceptance telemetry tracks delivered-token truth
         self.stats["spec_proposed"] += K * len(live)
         self.stats["spec_steps"] += 1
-        t1_tr = None if self.tracer is None else self.tracer.now()
         any_eos = any(r.eos_token_id is not None for _, r in live)
         out = None
         if any_eos:
             # materialize in generation order (drain older pendings first)
             self._drain_pending()
-            out = np.asarray(out_dev)
+            with _wait_span(self, "spec_block"):
+                out = np.asarray(out_dev)
         entries = []
         tok_marks = [] if self.tracer is not None else None
+        with self._span("serve.emit") as sp:
+            total = self._book_spec(live, emit, out, entries, tok_marks)
+            sp.set(tokens=total, finished=sum(1 for _, r in live if r.done))
+        if tok_marks and out is not None:
+            # at K>1 a dispatch emits a variable token count (``tokens`` of
+            # the pt.serve.emit span above); the rows' progress is booked
+            # here only when the values are on the host
+            self.tracer.tokens_batch(tok_marks, tags=self.trace_tags)
+        if entries:
+            self._pending.append((out_dev, entries, tok_marks, False))
+
+    def _book_spec(self, live, emit, out, entries, tok_marks) -> int:
+        """Book one speculative dispatch's per-row emission; returns the
+        tokens delivered."""
         total = 0
         for i, req in live:
             took = int(emit[i])
@@ -1940,16 +2103,7 @@ class ContinuousBatchingEngine:
             if req.done:
                 self._mark_done(req)
                 self._release_slot(i)
-        if self.tracer is not None:
-            # tokens-per-step rides the block span: at K>1 a dispatch
-            # emits a variable token count, and the SLO inter-token math
-            # must see real progress, not dispatch counts
-            self.tracer.decode_block_batch(t0_tr, K + 1, len(live),
-                                           tok_marks, t1=t1_tr,
-                                           tags=self.trace_tags,
-                                           tokens=total)
-        if entries:
-            self._pending.append((out_dev, entries))
+        return total
 
     def _reset_quant_blocks(self, blocks):
         """int8 allocation hygiene: zero the page bytes AND the per-block
@@ -1972,7 +2126,7 @@ class ContinuousBatchingEngine:
             W *= 2
         fn = self._jit_qreset.get(W)
         if fn is None:
-            def run(kv, idx):
+            def pt_kv_reset(kv, idx):
                 out = []
                 for k, v in kv:
                     out.append((
@@ -1982,12 +2136,14 @@ class ContinuousBatchingEngine:
                                         v.scale.at[idx].set(0.0))))
                 return out
 
-            fn = self._jit_qreset[W] = jax.jit(run)
+            fn = self._jit_qreset[W] = jax.jit(pt_kv_reset)
             self._note_compiled()
         npages = int(self.caches["kv"][0][0].shape[0])
         idx = np.full(W, npages, np.int32)     # pad: out of range, dropped
         idx[:len(blocks)] = blocks
-        self.caches = {"kv": fn(self.caches["kv"], jnp.asarray(idx)),
+        self.caches = {"kv": self._call_built("pt_kv_reset", W, fn,
+                                              self.caches["kv"],
+                                              jnp.asarray(idx)),
                        "tables": self.caches["tables"]}
 
     def _spec_seed(self, prompt, extra=()):
@@ -2024,18 +2180,20 @@ class ContinuousBatchingEngine:
             W *= 2
         fn = self._jit_cow_batch.get(W)
         if fn is None:
-            def run(kv, src, dst):
+            def pt_cow_copy(kv, src, dst):
                 return [copy_pages(k, v, src, dst) for (k, v) in kv]
 
-            fn = self._jit_cow_batch[W] = jax.jit(run)
+            fn = self._jit_cow_batch[W] = jax.jit(pt_cow_copy)
             self._note_compiled()
         src = np.full(W, self._park, np.int32)
         dst = np.full(W, self._park, np.int32)
         for j, (s, d) in enumerate(pairs):
             src[j] = s
             dst[j] = d
-        self.caches = {"kv": fn(self.caches["kv"], jnp.asarray(src),
-                                jnp.asarray(dst)),
+        self.caches = {"kv": self._call_built("pt_cow_copy", W, fn,
+                                              self.caches["kv"],
+                                              jnp.asarray(src),
+                                              jnp.asarray(dst)),
                        "tables": self.caches["tables"]}
         self._alloc.decref([s for s, _ in pairs])
 
@@ -2208,13 +2366,15 @@ class ContinuousBatchingEngine:
         if self._cow_fn is None:
             from ..ops.paged_attention import copy_pages
 
-            def run(kv, src, dst):
+            def pt_cow_copy(kv, src, dst):
                 return [copy_pages(k, v, src, dst) for (k, v) in kv]
 
-            self._cow_fn = jax.jit(run)
+            self._cow_fn = jax.jit(pt_cow_copy)
             self._note_compiled()
-        self.caches = {"kv": self._cow_fn(self.caches["kv"], np.int32(src),
-                                          np.int32(dst)),
+        self.caches = {"kv": self._call_built("pt_cow_copy", (),
+                                              self._cow_fn,
+                                              self.caches["kv"],
+                                              np.int32(src), np.int32(dst)),
                        "tables": self.caches["tables"]}
 
     def _prefill_tick(self):
@@ -2228,36 +2388,49 @@ class ContinuousBatchingEngine:
             return
         t0 = _time.perf_counter()
         try:
-            chunkers = [(s, self._slots[s]) for s in sorted(self._prefill_next)
-                        if self._prefill_next[s] < len(self._slots[s].prompt)]
-            if chunkers and self._fused:
-                # prompt-packing prefill (_run_pack): several short prompts
-                # — and several chunks of one long prompt — advance in ONE
-                # call per step instead of one chunk per slot per step
-                self._run_pack(chunkers)
-                while self._brownout_active and any(
-                        self._prefill_next[s] < len(r.prompt)
-                        for s, r in chunkers):
-                    self._run_pack([(s, r) for s, r in chunkers
-                                    if self._prefill_next[s] < len(r.prompt)])
-            elif chunkers:
-                self._run_chunk(chunkers)
-                while self._brownout_active and any(
-                        self._prefill_next[s] < len(r.prompt)
-                        for s, r in chunkers):
-                    # brownout disables chunked INTERLEAVING: the whole
-                    # prompt prefills this tick (legacy admit-stalls-a-step
-                    # behavior), trading decode overlap for zero extra
-                    # mid-prefill state under pressure. Same compiled chunk
-                    # program, run to completion.
-                    self._run_chunk([(s, r) for s, r in chunkers
-                                     if self._prefill_next[s] < len(r.prompt)])
-            ready = [(s, self._slots[s]) for s in sorted(self._prefill_next)
-                     if self._prefill_next[s] >= len(self._slots[s].prompt)]
-            if ready:
-                self._first_token(ready)
+            with self._span("serve.prefill") as sp:
+                left0 = self._prefill_left()
+                rows0 = self.stats["packed_rows"]
+                self._prefill_tick_inner()
+                sp.set(tokens=left0 - self._prefill_left(),
+                       rows=self.stats["packed_rows"] - rows0)
         finally:
             self.stats["prefill_host_s"] += _time.perf_counter() - t0
+
+    def _prefill_left(self) -> int:
+        """Prompt tokens of mid-prefill slots not yet written."""
+        return sum(len(self._slots[s].prompt) - nxt
+                   for s, nxt in self._prefill_next.items())
+
+    def _prefill_tick_inner(self):
+        chunkers = [(s, self._slots[s]) for s in sorted(self._prefill_next)
+                    if self._prefill_next[s] < len(self._slots[s].prompt)]
+        if chunkers and self._fused:
+            # prompt-packing prefill (_run_pack): several short prompts
+            # — and several chunks of one long prompt — advance in ONE
+            # call per step instead of one chunk per slot per step
+            self._run_pack(chunkers)
+            while self._brownout_active and any(
+                    self._prefill_next[s] < len(r.prompt)
+                    for s, r in chunkers):
+                self._run_pack([(s, r) for s, r in chunkers
+                                if self._prefill_next[s] < len(r.prompt)])
+        elif chunkers:
+            self._run_chunk(chunkers)
+            while self._brownout_active and any(
+                    self._prefill_next[s] < len(r.prompt)
+                    for s, r in chunkers):
+                # brownout disables chunked INTERLEAVING: the whole
+                # prompt prefills this tick (legacy admit-stalls-a-step
+                # behavior), trading decode overlap for zero extra
+                # mid-prefill state under pressure. Same compiled chunk
+                # program, run to completion.
+                self._run_chunk([(s, r) for s, r in chunkers
+                                 if self._prefill_next[s] < len(r.prompt)])
+        ready = [(s, self._slots[s]) for s in sorted(self._prefill_next)
+                 if self._prefill_next[s] >= len(self._slots[s].prompt)]
+        if ready:
+            self._first_token(ready)
 
     def _prefill_row(self, s: int, req: "Request"):
         """Table row handed to the prefill-chunk program: the slot's REAL
@@ -2290,7 +2463,7 @@ class ContinuousBatchingEngine:
             from ..core import autograd_engine
             from ..jit.api import _Swap
 
-            def run(params, ids, kv, rows, starts):
+            def pt_prefill_chunk(params, ids, kv, rows, starts):
                 sub = {"kv": kv, "tables": rows}
                 with autograd_engine.no_grad(), _Swap(self._tensors, params):
                     sub = self.model.paged_prefill_chunk(ids, sub, starts)
@@ -2298,10 +2471,10 @@ class ContinuousBatchingEngine:
 
             donate = self._CHUNK_DONATE_ARGNUMS if self._donate_carry else ()
             if self._mesh is not None:
-                fn = self._mesh_jit(run, self._CHUNK_ARG_NAMES, "kv",
-                                    donate, name=f"prefill_chunk@{g}")
+                fn = self._mesh_jit(pt_prefill_chunk, self._CHUNK_ARG_NAMES,
+                                    "kv", donate, name=f"prefill_chunk@{g}")
             else:
-                fn = jax.jit(run, donate_argnums=donate)
+                fn = jax.jit(pt_prefill_chunk, donate_argnums=donate)
             self._jit_chunk[g] = fn
             self._note_compiled()
         return fn
@@ -2318,9 +2491,10 @@ class ContinuousBatchingEngine:
             chunk = req.prompt[nxt: nxt + C]
             ids[r, : len(chunk)] = chunk
             starts[r] = nxt
-        fn = self._chunk_fn(g)
-        new_kv = fn(self._params, jnp.asarray(ids), self.caches["kv"],
-                    jnp.asarray(rows), jnp.asarray(starts))
+        new_kv = self._call_built(
+            "pt_prefill_chunk", g, self._chunk_fn(g), self._params,
+            jnp.asarray(ids), self.caches["kv"], jnp.asarray(rows),
+            jnp.asarray(starts))
         self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
         for s, req in group:
             nxt = self._prefill_next[s]
@@ -2377,9 +2551,10 @@ class ContinuousBatchingEngine:
             ids[r, : len(chunk)] = chunk
             starts[r] = off
             trows[r] = self._prefill_row(s, req)
-        fn = self._chunk_fn(g)
-        new_kv = fn(self._params, jnp.asarray(ids), self.caches["kv"],
-                    jnp.asarray(trows), jnp.asarray(starts))
+        new_kv = self._call_built(
+            "pt_prefill_chunk", g, self._chunk_fn(g), self._params,
+            jnp.asarray(ids), self.caches["kv"], jnp.asarray(trows),
+            jnp.asarray(starts))
         self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
         self.stats["packed_rows"] += len(rows)
         for s, req in group:
@@ -2423,8 +2598,8 @@ class ContinuousBatchingEngine:
             from ..core import autograd_engine
             from ..jit.api import _Swap
 
-            def run(params, last, kv, rows, last_tok, ints, floats,
-                    _sample=do_sample):
+            def pt_first_token(params, last, kv, rows, last_tok, ints,
+                               floats, _sample=do_sample):
                 true_len, seed, top_k, slots_ = (ints[:, 0], ints[:, 1],
                                                  ints[:, 2], ints[:, 3])
                 temp, top_p = floats[:, 0], floats[:, 1]
@@ -2436,27 +2611,39 @@ class ContinuousBatchingEngine:
                     keys = _fold_keys(seed, true_len)
                     nxt = sample_rows(logits, keys, temp, top_p, top_k)
                 else:
-                    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                    nxt = _greedy(logits)
                 return nxt, sub["kv"], last_tok.at[slots_].set(nxt)
 
             donate = self._FIRST_DONATE_ARGNUMS if self._donate_carry \
                 else ()
             if self._mesh is not None:
-                fn = self._mesh_jit(run, self._FIRST_ARG_NAMES,
+                fn = self._mesh_jit(pt_first_token, self._FIRST_ARG_NAMES,
                                     ("rep", "kv", "rep"), donate,
                                     name=f"first_token@{g}")
             else:
-                fn = jax.jit(run, donate_argnums=donate)
+                fn = jax.jit(pt_first_token, donate_argnums=donate)
             self._jit_first[(g, do_sample)] = fn
             self._note_compiled()
-        firsts_dev, new_kv, self._last_tok = fn(
+        firsts_dev, new_kv, self._last_tok = self._call_built(
+            "pt_first_token", (g, do_sample), fn,
             self._params, jnp.asarray(last), self.caches["kv"],
             jnp.asarray(rows), self._last_tok, jnp.asarray(ints),
             jnp.asarray(floats))
         self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
         self._samp_dev = None   # sampling params change -> re-upload lazily
         any_eos = any(r.eos_token_id is not None for _, r in ready)
-        firsts = np.asarray(firsts_dev) if any_eos else None
+        firsts = None
+        if any_eos:
+            with _wait_span(self, "first_token"):
+                firsts = np.asarray(firsts_dev)
+        with self._span("serve.emit", tokens=len(ready)) as sp:
+            self._emit_first(ready, firsts, firsts_dev)
+            sp.set(finished=sum(1 for _, r in ready if r.done))
+
+    def _emit_first(self, ready, firsts, firsts_dev):
+        """Book an admission wave's first tokens (values in ``firsts`` when
+        an eos id made the engine read them, else still on the device),
+        register the prompts' blocks and promote the slots to decoding."""
         entries = []
         ft_marks = [] if self.tracer is not None else None
         for row, (slot, req) in enumerate(ready):
@@ -2498,9 +2685,10 @@ class ContinuousBatchingEngine:
                 req.output.append(int(firsts[row]))
             else:
                 entries.append((row, req, 1))
-        if ft_marks:
+        if ft_marks and firsts is not None:
             # one lock acquisition for the whole admission wave's
-            # first-token + token stamps (not one per slot)
+            # first-token + token stamps (not one per slot). Values still
+            # on the device are stamped when they land (_drain_pending)
             self.tracer.first_tokens(ft_marks, tags=self.trace_tags)
         for row, (slot, req) in enumerate(ready):
             if ((firsts is not None and req.eos_token_id is not None
@@ -2510,7 +2698,7 @@ class ContinuousBatchingEngine:
                 self._mark_done(req)
                 self._release_slot(slot)
         if entries:
-            self._pending.append((firsts_dev, entries))
+            self._pending.append((firsts_dev, entries, ft_marks, True))
 
     def _admit_legacy(self):
         """Admit queued requests into free slots — ONE batched prefill call
@@ -2546,59 +2734,67 @@ class ContinuousBatchingEngine:
             # the device-resident last-token carry (no eager device ops here:
             # each is its own dispatch; cost not measured on the direct
             # runtime)
-            t0_tr = None if self.tracer is None else self.tracer.now()
-            firsts_dev = self._prefill_group(padded, grp)
+            with self._span("serve.prefill", tokens=padded * len(grp),
+                            rows=len(grp)):
+                firsts_dev = self._prefill_group(padded, grp)
+            firsts = None
+            if any(r.eos_token_id is not None for _, r in grp):
+                with _wait_span(self, "first_token"):
+                    firsts = np.asarray(firsts_dev)
+            with self._span("serve.emit", tokens=len(grp)) as sp:
+                self._emit_group(grp, firsts, firsts_dev)
+                sp.set(finished=sum(1 for _, r in grp if r.done))
+
+    def _emit_group(self, grp, firsts, firsts_dev):
+        """Book a legacy admission group's first tokens and occupy its
+        slots (``firsts``: the values when an eos id made the engine read
+        them, else None — they stay on the device)."""
+        entries = []
+        ft_marks = [] if self.tracer is not None else None
+        for row, (slot, req) in enumerate(grp):
+            self._temps[slot] = req.temperature
+            self._tops[slot] = req.top_p
+            self._topks[slot] = req.top_k
+            self._seeds[slot] = req.seed
+            self._slots[slot] = req
+            self._occupied[slot] = req
+            req._n_out += 1
+            self._sched_tokens += 1
             if self.tracer is not None:
-                self.tracer.span("prefill_group", None, t0_tr,
-                                 tags=self.trace_tags,
-                                 tokens=padded * len(grp), slots=len(grp))
-            any_eos = any(r.eos_token_id is not None for _, r in grp)
-            firsts = np.asarray(firsts_dev) if any_eos else None
-            entries = []
-            ft_marks = [] if self.tracer is not None else None
-            for row, (slot, req) in enumerate(grp):
-                self._temps[slot] = req.temperature
-                self._tops[slot] = req.top_p
-                self._topks[slot] = req.top_k
-                self._seeds[slot] = req.seed
-                self._slots[slot] = req
-                self._occupied[slot] = req
-                req._n_out += 1
-                self._sched_tokens += 1
-                if self.tracer is not None:
-                    now = _time.monotonic()
-                    self.tracer.admit(req.rid,
-                                      now - (req._enqueued_at or now),
-                                      miss_tokens=len(req.prompt),
-                                      tags=self._req_tags(req))
-                    ft_marks.append((req.rid, req._n_out))
-                self._pos[slot] = len(req.prompt) + 1
-                if self._fused:
-                    # static slot-owned tables in legacy layout: activation
-                    # only flips act/pos/sampling (+ the spec drafter seed)
-                    # via the traced scatter
-                    self._queue_update(slot, None, len(req.prompt) + 1, True,
-                                       req.seed, req.temperature, req.top_p,
-                                       req.top_k,
-                                       hist=(self._spec_seed(req.prompt)
-                                             if self._spec is not None
-                                             else None))
-                if firsts is not None:
-                    req.output.append(int(firsts[row]))
-                else:
-                    entries.append((row, req, 1))
-            if ft_marks:
-                # one lock acquisition for the group's first-token stamps
-                self.tracer.first_tokens(ft_marks, tags=self.trace_tags)
-            for row, (slot, req) in enumerate(grp):
-                if ((firsts is not None and req.eos_token_id is not None
-                     and int(firsts[row]) == req.eos_token_id)
-                        or req._n_out >= req.max_new_tokens):
-                    req.done = True
-                    self._mark_done(req)
-                    self._release_slot(slot)
-            if entries:
-                self._pending.append((firsts_dev, entries))
+                now = _time.monotonic()
+                self.tracer.admit(req.rid,
+                                  now - (req._enqueued_at or now),
+                                  miss_tokens=len(req.prompt),
+                                  tags=self._req_tags(req))
+                ft_marks.append((req.rid, req._n_out))
+            self._pos[slot] = len(req.prompt) + 1
+            if self._fused:
+                # static slot-owned tables in legacy layout: activation
+                # only flips act/pos/sampling (+ the spec drafter seed)
+                # via the traced scatter
+                self._queue_update(slot, None, len(req.prompt) + 1, True,
+                                   req.seed, req.temperature, req.top_p,
+                                   req.top_k,
+                                   hist=(self._spec_seed(req.prompt)
+                                         if self._spec is not None
+                                         else None))
+            if firsts is not None:
+                req.output.append(int(firsts[row]))
+            else:
+                entries.append((row, req, 1))
+        if ft_marks and firsts is not None:
+            # one lock acquisition for the group's first-token stamps;
+            # values still on the device are stamped as they land
+            self.tracer.first_tokens(ft_marks, tags=self.trace_tags)
+        for row, (slot, req) in enumerate(grp):
+            if ((firsts is not None and req.eos_token_id is not None
+                 and int(firsts[row]) == req.eos_token_id)
+                    or req._n_out >= req.max_new_tokens):
+                req.done = True
+                self._mark_done(req)
+                self._release_slot(slot)
+        if entries:
+            self._pending.append((firsts_dev, entries, ft_marks, True))
 
     def _bucket(self, n: int) -> int:
         if not self.prompt_buckets:
@@ -2631,8 +2827,8 @@ class ContinuousBatchingEngine:
             from ..core import autograd_engine
             from ..jit.api import _Swap
 
-            def run(params, ids, kv, all_tables, last_tok, ints, floats,
-                    _restep=restep, _sample=do_sample):
+            def pt_prefill_group(params, ids, kv, all_tables, last_tok, ints,
+                                 floats, _restep=restep, _sample=do_sample):
                 # ints [g, 4]: true_len, seed, top_k, slot; floats [g, 2]:
                 # temperature, top_p — packed so an admission moves THREE
                 # host->device buffers total (ids/ints/floats); the table
@@ -2658,16 +2854,18 @@ class ContinuousBatchingEngine:
                     keys = _fold_keys(seed, true_len)
                     nxt = sample_rows(logits, keys, temp, top_p, top_k)
                 else:
-                    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                    nxt = _greedy(logits)
                 return nxt, sub["kv"], last_tok.at[slots_].set(nxt)
 
-            fn = self._jit_prefill[(padded, restep, do_sample)] = jax.jit(run)
+            fn = self._jit_prefill[(padded, restep, do_sample)] = jax.jit(
+                pt_prefill_group)
             self._note_compiled()
         ints = np.asarray([[len(r.prompt), r.seed, r.top_k, s]
                            for s, r in grp], np.int32)
         floats = np.asarray([[r.temperature, r.top_p] for _, r in grp],
                             np.float32)
-        firsts, new_kv, self._last_tok = fn(
+        firsts, new_kv, self._last_tok = self._call_built(
+            "pt_prefill_group", (padded, restep, do_sample, len(grp)), fn,
             self._params, jnp.asarray(ids), self.caches["kv"],
             self.caches["tables"], self._last_tok,
             jnp.asarray(ints), jnp.asarray(floats))

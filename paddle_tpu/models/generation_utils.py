@@ -15,6 +15,8 @@ shape, and cache-length bucketing via ``max_length``.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -39,6 +41,7 @@ def validate_sampling(temperature, top_p, top_k=0):
         raise ValueError(f"top_k must be >= 0, got {top_k}")
 
 
+@functools.partial(jax.named_call, name="pt.sampler")
 def sample_rows(logits, keys, temps, top_ps, top_ks):
     """Row-vectorized sampling: per-row temperature/top-p/top-k/key.
 
@@ -66,8 +69,10 @@ def sample_rows(logits, keys, temps, top_ps, top_ks):
     return jnp.where(temps <= 0.0, greedy, sampled.astype(jnp.int32))
 
 
+@functools.partial(jax.named_call, name="pt.sampler")
 def fold_keys(seeds, positions):
-    """Stateless per-row keys: fold the token position into the request seed."""
+    """Stateless per-row keys: fold the token position into the request seed.
+    (Under the sampler's scope: the trace puts it with ``sample_rows``.)"""
     return jax.vmap(
         lambda s, p: jax.random.fold_in(jax.random.key(s), p))(seeds, positions)
 

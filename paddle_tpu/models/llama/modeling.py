@@ -15,6 +15,7 @@ FSDP model — no per-strategy layer forks like the reference's mpu vs plain nn.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -28,6 +29,13 @@ from ...nn import functional as F
 from ...nn import initializer as I
 from ...nn.layer.layers import Layer, LayerList
 from ..generation_utils import GenerationMixin, causal_cache_bias
+
+
+def _scope(name):
+    """Decorator: the method's ops carry ``name`` in their name stack (device
+    traces and lowered text; docs/OBSERVABILITY.md "Program spans and device
+    names"). Metadata only."""
+    return functools.partial(jax.named_call, name=name)
 
 
 class LlamaConfig:
@@ -168,6 +176,7 @@ class LlamaAttention(Layer):
         self.v_proj_weight = annotate(mk(h, self.num_kv_heads * hd), "embed", "heads")
         self.o_proj_weight = annotate(mk(self.num_heads * hd, h), "heads", "embed")
 
+    @_scope("pt.attn")
     def forward(self, hidden, cos, sin, attn_bias=None):
         b, s, h = hidden.shape if isinstance(hidden, Tensor) else hidden.shape
         hd = self.config.head_dim
@@ -193,6 +202,7 @@ class LlamaAttention(Layer):
         out = constrain(out, "batch", "seq", "embed")
         return out
 
+    @_scope("pt.attn")
     def decode_step(self, x, cos, sin, k_cache, v_cache, pos, pad_bias=None):
         """KV-cache attention for generation (used for prefill AND decode).
 
@@ -220,6 +230,7 @@ class LlamaAttention(Layer):
         out = out.reshape(b, s, self.num_heads * hd)
         return jnp.matmul(out, self.o_proj_weight._data), k_cache, v_cache
 
+    @_scope("pt.attn")
     def paged_decode_step(self, x, cos, sin, k_pages, v_pages, tables, pos):
         """Paged-KV generation step (serving suite, ops/paged_attention.py).
 
@@ -252,6 +263,7 @@ class LlamaAttention(Layer):
         out = out.reshape(b, s, self.num_heads * hd)
         return jnp.matmul(out, self.o_proj_weight._data), k_pages, v_pages
 
+    @_scope("pt.attn")
     def paged_prefill_chunk(self, x, cos, sin, k_pages, v_pages, tables,
                             starts):
         """Prefill CHUNK at PER-ROW absolute offsets over cached history
@@ -294,6 +306,7 @@ class LlamaAttention(Layer):
         out = gather_output_shards(out.reshape(b, s, -1))
         return jnp.matmul(out, self.o_proj_weight._data), k_pages, v_pages
 
+    @_scope("pt.attn")
     def paged_token_step(self, x, cos, sin, k_pages, v_pages, tables, pos_vec):
         """ONE token per row at PER-ROW positions (continuous batching:
         every slot is at a different decode offset). x: [b, 1, h];
@@ -375,6 +388,7 @@ class LlamaMLP(Layer):
         self.up_proj_weight = annotate(mk(h, m), "embed", "mlp")
         self.down_proj_weight = annotate(mk(m, h), "mlp", "embed")
 
+    @_scope("pt.mlp")
     def forward(self, x):
         from jax.ad_checkpoint import checkpoint_name
 
@@ -654,6 +668,7 @@ class LlamaForCausalLM(GenerationMixin, Layer):
         return (self.model.embed_tokens_weight._data.T
                 if self.lm_head_weight is None else self.lm_head_weight._data)
 
+    @_scope("pt.lm_head")
     def logits(self, hidden):
         out = jnp.matmul(hidden, self._lm_head_w())
         if self.lm_head_weight is not None:

@@ -73,7 +73,8 @@ def _stat_families(prefix: str, stats: dict, kinds: dict,
 
 
 # stats-dict keys that are level readings, not monotonic totals
-_ENGINE_GAUGE_KEYS = {"compile_cache_entries"}
+_ENGINE_GAUGE_KEYS = {"compile_cache_entries", "step_max_s",
+                      "step_max_wait_s"}
 # stats-dict keys NOT exported from engine.stats: "evictions" is a lagging
 # copy of radix.evictions (synced only at admit/brownout time) and the
 # collector already exports the live value as pt_radix_evictions_total —
@@ -83,9 +84,12 @@ _ENGINE_GAUGE_KEYS = {"compile_cache_entries"}
 # no pt_spec_* twin and stays in the auto-exported pt_engine_* set (the
 # verify-dispatch count is what shows spec degrading to 1-token
 # dispatches). The mesh counters export under their REQUIRED
-# pt_serving_* names below.
+# pt_serving_* names below; "steps" is pt_engine_steps_total below. The
+# rest of the program counters (step_wall_s, device_wait_s, decode_blocks,
+# decode_block_steps, programs_built, step_max_s, step_max_wait_s —
+# docs/OBSERVABILITY.md "Program spans and device names") auto-export.
 _ENGINE_SKIP_KEYS = {"evictions", "spec_proposed", "spec_accepted",
-                     "mesh_collective_bytes", "mesh_decode_steps"}
+                     "mesh_collective_bytes", "mesh_decode_steps", "steps"}
 
 
 def engine_collector(engine, **labels):

@@ -12,16 +12,24 @@ Span taxonomy (docs/OBSERVABILITY.md state machine):
 
     submit ─► admit(queue_wait) ─► prefill_chunk* ─► first_token
           └► shed                                       │
-                                                  decode_block*
+                                                     tokens*
                                                         │
                                   finish │ evict │ fail ◄┘
           (failover / migrate edges re-open a request on another replica)
 
 Timeline semantics: spans are HOST DISPATCH windows (jax dispatch is
-async — a decode block's span covers the host work that scheduled it, not
-device occupancy; device-side truth stays with ``jax.profiler``). TTFT is
-stamped when the first token is *scheduled*, matching what a streaming
-caller can first observe through the engine's async materialization.
+async — ``pt.serve.decode.dispatch``, the engine lane's span of a decode
+block, covers the host work that scheduled it, not device occupancy;
+device-side truth stays with ``jax.profiler``). TTFT and
+token progress are stamped when the token VALUES reach the host (on the
+path without eos that is ``_drain_pending``, not the dispatch) — the
+earliest a caller can read them.
+
+Program spans (:func:`program_span`, docs/OBSERVABILITY.md "Program spans
+and device names"): what the host does inside ``step()`` is written as
+``pt.*`` ``jax.profiler.TraceAnnotation``s — on the profiler's clock,
+beside the device plane — and, when a recorder is attached, also as spans
+on the engine lane here, each naming its parent.
 
 Crash/replay discipline (recovery.py): a re-admitted request keeps its
 ORIGINAL submit timestamp and first-token stamp (first wins — TTFT spans
@@ -48,7 +56,7 @@ from typing import Dict, List, Optional
 
 from .metrics import DEFAULT_LATENCY_BUCKETS_MS, MetricsRegistry
 
-__all__ = ["TraceRecorder"]
+__all__ = ["TraceRecorder", "program_span"]
 
 #: terminal event names — every submitted request must reach exactly one
 #: (unless it is re-opened by a failover/migration re-submit)
@@ -69,20 +77,15 @@ class TraceRecorder:
     buffer (oldest-first retention would reorder Perfetto lanes, so the
     buffer STOPS recording and counts drops instead — ``dropped``);
     per-request bookkeeping is bounded by ``max_requests`` with
-    terminal-request eviction. ``mirror_host_events=True`` additionally
-    feeds span durations into ``paddle_tpu.profiler``'s host-event table
-    so ``Profiler.summary()``'s OperatorView shows serving spans beside
-    model scopes.
+    terminal-request eviction.
     """
 
     def __init__(self, registry: Optional[MetricsRegistry] = None,
                  max_events: int = 200_000, max_requests: int = 100_000,
-                 mirror_host_events: bool = False,
                  clock=time.perf_counter):
         self.registry = registry if registry is not None else MetricsRegistry()
         self.max_events = int(max_events)
         self.max_requests = int(max_requests)
-        self.mirror_host_events = bool(mirror_host_events)
         self._clock = clock
         self._t0 = clock()
         # ONE recorder is shared by every replica of a fleet — under
@@ -187,20 +190,19 @@ class TraceRecorder:
 
     def span(self, name: str, rid: Optional[int], t0: float,
              t1: Optional[float] = None, tags: Optional[dict] = None,
-             **extra) -> None:
+             parent: Optional[str] = None, **extra) -> None:
+        """``parent``: the name of the enclosing program span (``pt.*``
+        spans nest; the chrome export carries it as ``args.parent``)."""
         t1 = self.now() if t1 is None else t1
         tags = tags or {}
+        if parent is not None:
+            extra["parent"] = parent
         with self._lock:
             self._emit({"name": name, "ph": "X", "ts": self._us(t0),
                         "dur": max(0.0, (t1 - t0) * 1e6),
                         "pid": int(tags.get("replica", 0)),
                         "tid": int(rid or 0),
                         "args": self._args(rid, tags, extra)})
-        if self.mirror_host_events:
-            from ..profiler import _host_events
-
-            _host_events.start(name, t0)
-            _host_events.stop(name, t1)
 
     # -- request lifecycle -------------------------------------------------
     def _track(self, rid: int) -> None:
@@ -285,7 +287,7 @@ class TraceRecorder:
         self.span("prefill_chunk", rid, t0, t1, tags, tokens=int(tokens))
 
     def first_token(self, rid: int, tags: Optional[dict] = None) -> None:
-        """First scheduled token. First stamp wins: a crash-replay twin
+        """First token on the host. First stamp wins: a crash-replay twin
         re-reaching its first token does NOT reset TTFT (the caller saw
         the original) — it records a tagged replay event instead."""
         with self._lock:
@@ -308,7 +310,7 @@ class TraceRecorder:
     def tokens(self, rid: int, total: int,
                tags: Optional[dict] = None) -> None:
         """Book streamed-token progress; ``total`` is the request's
-        cumulative scheduled-token count. Deduped against the journal
+        cumulative count of tokens on the host. Deduped against the journal
         high-water mark: during crash-replay catch-up the twin regenerates
         tokens the caller already has — those add nothing here."""
         with self._lock:
@@ -318,33 +320,12 @@ class TraceRecorder:
             self._streamed[rid] = int(total)
             self._c_tokens.inc(total - prev)
 
-    def decode_block(self, t0: float, n_steps: int, slots: int,
-                     t1: Optional[float] = None,
-                     tags: Optional[dict] = None,
-                     tokens: Optional[int] = None) -> None:
-        """Engine-lane span for one fused decode dispatch (tid 0 — block
-        work is batched across requests, so it has no single rid).
-        ``tokens`` carries the block's REAL emitted-token count: under
-        speculative decoding a dispatch emits a variable 1..K+1 tokens per
-        row, so TTFT/inter-token SLO math must read token progress off the
-        span, never infer it from n_steps x slots."""
-        extra = {} if tokens is None else {"tokens": int(tokens)}
-        self.span("decode_block", None, t0, t1, tags,
-                  n_steps=int(n_steps), slots=int(slots), **extra)
-
-    def decode_block_batch(self, t0: float, n_steps: int, slots: int,
-                           items, t1: Optional[float] = None,
-                           tags: Optional[dict] = None,
-                           tokens: Optional[int] = None) -> None:
-        """One decode block's full stamp set — the block span plus every
-        row's token progress — under a SINGLE lock acquisition (the
-        big-batch step path; per-slot locking is O(slots) contention per
-        block)."""
+    def tokens_batch(self, items, tags: Optional[dict] = None) -> None:
+        """Token progress of many rows — ``(rid, total)`` pairs — under one
+        lock acquisition."""
         with self._lock:
-            self.decode_block(t0, n_steps, slots, t1, tags, tokens=tokens)
-            if items:
-                for rid, total in items:
-                    self.tokens(rid, total, tags)
+            for rid, total in items:
+                self.tokens(rid, total, tags)
 
     def first_tokens(self, items, tags: Optional[dict] = None) -> None:
         """Batched first-token stamps for an admission wave: per rid the
@@ -549,3 +530,62 @@ class TraceRecorder:
                               if submitted else 0.0),
         }
         return out
+
+
+# -- program spans ----------------------------------------------------------
+# what the host does inside step(), on the profiler's clock
+
+_open = threading.local()      # per thread: names of the open program spans
+
+
+class program_span:
+    """``with program_span("serve.wait", tracer, tags, what="decode"):``
+    writes the span ``pt.serve.wait`` twice: as a
+    ``jax.profiler.TraceAnnotation`` (host plane of a profiler trace,
+    aligned with the device plane; an inactive ``TraceMe`` when no profiler
+    session is open) and, when ``recorder`` is a :class:`TraceRecorder`, on
+    its engine lane with ``parent`` = the enclosing program span of this
+    thread. Always written: there is no switch. ``set(**args)`` adds what
+    is only known at the end; ``elapsed_s`` holds the wall time after
+    exit. jax is imported at first use, so this package imports without
+    it."""
+
+    __slots__ = ("name", "recorder", "tags", "args", "elapsed_s", "_note",
+                 "_t0", "_r0")
+    _annotation = None
+
+    def __init__(self, name: str, recorder: Optional[TraceRecorder] = None,
+                 tags: Optional[dict] = None, note=None, **args):
+        self.name = "pt." + name
+        self.recorder, self.tags, self.args = recorder, tags, args
+        self.elapsed_s = 0.0
+        cls = note or program_span._annotation
+        if cls is None:
+            import jax
+
+            cls = program_span._annotation = jax.profiler.TraceAnnotation
+        self._note = cls(self.name, **args)
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+        self._note.set_metadata(**args)
+
+    def __enter__(self):
+        stack = _open.__dict__.setdefault("stack", [])
+        stack.append(self.name)
+        self._note.__enter__()
+        if self.recorder is not None:
+            self._r0 = self.recorder.now()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.elapsed_s = time.perf_counter() - self._t0
+        self._note.__exit__(*exc)
+        stack = _open.stack
+        stack.pop()
+        if self.recorder is not None:
+            self.recorder.span(self.name, None, self._r0, tags=self.tags,
+                               parent=stack[-1] if stack else None,
+                               **self.args)
+        return False

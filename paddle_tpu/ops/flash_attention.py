@@ -291,19 +291,21 @@ def _pallas_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         ]
         operands += [_seg_lanes(row_start.astype(jnp.int32), s_kv),
                      _seg_lanes(row_end.astype(jnp.int32), s_kv)]
-    res = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),          # acc
-            pltpu.VMEM((block_q, LSE_LANES), jnp.float32),  # running max
-            pltpu.VMEM((block_q, LSE_LANES), jnp.float32),  # running sum
-        ],
-        interpret=interpret,
-    )(*operands)
+    with jax.named_scope("pt_flash_fwd"):
+        res = pl.pallas_call(
+            kernel,
+            name="pt_flash_fwd",
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),          # acc
+                pltpu.VMEM((block_q, LSE_LANES), jnp.float32),  # running max
+                pltpu.VMEM((block_q, LSE_LANES), jnp.float32),  # running sum
+            ],
+            interpret=interpret,
+        )(*operands)
     lse = res[1] if with_lse else None
     return jnp.swapaxes(res[0], 1, 2), lse
 
@@ -599,21 +601,23 @@ def _pallas_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
                          lambda bi, hi, qi, ki: (bi, hi % hm,
                                                  _kv_idx_dq(qi, ki), 0)),
         ]
-    dq, delta = pl.pallas_call(
-        dq_kernel,
-        grid=grid_dq,
-        in_specs=dq_in_specs,
-        out_specs=[_qb, _qlanes],
-        out_shape=[
-            jax.ShapeDtypeStruct(qt.shape, q.dtype),
-            jax.ShapeDtypeStruct((b, hq, LSE_LANES, s_q), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((group * bq_dq, d), jnp.float32),          # dq acc
-            pltpu.VMEM((group * bq_dq, LSE_LANES), jnp.float32),  # delta
-        ],
-        interpret=interpret,
-    )(*dq_ops, *seg_ops)
+    with jax.named_scope("pt_flash_dq"):
+        dq, delta = pl.pallas_call(
+            dq_kernel,
+            name="pt_flash_dq",
+            grid=grid_dq,
+            in_specs=dq_in_specs,
+            out_specs=[_qb, _qlanes],
+            out_shape=[
+                jax.ShapeDtypeStruct(qt.shape, q.dtype),
+                jax.ShapeDtypeStruct((b, hq, LSE_LANES, s_q), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((group * bq_dq, d), jnp.float32),          # dq acc
+                pltpu.VMEM((group * bq_dq, LSE_LANES), jnp.float32),  # delta
+            ],
+            interpret=interpret,
+        )(*dq_ops, *seg_ops)
 
     # ---- dK / dV ----
     # q-heads blocked by `group` so one program sees every q-head of its
@@ -652,26 +656,28 @@ def _pallas_backward(q, k, v, o, lse, do, causal, scale, block_q, block_k,
             pl.BlockSpec((1, 1, bk_dq, LSE_LANES),
                          lambda bi, hi, ki, qi: (bi, hi % hm, ki, 0)),
         ]
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=grid_dkv,
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bk_dq, d),
-                         lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, bk_dq, d),
-                         lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(kt.shape, k.dtype),
-            jax.ShapeDtypeStruct(vt.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk_dq, d), jnp.float32),
-            pltpu.VMEM((bk_dq, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qt, kt, vt, dot, lse, delta, *seg_ops)
+    with jax.named_scope("pt_flash_dkv"):
+        dk, dv = pl.pallas_call(
+            dkv_kernel,
+            name="pt_flash_dkv",
+            grid=grid_dkv,
+            in_specs=dkv_in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, bk_dq, d),
+                             lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
+                pl.BlockSpec((1, 1, bk_dq, d),
+                             lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct(kt.shape, k.dtype),
+                jax.ShapeDtypeStruct(vt.shape, v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk_dq, d), jnp.float32),
+                pltpu.VMEM((bk_dq, d), jnp.float32),
+            ],
+            interpret=interpret,
+        )(qt, kt, vt, dot, lse, delta, *seg_ops)
 
     return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
             jnp.swapaxes(dv, 1, 2))

@@ -101,6 +101,9 @@ def _scan_bwd(res, g):
 _nll_sum_scan.defvjp(_scan_fwd, _scan_bwd)
 
 
+# every op of the loss holds "pt.fused_ce" in its name stack, the backward
+# as transpose(jvp(pt.fused_ce)): device traces find it by that name
+@functools.partial(jax.named_call, name="pt.fused_ce")
 def fused_linear_cross_entropy(hidden, w, labels, ignore_index: int = -100,
                                chunk: int = 1024, shift: bool = True):
     """Causal-LM loss ``mean(CE(hidden @ w, labels))`` without materializing

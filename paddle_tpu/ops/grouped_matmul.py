@@ -73,21 +73,24 @@ def _pgmm_raw(x, w, tile_gids, tile_m, interpret=False):
     nk = kdim // tk
     grid = (p // tm, n // tn, nk)
     kernel = functools.partial(_pgmm_kernel, nk=nk)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((tm, tk), lambda i, j, kk, g: (i, kk)),
-                pl.BlockSpec((1, tk, tn), lambda i, j, kk, g: (g[i], kk, j)),
-            ],
-            out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk, g: (i, j)),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((p, n), x.dtype),
-        interpret=interpret,
-    )(tile_gids, x, w)
+    with jax.named_scope("pt_grouped_matmul"):
+        return pl.pallas_call(
+            kernel,
+            name="pt_grouped_matmul",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec((tm, tk), lambda i, j, kk, g: (i, kk)),
+                    pl.BlockSpec((1, tk, tn),
+                                 lambda i, j, kk, g: (g[i], kk, j)),
+                ],
+                out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk, g: (i, j)),
+                scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
+            ),
+            out_shape=jax.ShapeDtypeStruct((p, n), x.dtype),
+            interpret=interpret,
+        )(tile_gids, x, w)
 
 
 def _pgmm_dw_kernel(gids_ref, x_ref, g_ref, dw_ref, *, nm):
@@ -147,21 +150,23 @@ def _pgmm_dw_call(x, dout, tile_gids, e, tile_m, interpret=False):
     nm = p // tm
     grid = (kdim // tk, n // tn, nm)   # m innermost: same-expert tiles are
     kernel = functools.partial(_pgmm_dw_kernel, nm=nm)  # consecutive
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((tm, tk), lambda i, j, mi, g: (mi, i)),
-                pl.BlockSpec((tm, tn), lambda i, j, mi, g: (mi, j)),
-            ],
-            out_specs=pl.BlockSpec((1, tk, tn),
-                                   lambda i, j, mi, g: (g[mi], i, j)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((e, kdim, n), jnp.float32),
-        interpret=interpret,
-    )(tile_gids, x, dout)
+    with jax.named_scope("pt_grouped_matmul_bwd"):
+        return pl.pallas_call(
+            kernel,
+            name="pt_grouped_matmul_bwd",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec((tm, tk), lambda i, j, mi, g: (mi, i)),
+                    pl.BlockSpec((tm, tn), lambda i, j, mi, g: (mi, j)),
+                ],
+                out_specs=pl.BlockSpec((1, tk, tn),
+                                       lambda i, j, mi, g: (g[mi], i, j)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((e, kdim, n), jnp.float32),
+            interpret=interpret,
+        )(tile_gids, x, dout)
 
 
 def _gid_zero_cot(gids):

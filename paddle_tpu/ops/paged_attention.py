@@ -335,20 +335,24 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        # all three dims "arbitrary": the double-buffer prefetch chain carries
-        # SMEM/semaphore state ACROSS batch boundaries, so no grid dim may be
-        # split across megacores
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(context_lens, block_tables.reshape(-1),
-      jnp.zeros((1,), jnp.int32),   # buffer index
-      jnp.ones((1,), jnp.int32),    # init flag
-      qr, k_cache, v_cache)
+    # the kernel's name reaches the HLO instruction and the scope its name
+    # stack: traces find the kernel by name, not by a shape
+    with jax.named_scope("pt_paged_decode"):
+        out = pl.pallas_call(
+            kernel,
+            name="pt_paged_decode",
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
+            # all three dims "arbitrary": the double-buffer prefetch chain carries
+            # SMEM/semaphore state ACROSS batch boundaries, so no grid dim may be
+            # split across megacores
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+            interpret=interpret,
+        )(context_lens, block_tables.reshape(-1),
+          jnp.zeros((1,), jnp.int32),   # buffer index
+          jnp.ones((1,), jnp.int32),    # init flag
+          qr, k_cache, v_cache)
     return out.reshape(b, hq, d)
 
 
@@ -678,6 +682,7 @@ class RadixPrefixCache:
 # cache maintenance (XLA scatters — bandwidth-bound, no kernel needed)
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.named_call, name="pt.kv_write")
 def append_paged_kv(k_cache, v_cache, k_new, v_new, block_tables, positions,
                     seq_ids=None):
     """Scatter new tokens into the page pool.

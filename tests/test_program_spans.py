@@ -1,0 +1,400 @@
+"""Program spans and device names (docs/OBSERVABILITY.md): the ``pt.*``
+host spans inside ``step()``, the always-on counters beside them, the names
+the device sees (module names, kernel names, name scopes), and the tracer
+stamps at materialisation on the path without eos."""
+
+import glob
+import os
+
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.inference.serving import (ContinuousBatchingEngine,
+                                          PrefixCacheConfig, Request, SpecConfig)
+from paddle_tpu.observability.tracing import TraceRecorder, program_span
+
+EOS = 1 << 20       # an eos id no token reaches: every block is read back
+
+
+@pytest.fixture(scope="module")
+def model():
+    paddle.seed(11)
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    cfg = LlamaConfig.tiny(num_hidden_layers=1)
+    return cfg, LlamaForCausalLM(cfg)
+
+
+def _engine(m, tracer=None, **kw):
+    kw.setdefault("prefix_cache",
+                  PrefixCacheConfig(prefill_chunk=16, extra_blocks=8))
+    return ContinuousBatchingEngine(m, max_batch=4, max_len=64, page_size=8,
+                                    block_size=4, fused=True, tracer=tracer,
+                                    **kw)
+
+
+def _requests(cfg, eos, n=5):
+    rng = np.random.default_rng(5)
+    return [Request(rng.integers(3, cfg.vocab_size, 6 + 5 * i).astype(np.int32),
+                    max_new_tokens=5 + i, eos_token_id=eos, seed=i + 1,
+                    **(dict(temperature=0.8, top_p=0.9) if i % 2 else {}))
+            for i in range(n)]
+
+
+def _run(eng, reqs):
+    for r in reqs:
+        eng.add_request(r)
+    calls = 0
+    while eng.has_work():
+        eng.step()
+        calls += 1
+    eng.finished()
+    return calls
+
+
+@pytest.fixture(scope="module")
+def served(model):
+    """A fused prefix-cache engine, warmed by one wave, then a second wave
+    under a fresh recorder: (engine, recorder, calls, stats before/after)."""
+    cfg, m = model
+    eng = _engine(m)
+    for _ in range(2):                      # cold, then prefix-warm: every
+        _run(eng, _requests(cfg, EOS))      # program of the third wave built
+    rec = TraceRecorder()
+    eng.tracer = rec
+    before = dict(eng.stats)
+    calls = _run(eng, _requests(cfg, EOS))
+    return eng, rec, calls, before, dict(eng.stats)
+
+
+def _pt(rec):
+    return [e for e in rec.events if e["name"].startswith("pt.")]
+
+
+def test_step_spans_nest_and_name_their_parent(served):
+    _, rec, calls, _, _ = served
+    spans = _pt(rec)
+    steps = [e for e in spans if e["name"] == "pt.serve.step"]
+    assert len(steps) == calls
+    assert all("parent" not in e["args"] for e in steps)
+    assert [e["args"]["step"] for e in steps] == sorted(
+        e["args"]["step"] for e in steps)
+    names = {e["name"] for e in spans}
+    assert {"pt.serve.admit", "pt.serve.prefill",
+            "pt.serve.decode.dispatch", "pt.serve.wait",
+            "pt.serve.emit"} <= names
+    by_name = {}
+    for e in spans:
+        by_name.setdefault(e["name"], []).append(e)
+    # every other span lies inside the span it names as its parent
+    for e in spans:
+        if e["name"] == "pt.serve.step":
+            continue
+        parent = e["args"]["parent"]
+        assert parent.startswith("pt.serve."), e
+        assert any(p["ts"] <= e["ts"] + 1e-3
+                   and e["ts"] + e["dur"] <= p["ts"] + p["dur"] + 1e-3
+                   for p in by_name[parent]), e
+    # what a span is for rides its args
+    d = by_name["pt.serve.decode.dispatch"][-1]["args"]
+    assert {"n_steps", "rows", "do_sample"} <= set(d)
+    assert {"admitted", "deferred"} <= set(by_name["pt.serve.admit"][0]["args"])
+    assert {"tokens", "finished"} <= set(by_name["pt.serve.emit"][0]["args"])
+    assert by_name["pt.serve.wait"][0]["args"]["what"] in (
+        "decode_block", "first_token", "pending")
+
+
+def test_children_cover_the_step(served):
+    """Under 5% of the steps' time lies outside a child span."""
+    _, rec, _, _, _ = served
+    spans = _pt(rec)
+    total = sum(e["dur"] for e in spans if e["name"] == "pt.serve.step")
+    covered = sum(e["dur"] for e in spans
+                  if e["args"].get("parent") == "pt.serve.step")
+    assert total > 0
+    assert covered <= total * (1 + 1e-6)
+    assert (total - covered) / total < 0.05
+
+
+def test_counters_count_what_ran(served):
+    _, rec, calls, s0, s1 = served
+    assert s1["steps"] - s0["steps"] == calls
+    spans = _pt(rec)
+    blocks = [e for e in spans if e["name"] == "pt.serve.decode.dispatch"
+              and "n_steps" in e["args"]]
+    assert s1["decode_blocks"] - s0["decode_blocks"] == len(blocks)
+    assert (s1["decode_block_steps"] - s0["decode_block_steps"]
+            == sum(e["args"]["n_steps"] for e in blocks))
+    wall = s1["step_wall_s"] - s0["step_wall_s"]
+    wait = s1["device_wait_s"] - s0["device_wait_s"]
+    assert 0.0 < wait <= wall
+    # the waits are the pt.serve.wait spans (recorder and counter agree to
+    # within the clock reads around them)
+    in_spans = sum(e["dur"] for e in spans if e["name"] == "pt.serve.wait")
+    assert in_spans * 1e-6 == pytest.approx(wait, rel=0.2, abs=2e-3)
+    assert 0.0 < s1["step_max_s"] and \
+        0.0 <= s1["step_max_wait_s"] <= s1["step_max_s"]
+    # warmed: the second wave built nothing
+    assert s1["programs_built"] == s0["programs_built"] > 0
+    assert not [e for e in spans if e["name"] == "pt.serve.build"]
+
+
+def test_first_calls_run_under_build_spans(model):
+    cfg, m = model
+    rec = TraceRecorder()
+    eng = _engine(m, tracer=rec)
+    _run(eng, _requests(cfg, EOS, n=3))
+    builds = [e for e in _pt(rec) if e["name"] == "pt.serve.build"]
+    assert len(builds) == eng.stats["programs_built"] > 0
+    programs = {e["args"]["program"] for e in builds}
+    assert {"pt_decode_block", "pt_prefill_chunk", "pt_first_token",
+            "pt_slot_update"} <= programs
+    # each decode length and sampling mode is a program of its own, which
+    # compile_cache_entries (one entry for the mega-step) does not count
+    decode = {e["args"]["key"] for e in builds
+              if e["args"]["program"] == "pt_decode_block"}
+    assert len(decode) > 1
+    assert eng.stats["programs_built"] > eng.stats["compile_cache_entries"]
+    assert all(e["args"]["parent"] in ("pt.serve.decode.dispatch",
+                                       "pt.serve.prefill", "pt.serve.admit")
+               for e in builds)
+
+
+def test_first_call_runs_above_a_frame_chunk_of_its_own():
+    """``first_call`` hands its arguments through and asks CPython for a
+    frame larger than any 16 KiB chunk of the frame stack, so that the
+    frames of jax's tracing and lowering above it share one chunk (PERF.md
+    section 6, PR 24: the warm set-up moved by 30% with the alignment)."""
+    import sys
+
+    from paddle_tpu.framework.compile_cache import first_call
+
+    seen = {}
+
+    def fn(a, b=0, *, c=0):
+        seen["caller"] = sys._getframe(1).f_code.co_name
+        return a, b, c
+
+    assert first_call(fn, 1, 2, c=3) == (1, 2, 3)
+    assert seen["caller"] == "first_call"
+    assert first_call.__code__.co_stacksize * 8 > 16 * 1024 * 32
+    with pytest.raises(ZeroDivisionError):
+        first_call(lambda: 1 / 0)
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "legacy"])
+def test_stamps_wait_for_the_values_without_eos(model, fused):
+    """On the path without eos nothing is read at dispatch: first_token,
+    token progress and the terminal are stamped when ``_drain_pending``
+    brings the values to the host, in lifecycle order."""
+    cfg, m = model
+    rec = TraceRecorder()
+    eng = (_engine(m, tracer=rec) if fused else ContinuousBatchingEngine(
+        m, max_batch=2, max_len=64, page_size=8, block_size=4, fused=False,
+        tracer=rec))
+    reqs = _requests(cfg, None, n=2)
+    for r in reqs:
+        eng.add_request(r)
+    while eng.has_work():
+        eng.step()
+    assert all(r.done for r in reqs)
+    # done by the schedule, values still on the device: nothing stamped
+    assert eng._pending
+    assert rec.slo_summary()["tokens_streamed"] == 0
+    assert rec.slo_summary()["p50_time_to_first_token_ms"] is None
+    assert len(rec.incomplete()) == 2
+    eng.finished()                                     # drains
+    assert rec.slo_summary()["tokens_streamed"] == sum(
+        len(r.output) for r in reqs)
+    assert rec.slo_summary()["p50_time_to_first_token_ms"] is not None
+    assert rec.incomplete() == []
+    for r in reqs:
+        chain = rec.lifecycle(r.rid)
+        assert chain[0] == "submit" and chain[-1] == "finish"
+        assert chain.index("first_token") < chain.index("finish")
+        assert chain.count("finish") == 1
+
+
+def test_spec_block_spans_and_stamps(model):
+    cfg, m = model
+    rec = TraceRecorder()
+    eng = _engine(m, tracer=rec, speculative=SpecConfig(k=2))
+    reqs = [Request(np.tile(np.arange(3, 9, dtype=np.int32), 3),
+                    max_new_tokens=8, seed=i + 1) for i in range(2)]
+    _run(eng, reqs)
+    assert eng.stats["spec_steps"] > 0
+    builds = {e["args"]["program"] for e in _pt(rec)
+              if e["name"] == "pt.serve.build"}
+    assert "pt_spec_block" in builds
+    waits = {e["args"]["what"] for e in _pt(rec)
+             if e["name"] == "pt.serve.wait"}
+    assert "spec_emit" in waits
+    assert rec.slo_summary()["tokens_streamed"] == sum(
+        len(r.output) for r in reqs)
+    assert rec.incomplete() == []
+
+
+def test_program_span_is_an_inactive_traceme_without_a_session():
+    """No recorder, no profiler session: nothing is kept, the stack of
+    open spans unwinds, and ``elapsed_s`` still reads."""
+    from paddle_tpu.observability import tracing
+
+    with program_span("serve.step", step=1) as outer:
+        with program_span("serve.wait", what="x") as inner:
+            assert tracing._open.stack == ["pt.serve.step", "pt.serve.wait"]
+        inner.set(more=1)
+    assert tracing._open.stack == []
+    assert outer.elapsed_s >= inner.elapsed_s >= 0.0
+    with pytest.raises(RuntimeError):
+        with program_span("serve.step"):
+            raise RuntimeError("boom")
+    assert tracing._open.stack == []
+
+
+def _host_names(trace_dir):
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            names |= {e.name for e in line.events if e.name.startswith("pt.")}
+    return names
+
+
+def test_profiler_host_plane_holds_the_spans(served, tmp_path):
+    """Under ``jax.profiler.start_trace`` the same spans land on the host
+    plane, beside the device's."""
+    import jax
+
+    from paddle_tpu.distributed.auto_parallel import Engine
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    eng, _, _, _, _ = served
+    cfg = eng.model.config
+    paddle.seed(3)
+    tcfg = LlamaConfig.tiny(num_hidden_layers=1)
+    trainer = Engine(LlamaForCausalLM(tcfg), mesh=None, lr=1e-3)
+    ids = np.random.default_rng(0).integers(
+        0, tcfg.vocab_size, (2, 16)).astype(np.int32)
+    jax.block_until_ready(trainer.step(ids, ids))      # built outside
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _run(eng, _requests(cfg, EOS, n=3))
+        jax.block_until_ready(trainer.step(ids, ids))
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_names(str(tmp_path))
+    assert {"pt.serve.step", "pt.serve.admit", "pt.serve.prefill",
+            "pt.serve.decode.dispatch", "pt.serve.wait", "pt.serve.emit",
+            "pt.train.step"} <= names
+
+
+# ---- the names the device sees ----------------------------------------------
+
+def _lowered(fn, *args, **kw):
+    return fn.lower(*args, **kw).as_text(debug_info=True)
+
+
+def test_serving_programs_carry_their_names(served):
+    import jax.numpy as jnp
+
+    eng, _, _, _, _ = served
+    B = eng.max_batch
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)            # noqa: E731
+    f32 = lambda *s: jnp.zeros(s, jnp.float32)          # noqa: E731
+    kv, tables = eng.caches["kv"], eng.caches["tables"]
+    text = _lowered(eng._jit_mega, eng._params, i32(B), kv, tables, i32(B),
+                    jnp.zeros(B, bool), i32(B), f32(B), f32(B), i32(B),
+                    n_steps=2, do_sample=True)
+    assert "module @jit_pt_decode_block" in text
+    for scope in ("pt.sampler", "pt.attn", "pt.mlp", "pt.lm_head",
+                  "pt.kv_write"):
+        assert scope + "/" in text, scope
+    greedy = _lowered(eng._jit_mega, eng._params, i32(B), kv, tables, i32(B),
+                      jnp.zeros(B, bool), i32(B), f32(B), f32(B), i32(B),
+                      n_steps=1, do_sample=False)
+    assert '"pt.sampler"' in greedy             # the all-greedy arm too
+    C, P = eng._chunk_tokens, eng._maxp
+    text = _lowered(eng._chunk_fn(2), eng._params, i32(2, C), kv, i32(2, P),
+                    i32(2))
+    assert "module @jit_pt_prefill_chunk" in text
+    assert "pt.attn/pt.kv_write/" in text
+    (g, _), first = next((k, fn) for k, fn in eng._jit_first.items()
+                         if k[1])
+    text = _lowered(first, eng._params, i32(g), kv, i32(g, P), eng._last_tok,
+                    i32(g, 4), f32(g, 2))
+    assert "module @jit_pt_first_token" in text and "pt.sampler/" in text
+    samp = eng._dev_samp
+    text = _lowered(eng._jit_apply, tables, eng._dev_pos, eng._dev_act, *samp,
+                    i32(1, 1), i32(1), np.zeros(eng._upd_width, np.int32),
+                    np.zeros((eng._upd_width, P), np.int32),
+                    *[np.zeros(eng._upd_width, d) for d in
+                      (np.int32, bool, np.int32, np.float32, np.float32,
+                       np.int32)], i32(1, 1), i32(1))
+    assert "module @jit_pt_slot_update" in text
+    if eng._jit_cow_batch:
+        w, fn = next(iter(eng._jit_cow_batch.items()))
+        assert "module @jit_pt_cow_copy" in _lowered(fn, kv, i32(w), i32(w))
+
+
+def test_legacy_spec_and_reset_programs_carry_their_names(model):
+    cfg, m = model
+    eng = ContinuousBatchingEngine(m, max_batch=2, max_len=64, page_size=8,
+                                   block_size=4, fused=False)
+    _run(eng, _requests(cfg, None, n=2))
+    assert eng._jit_step.__wrapped__.__name__ == "pt_decode_block"
+    assert {fn.__wrapped__.__name__ for fn in eng._jit_prefill.values()} == {
+        "pt_prefill_group"}
+    spec = _engine(m, speculative=SpecConfig(k=2), kv_cache="int8")
+    _run(spec, [Request(np.tile(np.arange(3, 9, dtype=np.int32), 3),
+                        max_new_tokens=6)])
+    assert spec._jit_spec.__wrapped__.__name__ == "pt_spec_block"
+    assert {fn.__wrapped__.__name__ for fn in spec._jit_qreset.values()} == {
+        "pt_kv_reset"}
+
+
+def test_train_step_carries_its_name_and_scopes():
+    import jax.numpy as jnp
+
+    from paddle_tpu.distributed.auto_parallel import Engine
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    paddle.seed(3)
+    cfg = LlamaConfig.tiny(num_hidden_layers=1)
+    eng = Engine(LlamaForCausalLM(cfg), mesh=None, lr=1e-3, clip_norm=1.0)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    text = _lowered(eng._build_step(), eng.params, eng.m, eng.v,
+                    eng.step_count, ids, ids)
+    assert "module @jit_pt_train_step" in text
+    # forward ops under jvp(<scope>), the backward pass under
+    # transpose(jvp(<scope>)): a reader finds both by the scope's name
+    for scope in ("pt.attn", "pt.mlp", "pt.fused_ce"):
+        assert f"/jvp({scope})/" in text, scope
+        assert f"/transpose(jvp({scope}))/" in text, scope
+    assert "/pt.optimizer/" in text
+    eng.eval_loss(ids, ids)
+    assert "module @jit_pt_eval_loss" in _lowered(
+        eng._jit_loss, eng.params, ids, ids)
+
+
+@pytest.mark.parametrize("kernel", ["pt_paged_decode", "pt_flash_fwd",
+                                    "pt_flash_dq", "pt_flash_dkv",
+                                    "pt_grouped_matmul",
+                                    "pt_grouped_matmul_bwd"])
+def test_pallas_kernels_are_named_in_the_source(kernel):
+    """Each kernel is a ``pallas_call(name=...)`` under a scope of the same
+    name (their lowering for the chip cannot run on the CPU; the AOT check
+    of chipbench/scratch reads the names in the compiled program)."""
+    import importlib
+    import inspect
+
+    src = "".join(inspect.getsource(importlib.import_module(
+        "paddle_tpu.ops." + mod)) for mod in
+        ("flash_attention", "grouped_matmul", "paged_attention"))
+    assert f'with jax.named_scope("{kernel}"):' in src
+    assert f'name="{kernel}",' in src

@@ -229,10 +229,15 @@ def test_fused_crash_replay_bit_identical(model, tmp_path):
         assert list(got.tokens) == list(want.tokens)
 
 
-def test_tracer_batched_stamps_equal_per_slot_stamps():
-    """decode_block_batch / first_tokens / tokens_batch (one lock per
-    step) must book exactly what the per-slot calls book."""
-    from paddle_tpu.observability.tracing import TraceRecorder
+@pytest.mark.parametrize("values_on_host", [True, False],
+                         ids=["values_on_host", "values_pending"])
+def test_tracer_batched_stamps_equal_per_slot_stamps(values_on_host):
+    """first_tokens / tokens_batch (one lock per step) must book exactly
+    what the per-slot calls book: every row's token progress beside the
+    engine-lane ``pt.serve.decode.dispatch`` program span — right after the
+    dispatch when the block's values are on the host, and later
+    (``tokens_batch`` from ``_drain_pending``) when they are not."""
+    from paddle_tpu.observability.tracing import TraceRecorder, program_span
 
     a, b = TraceRecorder(), TraceRecorder()
     for rid in (1, 2):
@@ -242,17 +247,26 @@ def test_tracer_batched_stamps_equal_per_slot_stamps():
     for rid in (1, 2):
         a.first_token(rid)
         a.tokens(rid, 1)
-    a.decode_block(a.now(), 4, 2)
+    a.span("pt.serve.decode.dispatch", None, a.now(), n_steps=4, rows=2,
+           parent=None)
     for rid in (1, 2):
         a.tokens(rid, 5)
     # batched stamping (fused shape)
     b.first_tokens([(1, 1), (2, 1)])
-    b.decode_block_batch(b.now(), 4, 2, [(1, 5), (2, 5)])
+    with program_span("serve.decode.dispatch", b, n_steps=4, rows=2):
+        pass
+    if not values_on_host:
+        assert b.slo_summary()["tokens_streamed"] == 2   # not yet on host
+    b.tokens_batch([(1, 5), (2, 5)])
     sa, sb = a.slo_summary(), b.slo_summary()
     assert sa["tokens_streamed"] == sb["tokens_streamed"] == 10
     assert sa["submitted"] == sb["submitted"] == 2
     assert ([e["name"] for e in a.events if e["tid"] == 1]
             == [e["name"] for e in b.events if e["tid"] == 1])
+    span_a, = [e for e in a.events if e["name"] == "pt.serve.decode.dispatch"]
+    span_b, = [e for e in b.events if e["name"] == "pt.serve.decode.dispatch"]
+    assert span_a["args"] == span_b["args"] == {"n_steps": 4, "rows": 2}
+    assert span_a["tid"] == span_b["tid"] == 0      # the engine lane
 
 
 @pytest.mark.slow   # one 128-row compile wave (~3-4 min budget class) —

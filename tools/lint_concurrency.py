@@ -46,7 +46,8 @@ BASELINE_PATH = os.path.join(ROOT, "tools", "concurrency_baseline.json")
 #: locks). Reviewed alongside the baseline file.
 _TRACER_API = ["TraceRecorder." + m for m in (
     "submit", "shed", "admit", "prefill_chunk", "first_token", "tokens",
-    "decode_block", "finish", "mark_recovered", "failover", "migrate",
+    "tokens_batch", "first_tokens", "finish", "mark_recovered", "failover",
+    "migrate",
     "migration_failure", "recovery", "publish", "resume", "instant",
     "span", "is_open", "incomplete", "lifecycle", "export_chrome",
     "slo_summary", "counters")]
