@@ -431,8 +431,6 @@ def phase_kernels(tmp: str) -> None:
 
     from paddle_tpu.ops.flash_attention import (_xla_reference,
                                                 flash_attention)
-    from paddle_tpu.ops.paged_attention import (paged_decode_attention,
-                                                paged_decode_reference)
 
     # flash attention at the trainer's shape: [b, s, h, d], GQA 16/4
     b, s, d = TRAIN_BATCH, TRAIN_SEQ, 128
@@ -463,27 +461,71 @@ def phase_kernels(tmp: str) -> None:
     for name, g, rg in zip(("dq", "dk", "dv"), grads, ref_grads):
         _check_close(f"flash {name}", g, rg)
 
-    # paged decode at the server's shape, ragged lengths, one empty row
-    hq = hkv = SERVE_MODEL["num_attention_heads"]
-    maxp = SERVE_MAX_LEN // SERVE_PAGE
-    n_pages = SERVE_SLOTS * maxp
+    # paged decode at the server's shape (MHA 16/16, 8 pages a row), and at
+    # the shape class of the benchmark's serving cell (GQA 16/8, 24 rows of
+    # 128 pages, ragged to 2,048); one empty row in each
+    heads = SERVE_MODEL["num_attention_heads"]
+    _paged_decode_check("paged decode", heads, heads,
+                        SERVE_MAX_LEN // SERVE_PAGE,
+                        [1, 16, 17, 0, 64, 100, 127, 128])
+    _paged_decode_check("paged decode gqa", 16, 8, 128, CELL_CONTEXTS)
+
+
+#: 24 context lengths like the chat-batch cell's: 80 to 2,048 around a mean
+#: of 700, one row empty
+CELL_CONTEXTS = [593, 478, 348, 2048, 485, 837, 897, 80, 1828, 1540, 418, 0,
+                 853, 411, 549, 997, 980, 349, 365, 345, 475, 502, 380, 764]
+#: HBM bytes a second of one v5e chip (Google Cloud documentation, "TPU v5e")
+V5E_HBM_BYTES_S = 819e9
+
+
+def _paged_decode_check(what: str, hq: int, hkv: int, maxp: int,
+                        contexts) -> None:
+    """The paged decode kernel against its reference at "highest", head_dim
+    128 and the server's page size; prints (and asserts nothing about) its
+    time a call, chained in one program, and the share of the chip's
+    bandwidth that the bytes the benchmark reckons for the call make of it
+    (whole pages of context, q and o)."""
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench.ops.paged_decode import paged_decode_bytes
+    from paddle_tpu.ops.paged_attention import (paged_decode_attention,
+                                                paged_decode_reference)
+
+    b, d, page = len(contexts), 128, SERVE_PAGE
+    n_pages = b * maxp
     kq, kk, kv, kt = jax.random.split(jax.random.key(SEED + 1), 4)
-    q = jax.random.normal(kq, (SERVE_SLOTS, hq, d), jnp.bfloat16)
-    kc = jax.random.normal(kk, (n_pages, hkv, SERVE_PAGE, d), jnp.bfloat16)
-    vc = jax.random.normal(kv, (n_pages, hkv, SERVE_PAGE, d), jnp.bfloat16)
+    q = jax.random.normal(kq, (b, hq, d), jnp.bfloat16)
+    kc = jax.random.normal(kk, (n_pages, hkv, page, d), jnp.bfloat16)
+    vc = jax.random.normal(kv, (n_pages, hkv, page, d), jnp.bfloat16)
     tables = jax.random.permutation(kt, n_pages).reshape(
-        SERVE_SLOTS, maxp).astype(jnp.int32)
-    lens = jnp.asarray([1, 16, 17, 0, 64, 100, 127, 128], jnp.int32)
+        b, maxp).astype(jnp.int32)
+    lens = jnp.asarray(contexts, jnp.int32)
     paged = jax.jit(paged_decode_attention)
     calls = _count_kernels(paged.lower(q, kc, vc, tables, lens).as_text())
-    _check(calls == 1, f"kernels/paged: decode lowered to {calls} "
+    _check(calls == 1, f"kernels/{what}: decode lowered to {calls} "
                        f"tpu_custom_call(s), expected the one Pallas kernel")
     got = paged(q, kc, vc, tables, lens)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(paged_decode_reference)(q, kc, vc, tables, lens)
-    _check_close("paged decode", got, want)
-    _check(not bool(jnp.any(got[3] != 0)),
-           "kernels/paged: the zero-length row is not all zeros")
+    _check_close(what, got, want)
+    empty = contexts.index(0)
+    _check(not bool(jnp.any(got[empty] != 0)),
+           f"kernels/{what}: the zero-length row is not all zeros")
+
+    n_calls = 200
+    chained = jax.jit(lambda q: jax.lax.fori_loop(
+        0, n_calls, lambda _, x: paged_decode_attention(
+            x, kc, vc, tables, lens), q))
+    jax.block_until_ready(chained(q))
+    t0 = time.perf_counter()
+    jax.block_until_ready(chained(q))
+    us = (time.perf_counter() - t0) / n_calls * 1e6
+    nbytes = paged_decode_bytes(contexts, hkv, hq, d, page)
+    print(f"kernels/{what}: {us:.1f} us a call over {n_calls} chained calls, "
+          f"{nbytes / 1e6:.1f} MB: {nbytes / V5E_HBM_BYTES_S / us * 1e8:.1f}% "
+          f"of {V5E_HBM_BYTES_S / 1e9:.0f} GB/s", flush=True)
 
 
 def _spread(what: str, arr) -> None:

@@ -11,15 +11,24 @@ Layouts (reference block_multihead_attention):
   block_tables:    [batch, pages_per_seq] int32 (-1 = unassigned)
   context_lens:    [batch] int32 — tokens already in cache (incl. current step)
 
-Decode kernel design (measured 435 GB/s-class architecture, v5e):
-  - grid (batch, kv_heads, seq_chunks); each chunk DMAs G pages of ONE kv head
-    HBM→VMEM. The chunk loop is a *grid* dimension, so double buffering runs
-    across grid steps: an SMEM buffer index persists, and each step prefetches
-    the NEXT VALID (b, h, chunk) step's pages while computing its own.
-  - context lengths arrive via scalar prefetch; chunks past a sequence's
-    length are skipped entirely (no DMA, no compute).
-  - online softmax in fp32 with VMEM carry across chunks; GQA computes all
-    `group` q-heads of the kv head in one [group, G*page] block.
+Decode kernel design (v5e, PERF.md section 6, PR 25: 105 us a call at the
+chat-batch cell's shapes, 80% of the time its bytes take at 819 GB/s):
+  - the unit of work is (row, chunk of pages) for ALL KV heads. The pool's
+    layout makes a page's [kv_heads, page, d] one contiguous block, so one DMA
+    a page and pool side brings it into a VMEM buffer laid out
+    [kv_heads, chunk_tokens, d]: every head's chunk is one tile.
+  - grid (batch,); inside a step a loop walks the row's chunks and carries
+    the online softmax in float32. While a chunk computes, the next one is
+    in flight in the other buffer slot: the row's next chunk, or after its
+    last one the next row's first, so the chain runs across rows. An SMEM
+    word carries the slot from row to row.
+  - context lengths and block tables arrive via scalar prefetch. Only pages
+    that hold context are fetched (a row's last chunk is cut at its last
+    page); rows of length 0 fetch and compute nothing.
+  - the chunk's size follows from the shapes (`_decode_chunk_pages`): page
+    size, KV heads, head_dim, the pool's dtype, pages a row, a VMEM budget.
+  - all heads of a chunk are two batched dots, `q.K^T` and `p.V`, with
+    `group` query rows a KV head.
 """
 
 from __future__ import annotations
@@ -171,106 +180,133 @@ def paged_decode_reference(q, k_cache, v_cache, block_tables, context_lens,
 # Pallas decode kernel
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(lens_ref, tables_ref, buf_idx, init_ref,
-                         q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, acc_ref, m_ref, l_ref,
-                         sem, *, page, G, max_pages, scale, group, hkv, batch):
-    bi, hi, ci = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    chunk_tokens = page * G
-    ctx = lens_ref[bi]
-    # every (b, h) processes AT LEAST one chunk even at length 0 — otherwise a
-    # zero-length row would break the prefetch chain and the next valid row
-    # would wait on semaphores armed with the wrong pages (its own output is
-    # forced to zeros at the final-store below; neighbors must stay correct)
-    n_chunks_b = jnp.maximum((ctx + chunk_tokens - 1) // chunk_tokens, 1)
+#: VMEM the decode kernel spends on page buffers (K and V, two slots each),
+#: and the longest chunk of context it computes at once
+_DECODE_VMEM_BUDGET = 4 << 20
+_DECODE_MAX_CHUNK_TOKENS = 512
 
-    def chunk_copies(slot, b2, h2, c2):
-        out = []
-        for g in range(G):
-            pidx = jnp.maximum(tables_ref[b2 * max_pages + c2 * G + g], 0)
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[pidx, h2], k_buf.at[slot, g], sem.at[slot, 0]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[pidx, h2], v_buf.at[slot, g], sem.at[slot, 1]))
-        return out
 
-    def next_step(b2, h2, c2):
-        # lexicographic next VALID step in (b, h, chunk) grid order —
-        # chunks beyond a sequence's length are skipped by everyone
-        # (min 1 chunk per (b, h): matches n_chunks_b above)
-        nb = jnp.maximum((lens_ref[b2] + chunk_tokens - 1) // chunk_tokens, 1)
-        c3 = c2 + 1
-        roll_h = c3 >= nb
-        h3 = jnp.where(roll_h, h2 + 1, h2)
-        c3 = jnp.where(roll_h, 0, c3)
-        roll_b = h3 >= hkv
-        b3 = jnp.where(roll_b, b2 + 1, b2)
-        h3 = jnp.where(roll_b, 0, h3)
-        return b3, h3, c3
+def _decode_chunk_pages(max_pages, hkv, page, d, itemsize):
+    """Pages in one unit of the decode kernel's work, from what the call can
+    see: as many as fit the VMEM budget (every page brings all its KV heads,
+    for K and for V, double buffered), no more than
+    ``_DECODE_MAX_CHUNK_TOKENS`` of context, no more than a row's table.
+    On the v5e at the chat-batch shapes (8 KV heads, page 16, head_dim 128,
+    bf16) chunks of 128 / 256 / 512 tokens ran 121 / 107 / 105 us a call."""
+    page_bytes = 4 * hkv * page * d * itemsize
+    fit = min(_DECODE_VMEM_BUDGET // page_bytes,
+              _DECODE_MAX_CHUNK_TOKENS // page, max_pages)
+    return max(fit, 1)
 
-    @pl.when(ci < n_chunks_b)
+
+def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sem, slot_ref, *, page, C, max_pages,
+                         scale, batch):
+    bi = pl.program_id(0)
+    hkv, group, d = q_ref.shape[1:]
+    CT = C * page
+
+    def row_pages(row):
+        return jnp.minimum((lens_ref[row] + page - 1) // page, max_pages)
+
+    def copies(slot, pidx, g):
+        return (pltpu.make_async_copy(k_hbm.at[pidx], k_buf.at[slot, :, g],
+                                      sem.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[pidx], v_buf.at[slot, :, g],
+                                      sem.at[slot, 1]))
+
+    def start_chunk(slot, row, c, n):
+        """Fetch the first ``n`` pages of chunk ``c`` of ``row``: one DMA a
+        page and pool side, [kv_heads, page, d] each."""
+        def body(g, _):
+            pidx = jnp.maximum(tables_ref[row * max_pages + c * C + g], 0)
+            for cp in copies(slot, pidx, g):
+                cp.start()
+            return 0
+        jax.lax.fori_loop(0, n, body, 0)
+
+    def wait_chunk(slot, n):
+        def body(g, _):
+            for cp in copies(slot, 0, g):
+                cp.wait()
+            return 0
+        jax.lax.fori_loop(0, n, body, 0)
+
+    @pl.when(bi == 0)
     def _():
-        # very first valid step of the whole grid: no one prefetched for us
-        # (init flag arrives as a scalar-prefetch input set to 1 by the caller
-        # and is cleared here — SMEM scratch is NOT zero-initialized)
-        @pl.when(init_ref[0] == 1)
-        def _():
-            init_ref[0] = 0
-            buf_idx[0] = 0
-            for c in chunk_copies(0, bi, hi, ci):
-                c.start()
+        # nobody fetched for the first row. The buffers start as zeros: a
+        # row's last chunk fetches only the pages that hold context, what the
+        # rest of the buffer holds meets p == 0, and 0 * x is 0 only for a
+        # finite x (scratch memory is not initialised)
+        slot_ref[0] = 0
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        start_chunk(0, 0, 0, jnp.minimum(row_pages(0), C))
 
-        cur = buf_idx[0]
-        b3, h3, c3 = next_step(bi, hi, ci)
+    ctx = lens_ref[bi]
+    n_pages = row_pages(bi)
+    n_chunks = (n_pages + C - 1) // C
+    slot0 = slot_ref[0]
+    nxt_row = jnp.minimum(bi + 1, batch - 1)
+    nxt_first = jnp.where(bi + 1 < batch,
+                          jnp.minimum(row_pages(nxt_row), C), 0)
 
-        @pl.when(b3 < batch)
-        def _():
-            for c in chunk_copies(1 - cur, b3, h3, c3):
-                c.start()
-        for c in chunk_copies(cur, bi, hi, ci):
-            c.wait()
-        buf_idx[0] = 1 - cur
+    # bf16 q and K go to the MXU as stored (float32 accumulation, the scale
+    # applied to the scores); anything else is computed from float32
+    q = q_ref[0]                                            # [hkv, group, d]
+    if not q.dtype == k_buf.dtype == jnp.bfloat16:
+        q = q.astype(jnp.float32)
 
-        @pl.when(ci == 0)
-        def _():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
+    def chunk_step(c, carry):
+        m_prev, l_prev, acc = carry
+        slot = (slot0 + c) % 2
+        # fetch what is computed next while this chunk computes: the row's
+        # next chunk, or after its last one the next row's first
+        last = c + 1 == n_chunks
+        start_chunk(1 - slot, jnp.where(last, nxt_row, bi),
+                    jnp.where(last, 0, c + 1),
+                    jnp.where(last, nxt_first,
+                              jnp.minimum(n_pages - (c + 1) * C, C)))
+        wait_chunk(slot, jnp.minimum(n_pages - c * C, C))
 
-        d = q_ref.shape[-1]
-        q = q_ref[0, 0].astype(jnp.float32) * scale        # [group, d]
-        kb = k_buf[cur].reshape(chunk_tokens, d).astype(jnp.float32)
-        vb = v_buf[cur].reshape(chunk_tokens, d).astype(jnp.float32)
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [group, CT]
-        pos = ci * chunk_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, (group, chunk_tokens), 1)
-        s = jnp.where(pos < ctx, s, NEG_INF)
-
-        m_prev = m_ref[:, :1]                               # [group, 1]
-        l_prev = l_ref[:, :1]
+        k = k_buf[slot].reshape(hkv, CT, d).astype(q.dtype)
+        # p.V from float32 p and V, as before; Mosaic's default-precision
+        # float32 dot is one bf16 pass on the MXU (PERF.md section 6, PR 25)
+        v = v_buf[slot].reshape(hkv, CT, d).astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        pos = c * CT + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(pos < ctx, s, NEG_INF)                # [hkv, group, CT]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        acc = acc * alpha + jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
 
-        @pl.when(ci == n_chunks_b - 1)
-        def _():
-            l_fin = l_ref[:, :1]
-            l_safe = jnp.where(l_fin > 0, l_fin, 1.0)
-            out = acc_ref[...] / l_safe
-            # zero-length rows (freed/parked slots) emit zeros, not garbage —
-            # callers may rely on inactive rows being inert
-            o_ref[0, 0] = jnp.where(ctx > 0, out, 0.0).astype(o_ref.dtype)
+    _, l, acc = jax.lax.fori_loop(
+        0, n_chunks, chunk_step,
+        (jnp.full((hkv, group, 1), NEG_INF, jnp.float32),
+         jnp.zeros((hkv, group, 1), jnp.float32),
+         jnp.zeros((hkv, group, d), jnp.float32)))
+    slot_ref[0] = (slot0 + n_chunks) % 2
+
+    @pl.when(n_chunks == 0)
+    def _():
+        # an empty row fetched nothing and computes nothing, but the next row
+        # still counts on this step for its first chunk
+        start_chunk(slot0, nxt_row, 0, nxt_first)
+
+    # zero-length rows (freed/parked slots) emit zeros, not garbage — callers
+    # may rely on inactive rows being inert
+    out = acc / jnp.where(l > 0, l, 1.0)
+    o_ref[0] = jnp.where(ctx > 0, out, 0.0).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
-                           scale=None, pages_per_chunk: int = 4,
-                           interpret: bool = False):
+                           scale=None, interpret: bool = False):
     """One-token-per-sequence paged decode.
 
     q: [batch, q_heads, head_dim]; caches [num_pages, kv_heads, page, d];
@@ -279,6 +315,14 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
     must already be appended via append_paged_kv; rows with length 0 return
     ZEROS — freed/parked serving slots are guaranteed inert). Returns
     [batch, hq, d].
+
+    On a TPU every bf16/float32 pool whose pages Mosaic can slice runs the
+    Pallas kernel, short tables included: the rule that sent rows of fewer
+    than two chunks to the XLA gather was written for the per-(row, head)
+    chain (3 ms at 8 rows x 16 heads x 2 pages) and is gone. Measured on the
+    v5e, MHA 16/16 over 8 rows: 8 pages a row, ragged to 128 tokens, kernel
+    12.9 us against the gather's 19.5 (the old kernel 133); 2 pages a row,
+    9.2 us against 7.0 — 2 us a call is not worth a second path.
     """
     b, hq, d = q.shape
     n_pages, hkv, page, _ = k_cache.shape
@@ -294,45 +338,35 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
     # Mosaic page-DMA slicing needs a 128-aligned trailing dim and a
     # sublane-aligned page dim — 8 sublanes at 4-byte, 16 at 2-byte, 32 at
     # 1-byte (int8 KV cache); other shapes take the dense-gather fallback
-    sublane = {4: 8, 2: 16, 1: 32}.get(jnp.dtype(k_cache.dtype).itemsize, 8)
+    itemsize = jnp.dtype(k_cache.dtype).itemsize
+    sublane = {4: 8, 2: 16, 1: 32}.get(itemsize, 8)
     shapes_ok = d % 128 == 0 and page % sublane == 0
     if not interpret and (jax.default_backend() != "tpu" or not shapes_ok):
         return paged_decode_reference(q, k_cache, v_cache, block_tables,
                                       context_lens, scale)
     max_pages = block_tables.shape[1]
-    G = pages_per_chunk
-    while max_pages % G:
-        G -= 1
-    n_chunks = max_pages // G
-    # single-chunk rows have nothing to stream: the kernel's serial per-(b,h)
-    # DMA chain is pure latency (~measured 3 ms in-situ at b8·h16·2 pages vs
-    # ~µs for the XLA gather+einsum), so short-context serving routes to the
-    # dense-gather path; the kernel wins once chunks per row >= 2
-    if n_chunks < 2 and not interpret:
-        return paged_decode_reference(q, k_cache, v_cache, block_tables,
-                                      context_lens, scale)
-    qr = q.reshape(b, hkv, group, d)
+    C = _decode_chunk_pages(max_pages, hkv, page, d, itemsize)
 
     kernel = functools.partial(
-        _paged_decode_kernel, page=page, G=G, max_pages=max_pages,
-        scale=float(scale), group=group, hkv=hkv, batch=b)
+        _paged_decode_kernel, page=page, C=C, max_pages=max_pages,
+        scale=float(scale), batch=b)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b, hkv, n_chunks),
+        num_scalar_prefetch=2,
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, group, d), lambda bi, hi, ci, *_: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, hkv, group, d), lambda bi, *_: (bi, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, d),
-                               lambda bi, hi, ci, *_: (bi, hi, 0, 0)),
+        out_specs=pl.BlockSpec((1, hkv, group, d),
+                               lambda bi, *_: (bi, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, G, page, d), k_cache.dtype),
-            pltpu.VMEM((2, G, page, d), v_cache.dtype),
-            pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
+            # [slot, kv head, page of the chunk, token, d]: a head's chunk is
+            # one [chunk_tokens, d] tile, a page's DMA lands in every head's
+            pltpu.VMEM((2, hkv, C, page, d), k_cache.dtype),
+            pltpu.VMEM((2, hkv, C, page, d), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     # the kernel's name reaches the HLO instruction and the scope its name
@@ -343,16 +377,14 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
             name="pt_paged_decode",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-            # all three dims "arbitrary": the double-buffer prefetch chain carries
-            # SMEM/semaphore state ACROSS batch boundaries, so no grid dim may be
-            # split across megacores
+            # "arbitrary": the prefetch chain carries the buffer slot and the
+            # DMAs in flight from one row to the next, so the rows may not
+            # be split across cores
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+                dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(context_lens, block_tables.reshape(-1),
-          jnp.zeros((1,), jnp.int32),   # buffer index
-          jnp.ones((1,), jnp.int32),    # init flag
-          qr, k_cache, v_cache)
+          q.reshape(b, hkv, group, d), k_cache, v_cache)
     return out.reshape(b, hq, d)
 
 

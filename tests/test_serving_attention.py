@@ -42,20 +42,67 @@ def _dense_attn(q, k, v, causal=True):
 # paged decode kernel
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("group", [1, 4])
-def test_paged_decode_matches_reference(group):
+def _chunk_tokens(hkv, d, page, maxp, dtype):
+    """Tokens of one unit of the kernel's work at these shapes."""
+    from paddle_tpu.ops.paged_attention import _decode_chunk_pages
+
+    return page * _decode_chunk_pages(maxp, hkv, page, d,
+                                      jnp.dtype(dtype).itemsize)
+
+
+def _cell_class_lens(hkv, maxp):
+    """Lengths around every edge of the kernel's units at the chat-batch
+    cell's shape class (head_dim 128, page 16, bf16 pools): an empty row, one
+    token, exactly a page, exactly a chunk, a chunk plus one, a ragged middle
+    and the full table."""
+    ct = _chunk_tokens(hkv, 128, 16, maxp, jnp.bfloat16)
+    assert ct < maxp * 16 and (maxp * 16) % ct, "maxp leaves no part chunk"
+    return [0, 1, 16, ct, ct + 1, ct + 16 * 3 + 5, maxp * 16]
+
+
+# (id, hq, hkv, d, page, maxp, dtype, lens or a function of (hkv, maxp))
+_DECODE_CASES = [
+    ("group1", 2, 2, 64, 16, 4, jnp.float32, [37, 16, 5]),
+    ("group4", 8, 2, 64, 16, 4, jnp.float32, [37, 16, 5]),
+    # the chat-batch cell's shape class: GQA 16/8, two query rows a KV head
+    ("cell-gqa16x8", 16, 8, 128, 16, 72, jnp.bfloat16, _cell_class_lens),
+    # what a tp shard of that model calls the kernel with: its local heads
+    ("tp-shard-1kv", 2, 1, 128, 16, 72, jnp.bfloat16, _cell_class_lens),
+    ("tp-shard-2kv", 4, 2, 128, 16, 72, jnp.bfloat16, _cell_class_lens),
+    # float32 pools, several chunks a row, a table that is no whole number
+    # of chunks
+    ("f32-chunks", 4, 2, 128, 8, 150, jnp.float32,
+     lambda hkv, maxp: [0, 1, 8, 512, 513, 777, 1200]),
+]
+
+
+@pytest.mark.parametrize("case", _DECODE_CASES, ids=lambda c: c[0])
+def test_paged_decode_matches_reference(case):
+    _, hq, hkv, d, page, maxp, dtype, lens = case
+    if callable(lens):
+        lens = lens(hkv, maxp)
     rng = np.random.default_rng(0)
-    b, hkv, d, page, maxp, npages = 3, 2, 64, 16, 4, 16
-    hq = hkv * group
-    q = _rand((b, hq, d), 0)
-    kc = _rand((npages, hkv, page, d), 1)
-    vc = _rand((npages, hkv, page, d), 2)
-    tables = jnp.asarray(rng.permutation(npages)[: b * maxp].reshape(b, maxp),
-                         jnp.int32)
-    lens = jnp.asarray([37, 16, 5], jnp.int32)
+    b = len(lens)
+    npages = b * maxp
+    q = _rand((b, hq, d), 0, dtype)
+    kc = _rand((npages, hkv, page, d), 1, dtype)
+    vc = _rand((npages, hkv, page, d), 2, dtype)
+    tables = rng.permutation(npages).reshape(b, maxp).astype(np.int32)
+    # entries past a row's context are unassigned, as the engine leaves them
+    for i, n in enumerate(lens):
+        tables[i, -(-n // page):] = -1
+    tables = jnp.asarray(tables)
+    lens = jnp.asarray(lens, jnp.int32)
     ref = paged_decode_reference(q, kc, vc, tables, lens)
     out = paged_decode_attention(q, kc, vc, tables, lens, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    # bf16 results may land one rounding apart
+    atol = 2e-5 if dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=atol)
+    for i, n in enumerate(np.asarray(lens)):
+        if n == 0:
+            assert not np.asarray(out[i], np.float32).any()
 
 
 def test_paged_decode_zero_length_neighbors_intact():
