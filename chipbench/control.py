@@ -79,7 +79,8 @@ class ServingBench:
         self.vocab = int(cfg["vocab_size"])
         self.model = cell.adapter.build_model(
             cfg, max_positions=int(cell.spec["engine"]["max_len"]))
-        cell.adapter.assign(self.model, W.model_weights(cfg, first_seed))
+        cell.adapter.assign(self.model,
+                            W.model_weights(cell.leaf_table, first_seed))
         self.engine = serving.build_engine(cell, self.model)
         sched = cell.generator.generate(cell.traffic, first_seed, self.vocab)
         serving.warm_up(self.engine, cell, self.vocab, sched.eos_token_id,
@@ -103,7 +104,7 @@ class ServingBench:
         eng.finished()
         if eng.has_work():
             raise RuntimeError("control: the engine did not come out empty")
-        w = W.model_weights(self.cell.config, seed)
+        w = W.model_weights(self.cell.leaf_table, seed)
         if lower is not None:
             w = jax.jit(lambda t: jax.tree_util.tree_map(lower, t),
                         donate_argnums=0)(w)

@@ -106,9 +106,9 @@ def served_stats(cell, seed: int, sample, sampling: dict, lower=None,
     ``lower`` picks them. Weights are made a layer at a time."""
     import jax
 
-    cfg, ref = cell.config, cell.reference
-    top = W.top_weights(cfg, seed)
-    layer = lambda i: W.layer_weights(cfg, seed, i)
+    cfg, ref, table = cell.config, cell.reference, cell.leaf_table
+    top = W.top_weights(table, seed)
+    layer = lambda i: W.layer_weights(table, seed, i)
     rows = []
     for lv in sample:
         out = np.asarray(lv.req.output, np.int32)
@@ -220,11 +220,11 @@ def reference_training(cell, seed: int, batches, hyper: dict, lower=None):
     import jax
     import jax.numpy as jnp
 
-    cfg, ref = cell.config, cell.reference
+    cfg, ref, table = cell.config, cell.reference, cell.leaf_table
     b1, b2 = float(hyper["beta1"]), float(hyper["beta2"])
     kw = dict(lr=float(hyper["lr"]), b1=b1, b2=b2, eps=float(hyper["epsilon"]),
               wd=float(hyper["weight_decay"]))
-    p = W.model_weights(cfg, seed, dtype=jnp.float32)
+    p = W.model_weights(table, seed, dtype=jnp.float32)
     if lower is not None:
         p = jax.jit(lambda t: jax.tree_util.tree_map(lower, t),
                     donate_argnums=0)(p)
@@ -262,7 +262,7 @@ def reference_training(cell, seed: int, batches, hyper: dict, lower=None):
         gk = flat_g.pop(k)
         g1_host[k] = np.asarray(gk)
         flat_p[k] = step1(flat_p[k], gk)
-    n_layers = cfg["num_hidden_layers"]
+    n_layers = len(table["layers"])
     tree = {k: v for k, v in flat_p.items() if "." not in k}
     tree["layers"] = [{k.split(".", 1)[1]: flat_p[k] for k in flat_p
                        if k.startswith(f"{i}.")} for i in range(n_layers)]
@@ -278,10 +278,10 @@ def reference_training(cell, seed: int, batches, hyper: dict, lower=None):
             i, leaf = k.split(".", 1)
             if ("L", i) not in start:
                 start.clear()
-                start[("L", i)] = W.layer_weights(cfg, seed, int(i))
+                start[("L", i)] = W.layer_weights(table, seed, int(i))
             s = start[("L", i)][leaf]
         else:
-            s = W.top_weights(cfg, seed, (k,))[k]
+            s = W.top_weights(table, seed, (k,))[k]
         if lower is not None:
             s = lower(s)
         dn[k] = float(step2(flat_p.pop(k), jnp.asarray(g1_host.pop(k)),
@@ -304,7 +304,7 @@ def program_norms(adapter, names, arrays, scale: float = 1.0):
     return {k: float(v) * scale for k, v in zip(keys, norms)}, vec
 
 
-def program_change(adapter, names, arrays, cfg, seed):
+def program_change(adapter, names, arrays, table, seed):
     """Per-leaf norm of (parameter now - seeded parameter). The seeded
     values are made again a layer at a time, so that the readout adds one
     layer's weights to the program's memory peak and not the whole model's."""
@@ -316,8 +316,8 @@ def program_change(adapter, names, arrays, cfg, seed):
     leaves = [adapter.leaf_of(n) for n in names]
     out = {}
     for layer in sorted({l for l, _ in leaves}, key=lambda l: (l is None, l)):
-        w0 = (W.top_weights(cfg, seed, dtype=jnp.bfloat16) if layer is None
-              else W.layer_weights(cfg, seed, layer, dtype=jnp.bfloat16))
+        w0 = (W.top_weights(table, seed, dtype=jnp.bfloat16) if layer is None
+              else W.layer_weights(table, seed, layer, dtype=jnp.bfloat16))
         for n, (l, leaf), a in zip(names, leaves, arrays):
             if l == layer:
                 out[leaf_key(adapter, n)] = float(diff(a, w0[leaf]))

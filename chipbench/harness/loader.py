@@ -9,6 +9,7 @@ configuration, a traffic mix or a metric.
     chipbench/cells/<name>.json         -> driver, engine/trainer sizes, limits
     chipbench/metrics/<metric>.py       -> reader of each per-layer metric
     chipbench/adapters/<adapter>.py, chipbench/reference/<reference>.py
+                                        (the reference states the leaf table)
     chipbench/rehearse/<name>.json      -> tiny preset for --rehearse (optional)
 """
 
@@ -70,6 +71,12 @@ class Cell:
     generator: object     # module of the traffic kind
     adapter: object
     reference: object
+
+    @property
+    def leaf_table(self) -> dict:
+        """The family's leaves as its reference states them for this
+        configuration: what ``harness/weights.py`` draws."""
+        return self.reference.leaf_table(self.config)
 
 
 def merge(base: dict, over: dict) -> dict:

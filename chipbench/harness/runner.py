@@ -116,7 +116,7 @@ def _serve(cell, seed, seconds, spans, tracer, counter, t_process):
     with spans.span("build"):
         model = cell.adapter.build_model(
             cfg, max_positions=int(cell.spec["engine"]["max_len"]))
-        cell.adapter.assign(model, W.model_weights(cfg, seed))
+        cell.adapter.assign(model, W.model_weights(cell.leaf_table, seed))
         engine = serving.build_engine(cell, model)
     serving.warm_up(engine, cell, vocab, sched.eos_token_id, sched.sampling,
                     spans)

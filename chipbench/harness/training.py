@@ -34,7 +34,7 @@ def build(cell, seed: int):
     model = cell.adapter.build_model(
         cell.config, max_positions=int(cell.traffic["seq_len"]),
         recompute=bool(t.get("recompute", False)))
-    cell.adapter.assign(model, W.model_weights(cell.config, seed))
+    cell.adapter.assign(model, W.model_weights(cell.leaf_table, seed))
     h = hyper_of(cell)
     return Engine(model, mesh=None, lr=h["lr"], clip_norm=h["clip_norm"],
                   beta1=h["beta1"], beta2=h["beta2"], epsilon=h["epsilon"],
@@ -77,8 +77,8 @@ def first_steps(cell, eng, feed, seed: int, names):
     gn, vec = check.program_norms(cell.adapter, names, eng.m,
                                   scale=1.0 / (1.0 - h["beta1"]))
     loss2, _ = feed.step()
-    dn = check.program_change(cell.adapter, names, eng.params, cell.config,
-                              seed)
+    dn = check.program_change(cell.adapter, names, eng.params,
+                              cell.leaf_table, seed)
     return {"loss": [loss1, loss2], "grad_norm": gn, "gain_grad": vec,
             "change_norm": dn}
 

@@ -26,6 +26,35 @@ import jax.numpy as jnp
 
 HIGHEST = "highest"
 
+#: leaves of one decoder layer and of the model's top, in key order
+LAYER_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "w_gate",
+                "w_up", "w_down")
+TOP_LEAVES = ("embed", "final_norm", "head")
+#: the leaves drawn as gains (1 + 0.05 * normal); every other is std * normal
+GAINS = ("attn_norm", "mlp_norm", "final_norm")
+
+
+def leaf_shapes(cfg: dict) -> dict:
+    """Shape of every leaf kind for a decoder configuration (HF keys)."""
+    h, f, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
+    hd = cfg.get("head_dim") or h // cfg["num_attention_heads"]
+    q, kv = cfg["num_attention_heads"] * hd, cfg["num_key_value_heads"] * hd
+    return {"attn_norm": (h,), "wq": (h, q), "wk": (h, kv), "wv": (h, kv),
+            "wo": (q, h), "mlp_norm": (h,), "w_gate": (h, f), "w_up": (h, f),
+            "w_down": (f, h), "embed": (v, h), "final_norm": (h,),
+            "head": (h, v)}
+
+
+def leaf_table(cfg: dict) -> dict:
+    """What ``harness/weights.py`` draws for this family: every layer has
+    the same leaves, ``(name, shape, kind)`` in key order."""
+    shapes = leaf_shapes(cfg)
+    leaves = lambda names: tuple(
+        (n, shapes[n], "gain" if n in GAINS else "normal") for n in names)
+    return {"std": float(cfg.get("initializer_range", 0.02)),
+            "top": leaves(TOP_LEAVES),
+            "layers": (leaves(LAYER_LEAVES),) * cfg["num_hidden_layers"]}
+
 
 def _rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
