@@ -40,7 +40,10 @@ class Tensor:
             data = data._data
         if dtype is not None:
             dtype = dtype_mod.convert_dtype(dtype)
-        if isinstance(data, (jax.Array, jax.core.Tracer)):
+        if isinstance(data, jax.ShapeDtypeStruct):
+            # a parameter built under LazyGuard: shape and type, no array
+            self._data = data
+        elif isinstance(data, (jax.Array, jax.core.Tracer)):
             self._data = data.astype(dtype) if (dtype is not None and data.dtype != dtype) else data
         else:
             if dtype is None and isinstance(data, (float,)):
@@ -282,7 +285,7 @@ jax.tree_util.register_pytree_node(Tensor, _tensor_flatten, _tensor_unflatten)
 class Parameter(Tensor):
     """Trainable tensor (reference: python/paddle/base/framework.py EagerParamBase)."""
 
-    __slots__ = ("trainable", "optimize_attr", "regularizer", "need_clip", "initialized")
+    __slots__ = ("trainable", "optimize_attr", "regularizer", "need_clip")
 
     def __init__(self, data, dtype=None, name=None, trainable=True):
         super().__init__(data, dtype=dtype, stop_gradient=not trainable, name=name)
@@ -292,7 +295,12 @@ class Parameter(Tensor):
         self.optimize_attr = {"learning_rate": 1.0}
         self.regularizer = None
         self.need_clip = True
-        self.initialized = True
+
+    @property
+    def initialized(self) -> bool:
+        """False while the parameter, built under ``LazyGuard``, holds a
+        shape and a type and no array."""
+        return not isinstance(self._data, jax.ShapeDtypeStruct)
 
 
 jax.tree_util.register_pytree_node(

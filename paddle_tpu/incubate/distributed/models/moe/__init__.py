@@ -1,2 +1,4 @@
-from .gate import BaseGate, GShardGate, NaiveGate, SwitchGate, topk_dispatch  # noqa: F401
-from .moe_layer import ExpertFFN, MoELayer, SwiGLUExpertFFN  # noqa: F401
+from .gate import (BaseGate, GShardGate, NaiveGate, SigmoidGate,  # noqa: F401
+                   SwitchGate, topk_dispatch)
+from .moe_layer import (DroplessMoE, ExpertFFN, MoELayer,  # noqa: F401
+                        SwiGLUExpertFFN, dropless_ffn)

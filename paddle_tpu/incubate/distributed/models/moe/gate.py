@@ -179,3 +179,54 @@ class SwitchGate(NaiveGate):
                 1.0 - self.switch_eps, 1.0 + self.switch_eps)
             logits = logits * noise
         return jax.nn.softmax(logits, axis=-1)
+
+
+class SigmoidGate(BaseGate):
+    """Sigmoid scores, bias-corrected choice, gates renormalised over the
+    chosen (the DeepSeek-V3 / LFM2 router). ``s = sigmoid(x . W)``; the
+    ``topk`` experts chosen are the largest of ``s + b`` (``b`` the expert
+    bias: it corrects the load and takes part in the CHOICE only); the
+    gates are ``s`` at the chosen, divided by their sum + ``norm_eps`` when
+    ``renormalize``, times ``scaling``. No capacity, no aux loss: the bias
+    is what balances. The matmul, the sigmoid and the top-k run in float32
+    whatever the model's type: a choice that flips on a bf16 rounding sends
+    a token through other experts.
+
+    ``route(x) -> (expert_idx [n, k] int32, gates [n, k] f32)`` is what a
+    dropless expert layer takes (``DroplessMoE``)."""
+
+    use_aux = False
+
+    def __init__(self, d_model, num_expert, topk=2, use_bias=True,
+                 renormalize=True, scaling=1.0, norm_eps=1e-6,
+                 initializer_range=0.02):
+        super().__init__(num_expert, 1)
+        self.top_k = int(topk)
+        self.renormalize = bool(renormalize)
+        self.scaling = float(scaling)
+        self.norm_eps = float(norm_eps)
+        self.gate_weight = self.create_parameter(
+            [d_model, num_expert], dtype="float32",
+            default_initializer=I.Normal(std=initializer_range))
+        self.expert_bias = (self.create_parameter(
+            [num_expert], dtype="float32", is_bias=True)
+            if use_bias else None)
+
+    def scores(self, inp):
+        x = _raw(inp).astype(jnp.float32)
+        w = self.gate_weight._data.astype(jnp.float32)
+        return jax.nn.sigmoid(jnp.matmul(
+            x, w, precision=jax.lax.Precision.HIGHEST))
+
+    def route(self, inp):
+        s = self.scores(inp)
+        pick = s if self.expert_bias is None else (
+            s + self.expert_bias._data.astype(jnp.float32))
+        _, idx = jax.lax.top_k(pick, self.top_k)
+        g = jnp.take_along_axis(s, idx, axis=-1)
+        if self.renormalize:
+            g = g / (g.sum(-1, keepdims=True) + self.norm_eps)
+        return idx.astype(jnp.int32), g * self.scaling
+
+    def forward(self, inp):
+        return self.route(inp)

@@ -16,6 +16,7 @@ the role of the reference's hand-issued alltoall; docs/MOE_AB.md).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -26,7 +27,7 @@ from .....core.tensor import Tensor
 from .....distributed.auto_parallel.logical_sharding import annotate, constrain
 from .....nn import initializer as I
 from .....nn.layer.layers import Layer
-from .gate import BaseGate, GShardGate, NaiveGate, SwitchGate
+from .gate import BaseGate, GShardGate, NaiveGate, SigmoidGate, SwitchGate
 
 
 def _raw(x):
@@ -348,3 +349,107 @@ class MoELayer(Layer):
     def get_loss(self, clear=True):
         """The gate's aux (load-balance) loss for this forward."""
         return self.gate.get_loss(clear=clear)
+
+
+# ---- dropless routed FFN: the choice and the gates come from the gate ------
+
+#: rows up to which the dense arm serves. Measured on the v5e at 64 rows
+#: only (64 experts of 2048 x 1536, a layer): dense 1.67 ms, 88% of the time
+#: the experts' bytes take, sorted 3.76 ms (PERF.md section 6, PR 28). Above
+#: that, reckoned and not measured: the dense arm multiplies every row by
+#: every expert (1.2 GFLOP a row), so it leaves the weights' read time at
+#: about 150 rows and meets the sorted arm's 3.8 ms between 370 and 610
+#: rows; 256 stays under that. `chipbench/scratch/moe_arm_sweep.py` reads
+#: the crossover when a chip run of it is made.
+_DENSE_ROWS = 256
+
+
+def dropless_arm(rows: int) -> str:
+    """Which dispatch serves ``rows`` tokens, from the count alone: "dense"
+    (every local expert for every row, the unchosen weighted 0: one read of
+    the weights, no sort, no gather; the decode regime) or "sorted" (pairs
+    sorted by expert into a grouped matmul: FLOPs for the chosen pairs
+    only; the prefill pack)."""
+    return "dense" if rows <= _DENSE_ROWS else "sorted"
+
+
+def dropless_ffn(tokens, expert_idx, gates, experts, first: int = 0):
+    """Routed SwiGLU FFN with no capacity: every chosen (token, expert)
+    pair whose expert this layer holds is computed, none is dropped.
+
+    tokens [n, d]; expert_idx [n, k] int32 over ALL experts; gates [n, k]
+    float32; ``experts`` a :class:`SwiGLUExpertFFN` holding experts
+    ``[first, first + experts.num_experts)``. Returns this share's part of
+    the layer's output [n, d] (the shares of a partition of the experts sum
+    to the whole layer: expert parallelism's form) and ``rows`` [local
+    experts] int32, the rows each local expert got."""
+    n, d = tokens.shape
+    k = expert_idx.shape[1]
+    e = experts.num_experts
+    local = expert_idx - first
+    mine = (local >= 0) & (local < e)
+    gates = jnp.where(mine, gates, 0.0)
+    local = jnp.where(mine, local, e)              # e: nobody's
+    rows = jnp.zeros((e + 1,), jnp.int32).at[local.reshape(-1)].add(1)[:e]
+    wg, wu, wd = (experts.w_gate._data, experts.w_up._data,
+                  experts.w_down._data)
+    with jax.named_scope("pt.moe.experts"):
+        if dropless_arm(n) == "dense":
+            # [n, e]: a token's gate for each local expert, 0 if unchosen
+            gate_of = jnp.einsum(
+                "nk,nke->ne", gates,
+                jax.nn.one_hot(local, e, dtype=jnp.float32))
+            g = jnp.einsum("nd,edf->nef", tokens, wg)
+            u = jnp.einsum("nd,edf->nef", tokens, wu)
+            act = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+                   * gate_of[:, :, None]).astype(tokens.dtype)
+            out = jnp.einsum("nef,efd->nd", act, wd)
+        else:
+            flat = local.reshape(-1)                              # [n*k]
+            order = jnp.argsort(flat, stable=True)   # nobody's rows last
+            x = jnp.take(tokens, order // k, axis=0)
+            y = experts.forward_ragged(x, rows, None)
+            w = jnp.take(gates.reshape(-1), order)
+            y = jnp.where((w > 0)[:, None], y.astype(jnp.float32)
+                          * w[:, None], 0.0)
+            out = jnp.zeros((n, d), jnp.float32).at[order // k].add(y)
+            out = out.astype(tokens.dtype)
+    return out, rows
+
+
+class DroplessMoE(Layer):
+    """Expert layer whose gate hands over the choice and the gates
+    (``gate.route``): sigmoid or softmax scores, bias-corrected or not, are
+    the gate's business; here every chosen pair is computed. The layer
+    routes over all ``num_experts`` and holds (and computes) the experts
+    ``[first, first + count)``, by default all of them. One dispatch is
+    picked from the row count (``dropless_arm``).
+
+    ``forward(x)`` returns the layer's (share of the) output with x's
+    shape; ``forward(x, with_rows=True)`` also returns the rows each local
+    expert got ([count] int32), for the serving engine's counters."""
+
+    def __init__(self, d_model: int, num_experts: int, d_hidden: int,
+                 gate: BaseGate, first: int = 0, count: Optional[int] = None,
+                 dtype: str = "float32", initializer_range: float = 0.02):
+        super().__init__()
+        count = num_experts - first if count is None else int(count)
+        if not 0 <= first <= first + count <= num_experts:
+            raise ValueError(f"experts [{first}, {first + count}) are not "
+                             f"among {num_experts}")
+        self.num_experts, self.first = int(num_experts), int(first)
+        self.gate = gate
+        self.top_k = gate.top_k
+        self.experts = SwiGLUExpertFFN(count, d_model, d_hidden, dtype=dtype,
+                                       initializer_range=initializer_range)
+
+    @functools.partial(jax.named_call, name="pt.moe")
+    def forward(self, x, with_rows: bool = False):
+        x = _raw(x)
+        tokens = x.reshape(-1, x.shape[-1])
+        with jax.named_scope("pt.moe.router"):
+            idx, gates = self.gate.route(tokens)
+        out, rows = dropless_ffn(tokens, idx, gates, self.experts,
+                                 self.first)
+        out = out.reshape(x.shape)
+        return (out, rows) if with_rows else out

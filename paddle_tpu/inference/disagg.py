@@ -81,6 +81,7 @@ from typing import Callable, List, Optional, Set
 import numpy as np
 
 from ..ops.paged_attention import (gather_chain_pages, gather_chain_scales,
+                                   pool_geometry, require_kv_layers,
                                    scatter_chain_pages)
 from .fleet import FleetRouter, ReplicaState, _Replica
 from .recovery import _admit_record, _request_from
@@ -308,6 +309,7 @@ class KVChainCodec:
         if engine.prefix_cache is None:
             raise ValueError("KV-chain splice needs a prefix-cache engine")
         kv = engine.caches["kv"]
+        require_kv_layers(kv, "the KV-chain splice (import_chain)")
         pool_shape = tuple(int(d) for d in kv[0][0].shape[1:])
         want = (hdr["kvh"], hdr["page_size"], hdr["hd"])
         if (engine.page_size != hdr["page_size"] or len(kv) != hdr["layers"]
@@ -501,9 +503,7 @@ class TieredRouter(FleetRouter):
                 > dst_engine.max_len):
             return False
         src_kv, dst_kv = src_engine.caches["kv"], dst_engine.caches["kv"]
-        if (len(dst_kv) != len(src_kv)
-                or dst_kv[0][0].shape[1:] != src_kv[0][0].shape[1:]
-                or dst_kv[0][0].dtype != src_kv[0][0].dtype):
+        if pool_geometry(dst_kv) != pool_geometry(src_kv):
             return False
         # capacity: free + radix-registered is an optimistic pool estimate
         # (registered blocks may be pinned by live tables), so the

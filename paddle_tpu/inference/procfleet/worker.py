@@ -164,9 +164,11 @@ def _engine_hello(engine) -> dict:
                        if getattr(engine, "mesh", None) is not None else 1)}
     if engine.prefix_cache is not None:
         kv = engine.caches["kv"]
-        kvh, page, hd = (int(d) for d in kv[0][0].shape[1:])
+        # the first layer that keeps K and V (a state layer has no heads)
+        pair = next(e for e in kv if isinstance(e, tuple))
+        kvh, page, hd = (int(d) for d in pair[0].shape[1:])
         out.update(layers=len(kv), kvh=kvh, hd=hd,
-                   dtype=str(kv[0][0].dtype), maxp=int(engine._maxp),
+                   dtype=str(pair[0].dtype), maxp=int(engine._maxp),
                    num_blocks=int(engine._alloc.num_blocks))
     return out
 

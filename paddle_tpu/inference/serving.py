@@ -122,12 +122,14 @@ def _greedy(logits):
 
 # host-side page bookkeeping lives next to the paged kernels; re-exported
 # here as the serving-facing API surface
-from ..ops.paged_attention import BlockAllocator, RadixPrefixCache
+from ..ops.paged_attention import (BlockAllocator, LayerStateError,
+                                   RadixPrefixCache, copy_layer_pages,
+                                   layer_kinds, pool_num_pages, state_bytes)
 
 __all__ = ["AutoscaleConfig", "BlockAllocator", "BrownoutConfig",
            "ContinuousBatchingEngine", "EngineSaturated", "FleetConfig",
            "FleetRouter", "KVCacheConfig", "KVChainCodec", "KVChainCorrupt",
-           "MeshConfig", "MeshDegraded", "PrefixCacheConfig",
+           "LayerStateError", "MeshConfig", "MeshDegraded", "PrefixCacheConfig",
            "RadixPrefixCache",
            "ReplicaState",
            "Request", "RequestJournal", "RequestShed", "SLOAutoscaler",
@@ -722,6 +724,27 @@ class ContinuousBatchingEngine:
             self.caches = model._init_paged_caches(max_batch, max_len,
                                                    page_size,
                                                    kv_dtype=self._kv_dtype)
+        # what each layer keeps, as the model's caches say it: "kv" (pages of
+        # K and V a token) or "state" (a fixed block kept with the page:
+        # ops.paged_attention.PageState — docs/SERVING.md "State that is
+        # not pages"). A state ring rides every program inside
+        # caches["kv"], is shared, copied on write, evicted and migrated
+        # with its page, and needs from the engine only each chunk row's
+        # count of real tokens (a padded tail must leave no trace in it).
+        self._state_layers = [i for i, k in enumerate(
+            layer_kinds(self.caches["kv"])) if k == "state"]
+        for what, on in (
+                ("speculative decoding (a rejected draft cannot be taken "
+                 "back out of a state ring)", self._spec is not None),
+                ("an engine without a prefix cache (bucketed prompts are "
+                 "prefilled through generate()'s dense-cache hook)",
+                 prefix_cache is None)):
+            if on and self._state_layers:
+                raise LayerStateError(
+                    f"PT-SRV-009: {type(model).__name__} keeps layers "
+                    f"{self._state_layers} of kind 'state' (PageState); "
+                    f"they cannot be served by {what}")
+        self._ctr_layout: List[tuple] = []
         self._slots: List[Optional[Request]] = [None] * max_batch
         # O(active) bookkeeping (big-batch refactor): occupied slots in a
         # dict, free slots in a deque — per-step work is bounded by what is
@@ -825,7 +848,15 @@ class ContinuousBatchingEngine:
                       # unconditionally so dashboards never lose them):
                       # accumulated per-device collective wire bytes of
                       # every sharded dispatch + sharded decode dispatches
-                      "mesh_collective_bytes": 0.0, "mesh_decode_steps": 0}
+                      "mesh_collective_bytes": 0.0, "mesh_decode_steps": 0,
+                      # routed-expert counters out of the decode block,
+                      # read back with its tokens (zero for a model whose
+                      # paged_token_step returns no "moe_rows"): rows
+                      # routed, experts with a row or more and the fullest
+                      # expert's rows, each summed over expert layers and
+                      # token steps, and the number of those (layer, step)
+                      "moe_rows_routed": 0, "moe_experts_touched": 0,
+                      "moe_layer_steps": 0, "moe_rows_max_expert": 0}
         # per-program collective census (label -> per-dispatch wire bytes),
         # filled lazily as each sharded program first dispatches — feeds
         # the serving collector and mirrors the PT-COMM contract entries
@@ -839,15 +870,21 @@ class ContinuousBatchingEngine:
         self._finish_marks: List["Request"] = []
         # int8 block-format occupancy gauge (pt_kv_quant_blocks): pool
         # pages held in quantized form — 0 on fp engines
-        self._kv_quant_blocks = (int(self.caches["kv"][0][0].shape[0])
+        self._kv_quant_blocks = (pool_num_pages(self.caches["kv"])
                                  if self._kv_dtype == "int8" else 0)
         # int8 allocation hygiene (_reset_quant_blocks): one compiled
         # reset-scatter per power-of-two width
         self._jit_qreset: Dict[int, object] = {}
         if self.prefix_cache is not None:
+            # prefix_hit_admissions: admissions that mapped cached pages;
+            # state_snapshot_bytes: what the state rings kept with the
+            # pages take
             self.stats.update(hit_tokens=0, miss_tokens=0, cow_copies=0,
                               evictions=0, prefill_host_s=0.0,
-                              brownouts=0, brownout_steps=0, packed_rows=0)
+                              brownouts=0, brownout_steps=0, packed_rows=0,
+                              prefix_hit_admissions=0,
+                              state_snapshot_bytes=state_bytes(
+                                  self.caches["kv"]))
 
         from ..jit.api import _collect_state
 
@@ -1295,6 +1332,7 @@ class ContinuousBatchingEngine:
                                                           params):
                         logits, cs = self.model.paged_token_step(
                             tok, cs, pos)
+                    ctr = cs.pop("counters", None)
                     if do_sample:
                         keys = _fold_keys(seeds, pos + 1)
                         nxt = sample_rows(logits, keys, temps, tops, topks)
@@ -1304,11 +1342,11 @@ class ContinuousBatchingEngine:
                         # vocab (measured 150x engine slowdown before
                         # this gate)
                         nxt = _greedy(logits)
-                    return (nxt, cs, pos + 1), nxt
+                    return (nxt, cs, pos + 1), (nxt, ctr)
 
-                (tok, cs, _), out = jax.lax.scan(
+                (tok, cs, _), (out, ctr) = jax.lax.scan(
                     body, (toks, caches, pos_vec), None, length=n_steps)
-                return jnp.swapaxes(out, 0, 1), tok, cs
+                return self._with_counters(out, ctr), tok, cs
 
             self._jit_step = jax.jit(
                 pt_decode_block, static_argnames=("n_steps", "do_sample"))
@@ -1327,6 +1365,39 @@ class ContinuousBatchingEngine:
             seeds_d, temps_d, tops_d, topks_d, n_steps=n,
             do_sample=do_sample)
         return out
+
+    def _with_counters(self, out, ctr):
+        """(Traced.) The block's tokens [slots, n] out of the scan's
+        [n, slots]; where the model's token step returned ``counters``
+        (name -> int array a step), each flattened to rows [k, n] under the
+        tokens, so that they reach the host in the block's one transfer.
+        The layout is noted for ``_book_counters``."""
+        out = jnp.swapaxes(out, 0, 1)
+        if not ctr:
+            return out
+        self._ctr_layout = [(name, tuple(ctr[name].shape[1:]))
+                            for name in sorted(ctr)]
+        rows = [jnp.swapaxes(ctr[name].reshape(out.shape[1], -1), 0, 1)
+                for name, _ in self._ctr_layout]
+        return jnp.concatenate([out] + [r.astype(out.dtype) for r in rows],
+                               axis=0)
+
+    def _book_counters(self, tail):
+        """Add a block's counter rows (``tail`` [k, n], host values under
+        the tokens of ``_with_counters``) into ``stats``. ``moe_rows``
+        [expert layers, experts] a step: the rows each expert got, parked
+        rows and rows past their EOS included (the device computed them)."""
+        off = 0
+        for name, shape in self._ctr_layout:
+            size = int(np.prod(shape))
+            a = tail[off:off + size].T.reshape((tail.shape[1],) + shape)
+            off += size
+            if name == "moe_rows":
+                st = self.stats
+                st["moe_rows_routed"] += int(a.sum())
+                st["moe_experts_touched"] += int((a > 0).sum())
+                st["moe_layer_steps"] += int(a.shape[0] * a.shape[1])
+                st["moe_rows_max_expert"] += int(a.max(-1).sum())
 
     def _book_block(self, live, n: int, async_ok: bool, out):
         """Book a dispatched block's tokens: by the schedule alone where no
@@ -1361,6 +1432,8 @@ class ContinuousBatchingEngine:
         self._drain_pending()
         with _wait_span(self, "decode_block"):
             out = np.asarray(out)
+        if out.shape[0] > self.max_batch:
+            self._book_counters(out[self.max_batch:])
         tok_marks = [] if self.tracer is not None else None
         block_tokens = 0
         with self._span("serve.emit") as sp:
@@ -1578,6 +1651,8 @@ class ContinuousBatchingEngine:
         for arr_dev, entries, marks, first in self._pending:
             with _wait_span(self, "pending"):
                 arr = np.asarray(arr_dev)
+            if arr.ndim == 2 and arr.shape[0] > self.max_batch:
+                self._book_counters(arr[self.max_batch:])
             for row, req, took in entries:
                 if arr.ndim == 1:           # prefill firsts [g]
                     req.output.append(int(arr[row]))
@@ -1922,17 +1997,18 @@ class ContinuousBatchingEngine:
                 tok, cs, p = carry
                 with autograd_engine.no_grad(), _Swap(self._tensors, params):
                     logits, cs = self.model.paged_token_step(tok, cs, p)
+                ctr = cs.pop("counters", None)
                 if do_sample:
                     keys = _fold_keys(seeds, p + 1)
                     nxt = sample_rows(logits, keys, temps, tops, topks)
                 else:
                     nxt = _greedy(logits)
-                return (nxt, cs, p + 1), nxt
+                return (nxt, cs, p + 1), (nxt, ctr)
 
-            (tok, cs, _), out = jax.lax.scan(
+            (tok, cs, _), (out, ctr) = jax.lax.scan(
                 body, (toks, caches, pos_vec), None, length=n_steps)
             new_pos = jnp.where(act, pos + n_steps, pos)
-            return jnp.swapaxes(out, 0, 1), tok, cs["kv"], new_pos
+            return self._with_counters(out, ctr), tok, cs["kv"], new_pos
 
         return pt_decode_block
 
@@ -2138,7 +2214,7 @@ class ContinuousBatchingEngine:
 
             fn = self._jit_qreset[W] = jax.jit(pt_kv_reset)
             self._note_compiled()
-        npages = int(self.caches["kv"][0][0].shape[0])
+        npages = pool_num_pages(self.caches["kv"])
         idx = np.full(W, npages, np.int32)     # pad: out of range, dropped
         idx[:len(blocks)] = blocks
         self.caches = {"kv": self._call_built("pt_kv_reset", W, fn,
@@ -2173,15 +2249,13 @@ class ContinuousBatchingEngine:
         ``_try_admit_prefix``) until the copy is dispatched — ``evict_lru``
         under a later admission in the same wave must not reclaim them
         first."""
-        from ..ops.paged_attention import copy_pages
-
         W = 1
         while W < len(pairs):
             W *= 2
         fn = self._jit_cow_batch.get(W)
         if fn is None:
             def pt_cow_copy(kv, src, dst):
-                return [copy_pages(k, v, src, dst) for (k, v) in kv]
+                return [copy_layer_pages(e, src, dst) for e in kv]
 
             fn = self._jit_cow_batch[W] = jax.jit(pt_cow_copy)
             self._note_compiled()
@@ -2328,6 +2402,12 @@ class ContinuousBatchingEngine:
         self._prefill_next[slot] = cached
         self.stats["hit_tokens"] += cached
         self.stats["miss_tokens"] += len(prompt) - cached
+        if cached:
+            # the mapped pages bring their state rings: the request resumes
+            # every state layer at the end of the hit (on a full-prompt
+            # hit, from the COW copy's ring, whose slot of position L-1 the
+            # re-step rewrites and whose L-2, L-3 it reads)
+            self.stats["prefix_hit_admissions"] += 1
         if self.tracer is not None:
             now = _time.monotonic()
             self.tracer.admit(
@@ -2364,10 +2444,8 @@ class ContinuousBatchingEngine:
 
     def _cow_copy(self, src: int, dst: int):
         if self._cow_fn is None:
-            from ..ops.paged_attention import copy_pages
-
             def pt_cow_copy(kv, src, dst):
-                return [copy_pages(k, v, src, dst) for (k, v) in kv]
+                return [copy_layer_pages(e, src, dst) for e in kv]
 
             self._cow_fn = jax.jit(pt_cow_copy)
             self._note_compiled()
@@ -2463,8 +2541,12 @@ class ContinuousBatchingEngine:
             from ..core import autograd_engine
             from ..jit.api import _Swap
 
-            def pt_prefill_chunk(params, ids, kv, rows, starts):
+            def pt_prefill_chunk(params, ids, kv, rows, starts, *valid):
+                # valid (state layers only): each row's count of real
+                # tokens, so that a padded tail leaves their state alone
                 sub = {"kv": kv, "tables": rows}
+                if valid:
+                    sub["valid"] = valid[0]
                 with autograd_engine.no_grad(), _Swap(self._tensors, params):
                     sub = self.model.paged_prefill_chunk(ids, sub, starts)
                 return sub["kv"]
@@ -2485,16 +2567,19 @@ class ContinuousBatchingEngine:
         t0_tr = None if self.tracer is None else self.tracer.now()
         ids = np.zeros((g, C), np.int32)
         starts = np.zeros(g, np.int32)
+        real = np.zeros(g, np.int32)
         rows = np.stack([self._prefill_row(s, req) for s, req in group])
         for r, (s, req) in enumerate(group):
             nxt = self._prefill_next[s]
             chunk = req.prompt[nxt: nxt + C]
             ids[r, : len(chunk)] = chunk
             starts[r] = nxt
+            real[r] = len(chunk)
         new_kv = self._call_built(
             "pt_prefill_chunk", g, self._chunk_fn(g), self._params,
             jnp.asarray(ids), self.caches["kv"], jnp.asarray(rows),
-            jnp.asarray(starts))
+            jnp.asarray(starts),
+            *([jnp.asarray(real)] if self._state_layers else []))
         self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
         for s, req in group:
             nxt = self._prefill_next[s]
@@ -2545,16 +2630,19 @@ class ContinuousBatchingEngine:
         t0_tr = None if self.tracer is None else self.tracer.now()
         ids = np.zeros((g, C), np.int32)
         starts = np.zeros(g, np.int32)
+        real = np.zeros(g, np.int32)       # parked dummy rows: no real token
         trows = np.full((g, self._maxp), self._park, np.int32)
         for r, (s, req, off) in enumerate(rows):
             chunk = req.prompt[off: off + C]
             ids[r, : len(chunk)] = chunk
             starts[r] = off
+            real[r] = len(chunk)
             trows[r] = self._prefill_row(s, req)
         new_kv = self._call_built(
             "pt_prefill_chunk", g, self._chunk_fn(g), self._params,
             jnp.asarray(ids), self.caches["kv"], jnp.asarray(trows),
-            jnp.asarray(starts))
+            jnp.asarray(starts),
+            *([jnp.asarray(real)] if self._state_layers else []))
         self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
         self.stats["packed_rows"] += len(rows)
         for s, req in group:

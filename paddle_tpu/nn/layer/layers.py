@@ -139,7 +139,14 @@ class Layer:
             trainable = getattr(attr, "trainable", True)
         if init is None:
             init = I.Constant(0.0) if is_bias else I.XavierNormal()
-        data = init(shape, dtype)
+        from ...tensor.toplevel_extras import LazyGuard
+
+        if LazyGuard.active():      # shape and type now, the array later
+            import jax
+
+            data = jax.ShapeDtypeStruct(tuple(int(d) for d in shape), dtype)
+        else:
+            data = init(shape, dtype)
         p = Parameter(data, dtype=dtype, name=name, trainable=trainable)
         p.optimize_attr["learning_rate"] = learning_rate
         if attr is not None and attr is not False:

@@ -205,16 +205,23 @@ pgmm.defvjp(_pgmm_fwd, _pgmm_bwd)
 def grouped_dot(x, w, group_sizes):
     """Grouped matmul over rows sorted by group (group_sizes [E] row
     counts): jax's megablox ``gmm`` Pallas kernel on TPU (the tuned
-    megablocks-class kernel — weight-stationary tiling, no padding),
-    ``lax.ragged_dot`` elsewhere. Both differentiate w.r.t. x and w."""
+    megablocks-class kernel — weight-stationary tiling, no padding between
+    groups), ``lax.ragged_dot`` elsewhere. Both differentiate w.r.t. x and
+    w. The kernel takes whole row tiles only: a row count that is no
+    multiple of the tile is filled up with rows that belong to no group
+    (they come after every group's) and cut off the result."""
     if jax.default_backend() == "tpu":
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        k, n = w.shape[1], w.shape[2]
-        tiling = (512, _fit_tile(512, k), _fit_tile(512, n))
+        m, k, n = x.shape[0], w.shape[1], w.shape[2]
+        tm = 512
+        tiling = (tm, _fit_tile(512, k), _fit_tile(512, n))
+        fill = -m % tm
+        if fill:
+            x = jnp.pad(x, ((0, fill), (0, 0)))
         # preferred_element_type and tiling are positional: they are the
         # custom_vjp's non-differentiable arguments
-        return gmm(x, w, group_sizes, x.dtype, tiling)
+        return gmm(x, w, group_sizes, x.dtype, tiling)[:m]
     return jax.lax.ragged_dot(x, w, group_sizes)
 
 

@@ -107,6 +107,155 @@ class QuantizedKVPool:
                 f"dtype={self.data.dtype})")
 
 
+# ---------------------------------------------------------------------------
+# state that is not pages of K and V (docs/SERVING.md "State that is not pages")
+# ---------------------------------------------------------------------------
+
+class LayerStateError(TypeError):
+    """PT-SRV-009: an operation that moves or reinterprets a layer's cache
+    met a layer kind it cannot carry (names the kind). Raised instead of
+    dropping the state: a stream that silently lost a layer's state is the
+    one outcome the serving contract forbids."""
+
+
+@jax.tree_util.register_pytree_node_class
+class PageState:
+    """A layer's fixed-size state, kept WITH the pages of the pool.
+
+    Some layer kinds keep no K and V a token but a fixed block a sequence:
+    a short causal convolution needs its last ``slots - 1`` inputs, whatever
+    the sequence's length. ``ring`` [num_pages, slots, width] holds, for
+    every page of the pool, the layer's input at the last ``slots``
+    positions written in that page: position ``p`` lives in page
+    ``tables[row, p // page]`` at ring slot ``p % slots``. A sequence's
+    running state is the ring of its current page (and, for the first
+    ``slots - 1`` offsets of a page, of the page before); a cached page's
+    ring IS the snapshot a prefix hit resumes from. So the state is
+    allocated, shared, copied on write, evicted, stolen and migrated with
+    the page, by the same table rows, and no program ever restores it.
+
+    ``page`` (tokens a page) is static. Registered as a pytree, so it rides
+    jit carries and donation like the (k, v) pairs beside it in
+    ``caches["kv"]``."""
+
+    __slots__ = ("ring", "page")
+
+    def __init__(self, ring, page: int):
+        self.ring = ring
+        self.page = int(page)
+
+    def tree_flatten(self):
+        return (self.ring,), self.page
+
+    @classmethod
+    def tree_unflatten(cls, page, children):
+        return cls(children[0], page)
+
+    @property
+    def slots(self) -> int:
+        return self.ring.shape[1]
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.ring.size) * jnp.dtype(self.ring.dtype).itemsize
+
+    def __repr__(self):
+        return (f"PageState(ring={tuple(self.ring.shape)}, "
+                f"dtype={self.ring.dtype}, page={self.page})")
+
+
+@functools.partial(jax.named_call, name="pt.state_write")
+def page_state_write(state: PageState, values, block_tables, positions,
+                     seq_ids=None, valid=None):
+    """Write ``values`` [n, width] (the layer's inputs at absolute
+    ``positions`` [n] of rows ``seq_ids``, default one a row) into the rings
+    of the pages that hold those positions. ``valid`` [n] bool drops
+    entries (the zero-padded tail of a last prefill chunk): they must leave
+    no trace. Among the entries of one call that share a (page, slot) the
+    caller keeps the last only (``page_state_keep_last``): a scatter with
+    duplicate indices has no order."""
+    n = values.shape[0]
+    if seq_ids is None:
+        seq_ids = jnp.arange(n, dtype=jnp.int32)
+    page_idx = block_tables[seq_ids, positions // state.page]
+    if valid is not None:
+        # out of range: jax drops the update
+        page_idx = jnp.where(valid, page_idx, state.ring.shape[0])
+    ring = state.ring.at[page_idx, positions % state.slots].set(
+        values.astype(state.ring.dtype), mode="drop")
+    return PageState(ring, state.page)
+
+
+def page_state_keep_last(valid, offsets, slots: int, page: int):
+    """For a chunk's rows ([b, s] ``valid`` and in-page ``offsets``): which
+    positions are the last valid writer of their (page, ring slot): the one
+    ``slots`` further on lies in another page or is not valid."""
+    later = jnp.pad(valid[:, slots:], ((0, 0), (0, slots)))
+    return valid & ~(later & (offsets + slots < page))
+
+
+def page_state_read(state: PageState, block_tables, positions, back: int,
+                    seq_ids=None):
+    """The layer's inputs at ``positions - back .. positions - 1``:
+    [n, back, width], oldest first, zeros before the sequence's start."""
+    n = positions.shape[0]
+    if seq_ids is None:
+        seq_ids = jnp.arange(n, dtype=jnp.int32)
+    q = positions[:, None] - jnp.arange(back, 0, -1, dtype=jnp.int32)
+    qc = jnp.maximum(q, 0)
+    page_idx = block_tables[seq_ids[:, None], qc // state.page]
+    vals = state.ring[page_idx, qc % state.slots]
+    return jnp.where((q >= 0)[..., None], vals, jnp.zeros((), vals.dtype))
+
+
+def layer_kinds(kv) -> List[str]:
+    """What each layer of ``caches["kv"]`` keeps: "kv" (pages of K and V a
+    token) or "state" (a fixed block, ``PageState``)."""
+    return ["state" if isinstance(e, PageState) else "kv" for e in kv]
+
+
+def pool_num_pages(kv) -> int:
+    """Pages in the pool, whatever the first layer keeps."""
+    e = kv[0]
+    return int((e.ring if isinstance(e, PageState) else e[0]).shape[0])
+
+
+def pool_geometry(kv) -> List[tuple]:
+    """Per layer ``(kind, shape of a page's block, dtype)``: two engines can
+    exchange pages only where these agree."""
+    out = []
+    for e in kv:
+        a = e.ring if isinstance(e, PageState) else e[0]
+        out.append(("state" if isinstance(e, PageState) else "kv",
+                    tuple(a.shape[1:]), str(a.dtype)))
+    return out
+
+
+def state_bytes(kv) -> int:
+    """Bytes of the state rings kept with the pages (0 without such
+    layers)."""
+    return sum(e.nbytes for e in kv if isinstance(e, PageState))
+
+
+def copy_layer_pages(entry, src, dst):
+    """``copy_pages`` for one layer's cache entry of either kind: a state
+    ring is copied with the page like K and V."""
+    if isinstance(entry, PageState):
+        src = jnp.atleast_1d(jnp.asarray(src, jnp.int32))
+        dst = jnp.atleast_1d(jnp.asarray(dst, jnp.int32))
+        return PageState(entry.ring.at[dst].set(entry.ring[src]), entry.page)
+    return copy_pages(*entry, src, dst)
+
+
+def require_kv_layers(kv, what: str):
+    kinds = layer_kinds(kv)
+    if "state" in kinds:
+        raise LayerStateError(
+            f"PT-SRV-009: {what} carries pages of K and V only; layer(s) "
+            f"{[i for i, k in enumerate(kinds) if k == 'state']} are of "
+            f"kind 'state' (PageState rings), which it would drop")
+
+
 def kv_absmax(x):
     """Per-(token, kv_head) absmax of new k/v rows ``x`` [n, kv_heads, d] —
     the head_dim reduction of ``PerChannelAbsmaxObserver`` math, feeding
@@ -780,6 +929,7 @@ def gather_chain_pages(kv, blocks):
     artifact's crc covers the quantized bytes exactly as stored."""
     import numpy as np
 
+    require_kv_layers(kv, "the KV-chain export (gather_chain_pages)")
     idx = np.asarray(blocks, np.int32)
     out = []
     for k, v in kv:
@@ -812,6 +962,7 @@ def scatter_chain_pages(kv, blocks, pages, scales=None):
     per-block ``scales`` (from :func:`gather_chain_scales` or the PTKV1
     header) alongside the raw int8 bytes. Returns the updated per-layer
     ``[(k_pages, v_pages), ...]`` list."""
+    require_kv_layers(kv, "the KV-chain import (scatter_chain_pages)")
     idx = jnp.asarray(blocks, jnp.int32)
     out = []
     for li, ((k, v), (pk, pv)) in enumerate(zip(kv, pages)):

@@ -139,14 +139,27 @@ def rank(input):
 
 
 class LazyGuard:
-    """Deferred-init guard (reference: LazyGuard). Initialization is eager in
-    this framework; the guard is a no-op context for porting compatibility."""
+    """Deferred-init guard (reference: LazyGuard). A parameter created
+    inside the guard (``Layer.create_parameter``) runs no initializer and
+    owns no device array: its ``_data`` is a ``jax.ShapeDtypeStruct`` that
+    holds shape and type, annotations stay, ``initialized`` reads False until
+    ``_data`` is assigned an array. So a model larger than half the device can be
+    built, then given its weights, with one copy on the device. Outside the
+    guard initialisation is eager as before. Guards nest."""
+
+    _depth = 0
 
     def __enter__(self):
+        LazyGuard._depth += 1
         return self
 
     def __exit__(self, *exc):
+        LazyGuard._depth -= 1
         return False
+
+    @staticmethod
+    def active() -> bool:
+        return LazyGuard._depth > 0
 
 
 def check_shape(x, expected):
