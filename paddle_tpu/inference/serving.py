@@ -1337,10 +1337,8 @@ class ContinuousBatchingEngine:
                         keys = _fold_keys(seeds, pos + 1)
                         nxt = sample_rows(logits, keys, temps, tops, topks)
                     else:
-                        # all-greedy batches skip the sampler: its
-                        # vocab-wide argsort costs ~10 ms/token at 32k
-                        # vocab (measured 150x engine slowdown before
-                        # this gate)
+                        # all-greedy batches skip the sampler's
+                        # vocabulary-wide sort (a program of their own)
                         nxt = _greedy(logits)
                     return (nxt, cs, pos + 1), (nxt, ctr)
 

@@ -29,8 +29,8 @@ def validate_sampling(temperature, top_p, top_k=0):
     """Shared range checks for sampling params (generate() + serving Request).
 
     Out-of-range values fail loudly here instead of silently degenerating in
-    ``sample_rows`` (e.g. top_p < 0 masks every candidate, making categorical
-    sample near-uniformly over the whole vocab).
+    ``sample_rows`` (e.g. top_p < 0 keeps no candidate at all, and the draw
+    then has nothing valid to choose from).
     """
     # `not (x >= 0)` (vs `x < 0`) also rejects NaN
     if temperature is not None and not float(temperature) >= 0.0:
@@ -52,19 +52,32 @@ def sample_rows(logits, keys, temps, top_ps, top_ks):
 
     logits [b, V] f32; keys: typed PRNG key array [b]; temps/top_ps [b] f32;
     top_ks [b] int32 (0 = disabled). temperature<=0 rows take argmax.
+
+    One stable sort a call gives the sorted logits and their token ids
+    together; the kept tokens are a prefix of that order, so one uniform a
+    row against the cumulative sum draws from the kept, renormalised
+    probabilities. Nothing else is V wide but elementwise passes and
+    reductions (no gather, no field of random bits); the trace readers count
+    a decode block's token steps by this one ``sort``.
     """
     V = logits.shape[-1]
     greedy = jnp.argmax(logits, -1).astype(jnp.int32)
     lg = logits / jnp.maximum(temps[:, None], 1e-6)
-    sort_idx = jnp.argsort(-lg, axis=-1)
-    sorted_lg = jnp.take_along_axis(lg, sort_idx, -1)
-    p = jax.nn.softmax(sorted_lg, -1)
+    ids = jax.lax.broadcasted_iota(jnp.int32, lg.shape, lg.ndim - 1)
+    neg_sorted, sort_idx = jax.lax.sort((-lg, ids), dimension=-1, num_keys=1,
+                                        is_stable=True)
+    p = jax.nn.softmax(-neg_sorted, -1)
     cum = jnp.cumsum(p, -1)
     keep = (cum - p) <= top_ps[:, None]
     kk = jnp.where(top_ks > 0, top_ks, V)
-    keep = keep & (jnp.arange(V)[None, :] < kk[:, None])
-    masked = jnp.where(keep, sorted_lg, -1e9)
-    choice = jax.vmap(jax.random.categorical)(keys, masked)
+    keep = keep & (ids < kk[:, None])
+    # keep is a prefix (cum - p never falls, top_k cuts a prefix): the kept
+    # mass is cum at its last entry, and u * mass lands in one kept interval
+    n_keep = jnp.sum(keep, -1, dtype=jnp.int32)
+    mass = jnp.take_along_axis(cum, n_keep[:, None] - 1, -1)
+    u = jax.vmap(lambda k: jax.random.uniform(k, (1,), jnp.float32))(keys)
+    choice = jnp.minimum(jnp.sum(cum <= u * mass, -1, dtype=jnp.int32),
+                         n_keep - 1)
     sampled = jnp.take_along_axis(sort_idx, choice[:, None], -1)[:, 0]
     return jnp.where(temps <= 0.0, greedy, sampled.astype(jnp.int32))
 

@@ -1,10 +1,13 @@
 """The streams of a ``models/llama`` engine at the chat-batch cell's rehearsal
 size (hidden 64, 2 layers, heads 4/2 of 16, 512 words; fused, prefix cache,
 pages of 4, blocks of 4), greedy and seeded-sampled requests mixed over
-shared prefixes. ``tests/data/serving_llama_streams.json`` holds them as the
-parent commit of PR 28 produced them (``python tests/_serving_streams.py
-<out>`` in a checkout of it); ``test_serving_state.py`` holds every later
-tree to them byte for byte."""
+shared prefixes. ``tests/data/serving_llama_streams.json`` holds them: the
+greedy streams (every fourth request) and the cache's counters as the parent
+commit of PR 28 produced them (``python tests/_serving_streams.py <out>`` in
+a checkout of it), the sampled streams as PR 29 produced them (``sample_rows``
+draws one uniform a row there, another random stream from the same key; the
+greedy streams were checked equal to the older file when it was re-recorded);
+``test_serving_state.py`` holds every later tree to them byte for byte."""
 
 import json
 import os

@@ -146,10 +146,12 @@ def _ps_role(rank, port, q):
     from paddle_tpu.distributed import ps, rpc
 
     try:
+        if rank == 0:
+            ps.run_server()     # before it can be reached: a trainer may
+                                # call as soon as init_rpc's rendezvous ends
         rpc.init_rpc("ps0" if rank == 0 else f"trainer{rank}", rank, 2,
                      f"127.0.0.1:{port}")
         if rank == 0:
-            ps.run_server()
             time.sleep(4.0)  # serve
         else:
             w = ps.PsWorker("ps0")
@@ -200,9 +202,10 @@ def _sharded_role(rank, port, q):
 
     try:
         name = f"ps{rank}" if rank < 2 else "trainer"
+        if rank < 2:
+            ps.run_server()     # before it can be reached (see _ps_role)
         rpc.init_rpc(name, rank, 3, f"127.0.0.1:{port}")
         if rank < 2:
-            ps.run_server()
             time.sleep(5.0)  # serve
         else:
             c = ps.ShardedPsClient(["ps0", "ps1"])
