@@ -404,7 +404,7 @@ def bench_serving_large_batch(dev, on_tpu):
     def build(n_slots, tracer=None):
         return ContinuousBatchingEngine(
             model, max_batch=n_slots, max_len=prompt_len + max_new,
-            page_size=page, block_size=block, fused=True,
+            page_size=page, block_size=block,
             prefix_cache=PrefixCacheConfig(extra_blocks=n_slots),
             tracer=tracer)
 
@@ -621,7 +621,7 @@ def bench_serving_mesh_degrade(dev, on_tpu):
         mesh = None if mesh_tp is None else MeshConfig(tp=int(mesh_tp))
         return ContinuousBatchingEngine(
             model, max_batch=slots, max_len=max_len, page_size=page,
-            block_size=block, fused=True,
+            block_size=block,
             prefix_cache=PrefixCacheConfig(extra_blocks=slots), mesh=mesh)
 
     def wave(sup):
@@ -935,7 +935,7 @@ def bench_serving_sharded(dev, on_tpu):
     def build(mesh=None):
         return ContinuousBatchingEngine(
             model, max_batch=slots, max_len=max_len, page_size=page,
-            block_size=block, fused=True,
+            block_size=block,
             prefix_cache=PrefixCacheConfig(extra_blocks=slots),
             mesh=mesh)
 
@@ -1554,7 +1554,7 @@ def bench_speculative(dev, on_tpu):
     def build(**kw):
         return ContinuousBatchingEngine(
             model, max_batch=slots, max_len=max_len, page_size=page,
-            block_size=4, fused=True,
+            block_size=4,
             prefix_cache=PrefixCacheConfig(extra_blocks=slots), **kw)
 
     def run_wave(e):

@@ -18,13 +18,13 @@ is built.
 Phases (children call ``run_phase``):
   train      853M llama (the north-star shape), a few fused steps on one
              fixed batch; flash kernels counted in the lowered step.
-  serve      750M-class llama under the legacy and the fused+prefix-cache
-             engines; the same 16-request wave twice; paged-decode kernels
-             counted in the lowered decode program; stream identity fused vs
-             legacy and engine vs generate() reported, not asserted.
+  serve      750M-class llama under the engine without and with a prefix
+             cache; the same 16-request wave twice; paged-decode kernels
+             counted in the lowered decode program; stream identity between
+             the two and engine vs generate() reported, not asserted.
   kernels    flash fwd + grad and paged decode against their jnp references.
-  multichip  only with >= 4 devices: the trainer on fsdp2 x tp2, the fused
-             server on tp=4, placement and memory spread asserted.
+  multichip  only with >= 4 devices: the trainer on fsdp2 x tp2, the
+             prefix-cache server on tp=4, placement and memory spread asserted.
 """
 
 from __future__ import annotations
@@ -297,22 +297,10 @@ def _identity(name: str, got, ref, rows) -> None:
 def _decode_kernels(eng) -> int:
     """tpu_custom_call count of the engine's decode program, lowered from
     its live state the way ``_decode_block_inner`` dispatches it."""
-    import jax.numpy as jnp
-
-    if eng._fused:
-        text = eng._jit_mega.lower(
-            eng._params, eng._last_tok, eng.caches["kv"],
-            eng.caches["tables"], eng._dev_pos, eng._dev_act,
-            *eng._dev_samp, n_steps=eng.block_size,
-            do_sample=False).as_text()
-    else:
-        samp = (jnp.asarray(eng._seeds), jnp.asarray(eng._temps),
-                jnp.asarray(eng._tops), jnp.asarray(eng._topks))
-        text = eng._jit_step.lower(
-            eng._params, eng._last_tok, eng.caches,
-            jnp.zeros(eng.max_batch, jnp.int32), *samp,
-            n_steps=eng.block_size, do_sample=False).as_text()
-    return _count_kernels(text)
+    return _count_kernels(eng._jit_mega.lower(
+        eng._params, eng._last_tok, eng.caches["kv"], eng.caches["tables"],
+        eng._dev_pos, eng._dev_act, *eng._dev_samp, n_steps=eng.block_size,
+        do_sample=False).as_text())
 
 
 def _build_server():
@@ -323,13 +311,13 @@ def _build_server():
     return LlamaForCausalLM(LlamaConfig(**SERVE_MODEL))
 
 
-def _fused_engine(model, **kw):
+def _prefix_engine(model, **kw):
     from paddle_tpu.inference.serving import (ContinuousBatchingEngine,
                                               PrefixCacheConfig)
 
     return ContinuousBatchingEngine(
         model, max_batch=SERVE_SLOTS, max_len=SERVE_MAX_LEN,
-        page_size=SERVE_PAGE, block_size=SERVE_BLOCK, fused=True,
+        page_size=SERVE_PAGE, block_size=SERVE_BLOCK,
         prefix_cache=PrefixCacheConfig(extra_blocks=8), **kw)
 
 
@@ -343,14 +331,13 @@ def phase_serve(tmp: str) -> None:
     wave = _wave()
     greedy = [i for i, s in enumerate(wave) if "temperature" not in s]
 
-    # default-constructed at 8 slots: the legacy step and prefill programs
-    legacy = ContinuousBatchingEngine(
+    # without a prefix cache: slot-owned pages and the bucketed prefill
+    # program; with one: radix admission, packed prefill, first-token re-step
+    plain = ContinuousBatchingEngine(
         model, max_batch=SERVE_SLOTS, max_len=SERVE_MAX_LEN,
         page_size=SERVE_PAGE, block_size=SERVE_BLOCK)
-    _check(not legacy._fused, "serve: default 8-slot engine came up fused")
-    fused = _fused_engine(model)
     streams = {}
-    for name, eng in (("legacy", legacy), ("fused", fused)):
+    for name, eng in (("plain", plain), ("prefix", _prefix_engine(model))):
         t0 = time.perf_counter()
         first = _serve_wave(eng, wave, f"serve/{name} wave 1")
         t1 = time.perf_counter()
@@ -371,17 +358,17 @@ def phase_serve(tmp: str) -> None:
                f"serve/{name}: lowered decode program holds {calls} "
                f"tpu_custom_call(s), expected {SERVE_KERNEL_CALLS} — decode "
                f"attention fell off the paged kernel")
-        if name == "fused":
+        if name == "prefix":
             gained = eng.stats["hit_tokens"] - hits
-            print(f"serve/fused: prefix cache hit_tokens rose by {gained} "
+            print(f"serve/prefix: prefix cache hit_tokens rose by {gained} "
                   f"on the second wave", flush=True)
-            _check(gained > 0, "serve/fused: hit_tokens did not rise on a "
+            _check(gained > 0, "serve/prefix: hit_tokens did not rise on a "
                                "wave that repeats a 48-token prefix")
         streams[name] = first
 
     # phase 4 — reported, not asserted: these identities were only ever
     # pinned in float32 on the CPU
-    _identity("fused == legacy", streams["fused"], streams["legacy"], greedy)
+    _identity("prefix == plain", streams["prefix"], streams["plain"], greedy)
     ref = {}
     for lo in range(0, len(wave), SERVE_SLOTS):
         rows = range(lo, min(lo + SERVE_SLOTS, len(wave)))
@@ -391,13 +378,13 @@ def phase_serve(tmp: str) -> None:
             max_length=SERVE_MAX_LEN).numpy())
         for i, row in zip(rows, toks):
             ref[i] = [int(t) for t in row]
-    for name in ("legacy", "fused"):
+    for name in ("plain", "prefix"):
         _identity(f"{name} == generate()", streams[name], ref, greedy)
     print(f"serve: peak device memory {_mem_gb(jax.devices()[0]):.2f} GB",
           flush=True)
     # phase 5 compares its tp=4 streams with these
-    with open(os.path.join(tmp, "fused_streams.json"), "w") as f:
-        json.dump(streams["fused"], f)
+    with open(os.path.join(tmp, "prefix_streams.json"), "w") as f:
+        json.dump(streams["prefix"], f)
 
 
 #: Kernel-vs-reference bounds. Inputs are bf16, both sides accumulate in
@@ -574,14 +561,14 @@ def _multichip_train() -> None:
 
 
 def _multichip_serve(tmp: str) -> None:
-    """The fused server of phase 2 on tp=4, devices as MeshConfig picks;
+    """The prefix-cache server of phase 2 on tp=4, devices as MeshConfig picks;
     streams compared (reported, not asserted) with phase 2's tp=1 ones."""
     from paddle_tpu.inference.serving import MeshConfig
 
-    eng = _fused_engine(_build_server(), mesh=MeshConfig(tp=4))
+    eng = _prefix_engine(_build_server(), mesh=MeshConfig(tp=4))
     wave = _wave()
     streams = _serve_wave(eng, wave, "multichip/serve")
-    with open(os.path.join(tmp, "fused_streams.json")) as f:
+    with open(os.path.join(tmp, "prefix_streams.json")) as f:
         single = json.load(f)
     _spread("serve", max(eng._params, key=lambda a: a.size))
     _identity("tp=4 == tp=1", streams, single,

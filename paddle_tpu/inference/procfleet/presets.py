@@ -45,11 +45,10 @@ def tiny_llama_prefix_engine(**kw):
 
 
 def tiny_llama_mesh_engine(**kw):
-    """Fused + prefix-cache variant for mesh-sharded workers: sharded
-    serving requires the fused engine with a prefix cache, and the worker
-    injects ``mesh=MeshConfig(tp, devices=<its group>)`` on top of these
-    kwargs (``WorkerSpec.mesh`` — docs/SERVING.md "Sharded serving")."""
+    """Prefix-cache variant for mesh-sharded workers: sharded serving
+    requires a prefix cache, and the worker injects
+    ``mesh=MeshConfig(tp, devices=<its group>)`` on top of these kwargs
+    (``WorkerSpec.mesh`` — docs/SERVING.md "Sharded serving")."""
     kw.setdefault("prefix_cache", True)
-    kw.setdefault("fused", True)
     kw.setdefault("max_batch", 4)
     return tiny_llama_engine(**kw)

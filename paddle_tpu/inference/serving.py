@@ -41,32 +41,29 @@ versus the dense-cache generate it matches exactly in fp32 (CPU tests) while
 bf16-on-TPU tokens may diverge at softmax near-ties between the two attention
 kernels — the standard cross-kernel serving caveat.
 
-**Fused mega-step mode** (``fused=True``; auto at ``max_batch >= 32`` —
-docs/SERVING.md): the big-batch (128-256 slot) step loop. Block tables,
-per-slot positions, the active-row mask and the sampling state are
-DEVICE-resident and mutated only by traced scatter programs
-(``_queue_update`` -> ``_flush_updates``) — the per-step host rebuild +
-``.copy()`` upload of ``_tables_host`` is gone, which also retires the
-async-borrow hazard class (PT-TRACE-005) at the source. Decode runs as
+**The step loop** (docs/SERVING.md "The mega-step"; one family at every
+``max_batch`` since PR 30): block tables, per-slot positions, the
+active-row mask and the sampling state are DEVICE-resident and mutated
+only by traced scatter programs (``_queue_update`` -> ``_flush_updates``)
+— no mutable host buffer is ever handed to ``jnp.asarray``, which retires
+the async-borrow hazard class (PT-TRACE-005) at the source. Decode runs as
 ONE jitted mega-step over all ``max_batch`` rows with ``jnp.where``-masked
 inactive rows (admission or completion never changes the program shape),
 sampling and the position advance stay in-graph, and prefill packs
 multiple (slot, chunk) rows into one ``paged_prefill_chunk`` call
 (``_run_pack``). Host bookkeeping is O(active): occupied slots live in a
-dict, free slots in a deque, and the per-step scans over ``max_batch``
-are gone. Token streams are byte-identical to the legacy per-slot path
-(greedy and seeded) — the fused programs run the same per-row math, and
-per-row values are independent of batch width in fp32 (the warm==cold
-argument; tests/test_serving_fused.py pins fused-vs-legacy equality).
+dict, free slots in a deque. Greedy and seeded token streams equal
+``generate()``'s on the CPU in fp32 — per-row values are independent of
+batch width (the warm==cold argument, pinned by the stream tests).
 
-**Speculative multi-token decoding** (``speculative=SpecConfig(...)``,
-fused mode — docs/SERVING.md "Speculative decode"): each decode dispatch
+**Speculative multi-token decoding** (``speculative=SpecConfig(...)`` —
+docs/SERVING.md "Speculative decode"): each decode dispatch
 emits 1..K+1 tokens per row — a device-resident n-gram drafter proposes K
 tokens from a per-slot history ring, one K+1-wide ``paged_verify_step``
 scores every position (``ops.paged_verify_attention`` append-then-gather),
 and in-graph greedy exact-match acceptance keeps the longest correct
 prefix plus one bonus token. Greedy output is byte-identical to the
-non-speculative mega-step; sampling blocks keep the legacy path.
+non-speculative mega-step; blocks with a sampled row take that one.
 
 **int8 KV block format** (``kv_cache=KVCacheConfig(dtype="int8")`` —
 docs/SERVING.md "int8 KV cache"): pools become
@@ -229,7 +226,7 @@ class PrefixCacheConfig:
     - ``extra_blocks``: pool headroom beyond the ``max_batch *
       pages_per_seq`` working set, retained for cached prefixes (0 still
       caches — prefix SHARING itself frees blocks).
-    - ``pack_rows``: fused-mode prompt-packing budget — max (slot, chunk)
+    - ``pack_rows``: prompt-packing budget — max (slot, chunk)
       rows per packed prefill call (default ``max(8, min(max_batch, 32))``;
       the pack always covers at least one chunk per mid-prefill slot, so
       this only bounds the EXTRA rows that let short prompts finish in one
@@ -242,7 +239,7 @@ class PrefixCacheConfig:
 
 @dataclasses.dataclass
 class SpecConfig:
-    """Knobs for speculative multi-token decoding inside the fused
+    """Knobs for speculative multi-token decoding inside the
     mega-step (``ContinuousBatchingEngine(speculative=...)`` —
     docs/SERVING.md "Speculative decode").
 
@@ -264,7 +261,7 @@ class SpecConfig:
     Greedy (temperature==0) output is byte-identical to the
     non-speculative mega-step — drafts only change how many tokens a
     dispatch emits, never which tokens. Blocks containing sampling rows
-    (temperature>0) keep the legacy sampled mega-step.
+    (temperature>0) keep the sampled mega-step.
 
     Composition with ``KVCacheConfig(dtype="int8")``: rejected drafts'
     appends feed the int8 blocks' monotone absmax scales, so a spec+int8
@@ -307,7 +304,7 @@ class MeshConfig:
     """Mesh-sharded serving (``ContinuousBatchingEngine(mesh=...)`` —
     docs/SERVING.md "Sharded serving").
 
-    ``tp`` devices run every hot-path program (fused mega-step, packed
+    ``tp`` devices run every hot-path program (mega-step, packed
     prefill chunk, speculative verify, first-token re-step) under
     ``shard_map``: weights are column-sharded along their OUTPUT dim
     (q/k/v along heads, gate/up along mlp, an untied lm_head along
@@ -331,7 +328,7 @@ class MeshConfig:
       host this way); actually dispatching on an abstract engine fails
       by construction.
 
-    Requires the fused engine with a prefix cache, and a model that
+    Requires a prefix cache, and a model that
     opts in via the ``tp_serving = True`` marker (llama; GPT's fused
     interleaved qkv projection cannot be column-sharded)."""
 
@@ -559,15 +556,17 @@ class ContinuousBatchingEngine:
                  speculative: Union[bool, SpecConfig, None] = None,
                  kv_cache: Union[str, KVCacheConfig, None] = None,
                  mesh: Union[int, "MeshConfig", None] = None,
-                 tracer=None, trace_tags: Optional[Dict] = None,
-                 donate_carry: bool = True,
-                 _unsafe_overcommit: bool = False):
+                 tracer=None, trace_tags: Optional[Dict] = None):
+        # ``fused`` chooses nothing since PR 30: there is one family of step
+        # programs. The name stays only because chipbench's cell files hand
+        # ``"fused": true`` over as a keyword; a ``benchmark`` issue drops
+        # the key there and the parameter here.
+        if fused not in (None, True):
+            raise ValueError(
+                "fused=False: the legacy step programs left the engine in "
+                "PR 30 — there is one decode family at every max_batch; "
+                "drop the argument")
         self.model = model
-        # buffer donation on the carry arguments of the jitted hot-path
-        # programs (mega-step kv/pos, prefill-chunk / first-token kv).
-        # Off switch exists for the PT-COST byte-identity A/B and for
-        # debugging with retained pre-step buffers.
-        self._donate_carry = bool(donate_carry)
         # per-request trace spans (observability.TraceRecorder — docs/
         # OBSERVABILITY.md): every stamp site is host-side, behind a single
         # `is not None` check, and records into a bounded buffer — nothing
@@ -613,26 +612,16 @@ class ContinuousBatchingEngine:
         self._ema_tok_s: Optional[float] = None
         self._sched_tokens = 0
         self._maxp = -(-max_len // page_size)
-        # fused mega-step mode (module docstring / docs/SERVING.md):
-        # device-resident tables/positions/sampling state + one jitted
-        # decode program over all rows. Auto-enabled at big batch, where
-        # per-step table uploads and O(max_batch) host scans dominate.
-        self._fused = (max_batch >= 32) if fused is None else bool(fused)
         # speculative multi-token decoding (docs/SERVING.md "Speculative
         # decode"): a device-resident n-gram drafter + one K-wide verify
-        # program per dispatch, greedy-exact. Fused-mode only — the spec
-        # program IS a mega-step variant over the device-resident state.
+        # program per dispatch, greedy-exact — a mega-step variant over the
+        # device-resident state.
         if speculative is True:
             speculative = SpecConfig()
         elif not speculative:
             speculative = None
         self._spec = speculative
         if self._spec is not None:
-            if not self._fused:
-                raise ValueError(
-                    "speculative decoding needs the fused mega-step "
-                    "(fused=True) — the drafter/verify state is "
-                    "device-resident")
             if self._spec.k < 1 or self._spec.ngram < 1:
                 raise ValueError("SpecConfig.k and .ngram must be >= 1")
             if self._spec.history < self._spec.ngram + self._spec.k:
@@ -662,11 +651,11 @@ class ContinuousBatchingEngine:
         self._mesh = None
         self._mesh_axis = None
         if mesh is not None:
-            if not self._fused or prefix_cache is None:
+            if prefix_cache is None:
                 raise ValueError(
-                    "mesh-sharded serving needs the fused engine with a "
-                    "prefix cache (fused=True, prefix_cache=...) — the "
-                    "legacy step/prefill programs stay single-device")
+                    "mesh-sharded serving needs a prefix cache "
+                    "(prefix_cache=...) — the bucketed prefill program of "
+                    "the static pool layout stays single-device")
             if not getattr(model, "tp_serving", False):
                 raise ValueError(
                     f"{type(model).__name__} does not support tensor-"
@@ -688,11 +677,6 @@ class ContinuousBatchingEngine:
                         "--xla_force_host_platform_device_count")
                 self._mesh = jax.sharding.Mesh(np.asarray(devs[:tp]),
                                                (self._mesh_axis,))
-        # DRILL-ONLY knob (tools/fault_drill.py prefix_cache_exhaustion):
-        # allocate past pool capacity by ripping blocks out of the radix
-        # cache while live tables still map them — demonstrates the
-        # corruption the refcounted path exists to prevent. Never enable.
-        self._overcommit = bool(_unsafe_overcommit)
         if prefix_cache is not None:
             c = prefix_cache.prefill_chunk or min(max_len, 8 * page_size)
             self._chunk_tokens = -(-int(c) // page_size) * page_size
@@ -707,15 +691,16 @@ class ContinuousBatchingEngine:
             self._park = n_blocks
             self._alloc = BlockAllocator(n_blocks)
             self._radix = RadixPrefixCache(page_size, self._alloc)
-            self._tables_host = np.full((max_batch, self._maxp), self._park,
-                                        np.int32)
-            self._tables_dirty = True
+            # the device table starts all-parked; only _flush_updates'
+            # scatters write it afterwards (no host table exists)
+            self.caches = {"kv": self.caches["kv"],
+                           "tables": jnp.full((max_batch, self._maxp),
+                                              self._park, jnp.int32)}
             self._slot_rows: List[Optional[np.ndarray]] = [None] * max_batch
             self._slot_blocks: List[Optional[List[int]]] = [None] * max_batch
             self._prefill_next: Dict[int, int] = {}
             self._jit_chunk: Dict[int, object] = {}
             self._jit_first: Dict[tuple, object] = {}
-            self._cow_fn = None
             self._jit_cow_batch: Dict[int, object] = {}
             self._pack_rows = (max(8, min(max_batch, 32))
                                if prefix_cache.pack_rows is None
@@ -761,47 +746,31 @@ class ContinuousBatchingEngine:
         # lazily from self._pending — see _drain_pending)
         self._last_tok = jnp.zeros(max_batch, jnp.int32)
         self._pending: List[tuple] = []
-        self._temps = np.zeros(max_batch, np.float32)
-        self._tops = np.ones(max_batch, np.float32)
-        self._topks = np.zeros(max_batch, np.int32)
-        self._seeds = np.zeros(max_batch, np.int32)
-        # device copies of the sampling params, re-uploaded only when an
-        # admission changes them (every host->device put is a dispatch)
-        self._samp_dev = None
-        if self._fused:
-            # device-resident per-slot step state: positions, active mask,
-            # sampling params. Admission/release mutate them ONLY through
-            # _queue_update -> _flush_updates (traced scatters applied at
-            # the next decode dispatch) — no mutable host buffer is ever
-            # handed to jnp.asarray, which retires the async-borrow hazard
-            # class (PT-TRACE-005) at the source.
-            self._dev_pos = jnp.zeros(max_batch, jnp.int32)
-            self._dev_act = jnp.zeros(max_batch, jnp.bool_)
-            self._dev_samp = (jnp.zeros(max_batch, jnp.int32),
-                              jnp.zeros(max_batch, jnp.float32),
-                              jnp.ones(max_batch, jnp.float32),
-                              jnp.zeros(max_batch, jnp.int32))
-            self._upd: Dict[int, tuple] = {}
-            self._upd_width = min(max_batch, 32)
-            self._jit_mega = None
-            self._jit_apply = None
-            if self._spec is not None:
-                # drafter state: per-slot history ring + written count —
-                # device-resident like pos/act, mutated only by the spec
-                # program and the activation scatters (_flush_updates)
-                self._dev_hist = jnp.zeros(
-                    (max_batch, self._spec.history), jnp.int32)
-                self._dev_hlen = jnp.zeros(max_batch, jnp.int32)
-                self._jit_spec = None
-            if self.prefix_cache is not None:
-                # the device table starts all-parked (the legacy path
-                # builds this lazily via the dirty-flag upload; the fused
-                # path never uploads a host table at all)
-                self.caches = {"kv": self.caches["kv"],
-                               "tables": jnp.full(
-                                   (max_batch, self._maxp), self._park,
-                                   jnp.int32)}
-                self._tables_dirty = False
+        # device-resident per-slot step state: positions, active mask,
+        # sampling params (seeds, temperatures, top_p, top_k).
+        # Admission/release mutate them ONLY through _queue_update ->
+        # _flush_updates (traced scatters applied at the next decode
+        # dispatch) — no mutable host buffer is ever handed to
+        # jnp.asarray, which retires the async-borrow hazard class
+        # (PT-TRACE-005) at the source.
+        self._dev_pos = jnp.zeros(max_batch, jnp.int32)
+        self._dev_act = jnp.zeros(max_batch, jnp.bool_)
+        self._dev_samp = (jnp.zeros(max_batch, jnp.int32),
+                          jnp.zeros(max_batch, jnp.float32),
+                          jnp.ones(max_batch, jnp.float32),
+                          jnp.zeros(max_batch, jnp.int32))
+        self._upd: Dict[int, tuple] = {}
+        self._upd_width = min(max_batch, 32)
+        self._jit_mega = None
+        self._jit_apply = None
+        if self._spec is not None:
+            # drafter state: per-slot history ring + written count —
+            # device-resident like pos/act, mutated only by the spec
+            # program and the activation scatters (_flush_updates)
+            self._dev_hist = jnp.zeros(
+                (max_batch, self._spec.history), jnp.int32)
+            self._dev_hlen = jnp.zeros(max_batch, jnp.int32)
+            self._jit_spec = None
         self._queue: collections.deque = collections.deque()
         self._finished: Dict[int, Request] = {}
         # deadline-carrying requests currently in the system: the per-step
@@ -892,7 +861,6 @@ class ContinuousBatchingEngine:
         self._params = [t._data for t in tensors]
         self._tensors = tensors
         self._jit_prefill: Dict[int, object] = {}
-        self._jit_step = None
         # mesh placement (real meshes: one device_put pass; abstract
         # meshes: specs only — the audit path never touches devices).
         # Head-granularity check first: a column shard must hold WHOLE
@@ -1230,27 +1198,14 @@ class ContinuousBatchingEngine:
 
     def _decode_block_inner(self):
         with self._span("serve.decode.dispatch") as sp:
-            if self._fused:
-                # device-resident state: every admission/release queued
-                # since the last block lands as ONE traced scatter program —
-                # the host never rebuilds or re-uploads a [max_batch, pages]
-                # table
-                self._flush_updates()
-            elif self.prefix_cache is not None and self._tables_dirty:
-                # dynamic block tables: rows for decode-ready slots map their
-                # allocated (possibly shared) pages; free and still-prefilling
-                # rows point at the parking page so the scan's dummy append
-                # can never touch a block another request shares. The .copy()
-                # is LOAD-BEARING: jax borrows the host buffer for an async
-                # transfer, and _release_slot mutates _tables_host — without
-                # a private snapshot the scan can observe post-mutation rows
-                # (measured ~1/30 runs decoding against parking-page tables)
-                self.caches = {"kv": self.caches["kv"],
-                               "tables": jnp.asarray(self._tables_host.copy())}
-                self._tables_dirty = False
-            # O(active): the decode set comes from the occupied dict (sorted
-            # for the legacy path's deterministic slot order), never a
-            # max_batch scan
+            # device-resident state: every admission/release queued since
+            # the last block lands as ONE traced scatter program — the host
+            # never rebuilds or uploads a [max_batch, pages] table. Rows of
+            # free and still-prefilling slots stay on the parking page, so
+            # the scan's dummy append can never touch a shared block
+            self._flush_updates()
+            # O(active): the decode set comes from the occupied dict (in
+            # slot order), never a max_batch scan
             live = [(i, r) for i, r in sorted(self._occupied.items())
                     if not (self.prefix_cache is not None
                             and i in self._prefill_next)]
@@ -1259,7 +1214,7 @@ class ContinuousBatchingEngine:
             # all-greedy block with verify-window headroom on every row
             # (the K+1 window writes k/v at positions pos-1 .. pos-1+K):
             # one speculative dispatch replaces the scan block. Sampling
-            # rows keep the legacy sampled mega-step; rows at the max_len
+            # rows keep the sampled mega-step; rows at the max_len
             # boundary finish on ordinary blocks.
             spec = (self._spec is not None
                     and not any(r.temperature > 0.0 for _, r in live)
@@ -1298,70 +1253,20 @@ class ContinuousBatchingEngine:
     def _dispatch_block(self, live, n: int, do_sample: bool):
         """Dispatch ``pt_decode_block`` for ``n`` token steps; returns the
         device array of the block's tokens [slots, n]."""
-        toks = self._last_tok
-        if self._fused:
-            # ONE jitted mega-step over all rows: decode + sampling +
-            # position advance in-graph, inactive rows masked by the
-            # device-side act vector — admission never retraces
-            if self._jit_mega is None:
-                self._jit_mega = self._build_mega_jit()
-                self._note_compiled()
-            seeds_d, temps_d, tops_d, topks_d = self._dev_samp
-            out, self._last_tok, new_kv, self._dev_pos = self._call_built(
-                "pt_decode_block", (n, do_sample), self._jit_mega,
-                self._params, toks, self.caches["kv"],
-                self.caches["tables"], self._dev_pos, self._dev_act,
-                seeds_d, temps_d, tops_d, topks_d, n_steps=n,
-                do_sample=do_sample)
-            self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
-            return out
-        active = np.zeros(self.max_batch, bool)
-        for i, _ in live:
-            active[i] = True
-        # parked rows decode at position 0 over slot-local pages — harmless
-        pos_vec = jnp.asarray(np.where(active, self._pos, 1) - 1)
-        if self._jit_step is None:
-            from ..core import autograd_engine
-            from ..jit.api import _Swap
-
-            def pt_decode_block(params, toks, caches, pos_vec, seeds, temps,
-                                tops, topks, n_steps, do_sample):
-                def body(carry, _):
-                    tok, cs, pos = carry
-                    with autograd_engine.no_grad(), _Swap(self._tensors,
-                                                          params):
-                        logits, cs = self.model.paged_token_step(
-                            tok, cs, pos)
-                    ctr = cs.pop("counters", None)
-                    if do_sample:
-                        keys = _fold_keys(seeds, pos + 1)
-                        nxt = sample_rows(logits, keys, temps, tops, topks)
-                    else:
-                        # all-greedy batches skip the sampler's
-                        # vocabulary-wide sort (a program of their own)
-                        nxt = _greedy(logits)
-                    return (nxt, cs, pos + 1), (nxt, ctr)
-
-                (tok, cs, _), (out, ctr) = jax.lax.scan(
-                    body, (toks, caches, pos_vec), None, length=n_steps)
-                return self._with_counters(out, ctr), tok, cs
-
-            self._jit_step = jax.jit(
-                pt_decode_block, static_argnames=("n_steps", "do_sample"))
+        # ONE jitted mega-step over all rows: decode + sampling +
+        # position advance in-graph, inactive rows masked by the
+        # device-side act vector — admission never retraces
+        if self._jit_mega is None:
+            self._jit_mega = self._build_mega_jit()
             self._note_compiled()
-        if self._samp_dev is None:
-            # private snapshots: jax borrows host buffers for async
-            # transfers and these arrays mutate on admission/slot-release
-            self._samp_dev = (jnp.asarray(self._seeds.copy()),
-                              jnp.asarray(self._temps.copy()),
-                              jnp.asarray(self._tops.copy()),
-                              jnp.asarray(self._topks.copy()))
-        seeds_d, temps_d, tops_d, topks_d = self._samp_dev
-        out, self._last_tok, self.caches = self._call_built(
-            "pt_decode_block", (n, do_sample), self._jit_step,
-            self._params, toks, self.caches, pos_vec,
+        seeds_d, temps_d, tops_d, topks_d = self._dev_samp
+        out, self._last_tok, new_kv, self._dev_pos = self._call_built(
+            "pt_decode_block", (n, do_sample), self._jit_mega,
+            self._params, self._last_tok, self.caches["kv"],
+            self.caches["tables"], self._dev_pos, self._dev_act,
             seeds_d, temps_d, tops_d, topks_d, n_steps=n,
             do_sample=do_sample)
+        self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
         return out
 
     def _with_counters(self, out, ctr):
@@ -1470,11 +1375,10 @@ class ContinuousBatchingEngine:
 
     def finished(self) -> Dict[int, Request]:
         self._drain_pending()
-        if self._fused:
-            # control plane: land any queued release scatters so a drained
-            # engine's device state (act mask / parked tables) is actually
-            # drained, not pending the next decode dispatch
-            self._flush_updates()
+        # control plane: land any queued release scatters so a drained
+        # engine's device state (act mask / parked tables) is actually
+        # drained, not pending the next decode dispatch
+        self._flush_updates()
         # retry-registry snapshot rides here (control plane), NOT in step():
         # a per-step dict copy was measurable on the decode hot path
         if self._retry_stats_fn is None:
@@ -1604,27 +1508,18 @@ class ContinuousBatchingEngine:
         if req.deadline_s is not None:
             self._n_deadlined += 1
         self._pos[slot] = int(pos)
-        self._temps[slot] = req.temperature
-        self._tops[slot] = req.top_p
-        self._topks[slot] = req.top_k
-        self._seeds[slot] = req.seed
-        self._samp_dev = None
         # control-plane eager scatter: the decode chain reads the carry
         # from device state, and migration happens once per request
         self._last_tok = self._last_tok.at[slot].set(
             jnp.int32(int(last_tok)))
-        if self._fused:
-            # spec engines re-seed the drafter ring with prompt + delivered
-            # tokens (minus the last-token carry restored above) so the
-            # migrated stream drafts from its full history
-            self._queue_update(slot, row, int(pos), True, req.seed,
-                               req.temperature, req.top_p, req.top_k,
-                               hist=(self._spec_seed(req.prompt,
-                                                     extra=req.output[:-1])
-                                     if self._spec is not None else None))
-        else:
-            self._tables_host[slot] = row
-            self._tables_dirty = True
+        # spec engines re-seed the drafter ring with prompt + delivered
+        # tokens (minus the last-token carry restored above) so the
+        # migrated stream drafts from its full history
+        self._queue_update(slot, row, int(pos), True, req.seed,
+                           req.temperature, req.top_p, req.top_k,
+                           hist=(self._spec_seed(req.prompt,
+                                                 extra=req.output[:-1])
+                                 if self._spec is not None else None))
         n_full = len(req.prompt) // self.page_size
         if n_full and not self._brownout_active:
             self._radix.insert(req.prompt[: n_full * self.page_size],
@@ -1675,15 +1570,15 @@ class ContinuousBatchingEngine:
         """Free slot ``i``. Prefix mode DECREFS the slot's blocks (a shared
         prefix block stays alive while any other table or the radix cache
         references it — freeing it would corrupt the survivors) and parks
-        the slot's decode-table row (fused mode: via the next traced
-        scatter — freed pages may be re-mapped by the very next admission,
-        and the inactive row's dummy append must never touch them)."""
+        the slot's decode-table row via the next traced scatter — freed
+        pages may be re-mapped by the very next admission, and the
+        inactive row's dummy append must never touch them. The device
+        table is authoritative: there is no host mirror to drift."""
         if self._slots[i] is not None:
             self._occupied.pop(i, None)
             self._free_slots.append(i)
         self._slots[i] = None
         self._pos[i] = 0
-        self._temps[i] = 0.0
         if self.prefix_cache is not None:
             blocks = self._slot_blocks[i]
             if blocks:
@@ -1691,18 +1586,9 @@ class ContinuousBatchingEngine:
             self._slot_blocks[i] = None
             self._slot_rows[i] = None
             self._prefill_next.pop(i, None)
-            if self._fused:
-                # the device table (caches["tables"], scatter-updated) is
-                # authoritative in fused mode — don't maintain a host
-                # mirror that could silently drift from it
-                self._queue_update(i, None, 0, False)
-            else:
-                self._tables_host[i] = self._park
-                self._tables_dirty = True
-        elif self._fused:
-            self._queue_update(i, None, 0, False)
+        self._queue_update(i, None, 0, False)
 
-    # -- fused mega-step machinery (module docstring / docs/SERVING.md) ----
+    # -- mega-step machinery (module docstring / docs/SERVING.md) ----------
     def _queue_update(self, slot: int, row, pos: int, act: bool,
                       seed: int = 0, temp: float = 0.0, top_p: float = 1.0,
                       top_k: int = 0, hist=None):
@@ -1711,9 +1597,10 @@ class ContinuousBatchingEngine:
         of the same slot in one step collapses to the admit — and
         everything queued lands as ONE traced scatter program at the next
         decode dispatch. ``row=None`` means the parking row (release) or
-        an unchanged static table (legacy-layout engines). ``hist`` (spec
-        engines) is the slot's drafter seed ``(ring_row, hlen)`` — None
-        resets the ring (release / non-spec engines ignore it)."""
+        an unchanged static table (engines without a prefix cache).
+        ``hist`` (spec engines) is the slot's drafter seed ``(ring_row,
+        hlen)`` — None resets the ring (release / non-spec engines ignore
+        it)."""
         self._upd[slot] = (None if row is None else np.asarray(row, np.int32),
                            int(pos), bool(act), int(seed), float(temp),
                            float(top_p), int(top_k), hist)
@@ -1752,8 +1639,8 @@ class ContinuousBatchingEngine:
         for lo in range(0, len(items), W):
             batch = items[lo:lo + W]
             idx = np.full(W, self.max_batch, np.int32)
-            # legacy-layout engines have static slot-owned tables: the
-            # apply program ignores urows, so don't build/upload the
+            # engines without a prefix cache have static slot-owned tables:
+            # the apply program ignores urows, so don't build/upload the
             # [W, maxp] buffer at all (a 1-element dummy keeps the
             # signature); same for the drafter ring on non-spec engines
             urows = (np.full((W, self._maxp), self._park, np.int32)
@@ -1962,7 +1849,7 @@ class ContinuousBatchingEngine:
         get the same program as jit(shard_map(...)) behind a
         static-variant dispatcher (``_mesh_jit``) — byte-identical
         output, per-shard compute."""
-        donate = self._MEGA_DONATE_ARGNUMS if self._donate_carry else ()
+        donate = self._MEGA_DONATE_ARGNUMS
         if self._mesh is not None:
             return self._mesh_jit(
                 self._mega_step_fn(), self._MEGA_ARG_NAMES,
@@ -1974,15 +1861,15 @@ class ContinuousBatchingEngine:
                        donate_argnums=donate)
 
     def _mega_step_fn(self):
-        """The fused mega-step program (tools/lint_graph.py records and
-        lints this — the one program a 128-256-slot engine dispatches per
-        decode block): decode ``n_steps`` tokens for every row at per-row
-        positions, sample in-graph, and advance the device-side positions,
+        """The mega-step program (tools/lint_graph.py records and lints
+        this — the one program an engine dispatches per decode block):
+        decode ``n_steps`` tokens for every row at per-row positions,
+        sample in-graph, and advance the device-side positions,
         with inactive rows masked by the ``act`` vector (they step a
         parked dummy row whose output the host ignores) — so admissions
         and completions never change the program shape and never retrace.
-        The per-row math is IDENTICAL to the legacy ``_jit_step`` body,
-        which is what makes fused-vs-legacy token streams byte-identical."""
+        The per-row math is ``generate()``'s: ``paged_token_step`` then
+        ``sample_rows`` under the key (request seed, position)."""
         from ..core import autograd_engine
         from ..jit.api import _Swap
 
@@ -2016,7 +1903,7 @@ class ContinuousBatchingEngine:
         donation included (kv / pos / drafter ring+length are the carries;
         tools/audit_program_cost.py traces this, PT-COST-003 audits the
         ``donated_invars``)."""
-        donate = self._SPEC_DONATE_ARGNUMS if self._donate_carry else ()
+        donate = self._SPEC_DONATE_ARGNUMS
         if self._mesh is not None:
             return self._mesh_jit(
                 self._spec_step_fn(), self._SPEC_ARG_NAMES,
@@ -2240,8 +2127,8 @@ class ContinuousBatchingEngine:
         return row, hlen
 
     def _cow_copy_batch(self, pairs):
-        """All of an admission wave's COW copies in ONE device dispatch
-        (the legacy path copies per admission). Padded to a power-of-two
+        """All of an admission wave's COW copies in ONE device dispatch.
+        Padded to a power-of-two
         width with park->park self-copies so the compiled-program set
         stays O(log max_batch); the sources stay pinned (incref'd by
         ``_try_admit_prefix``) until the copy is dispatched — ``evict_lru``
@@ -2277,17 +2164,15 @@ class ContinuousBatchingEngine:
         in-process): serving programs key on shapes — admission group size,
         prompt bucket, chunk width, sampling mode — so a shape-churning
         workload compiles without bound. Track the entry count and warn
-        past ``compile_cache_cap``. (``_jit_step`` counts as one entry; its
+        past ``compile_cache_cap``. (The mega-step counts as one entry; its
         n_steps variants live in jax's own jit cache.)"""
         n = (len(self._jit_prefill) + len(self._jit_qreset)
-             + (self._jit_step is not None))
-        if self._fused:
-            n += (self._jit_mega is not None) + (self._jit_apply is not None)
-            if self._spec is not None:
-                n += self._jit_spec is not None
+             + (self._jit_mega is not None) + (self._jit_apply is not None))
+        if self._spec is not None:
+            n += self._jit_spec is not None
         if self.prefix_cache is not None:
             n += (len(self._jit_chunk) + len(self._jit_first)
-                  + (self._cow_fn is not None) + len(self._jit_cow_batch))
+                  + len(self._jit_cow_batch))
         self.stats["compile_cache_entries"] = n
         if n > self.compile_cache_cap:
             import warnings
@@ -2317,7 +2202,7 @@ class ContinuousBatchingEngine:
 
         if not self._queue:
             return
-        cow_wave = [] if self._fused else None
+        cow_wave = []
         while self._free_slots and self._queue:
             req = self._queue[0]
             held = resource_hold("serving.block_pool", f"rid:{req.rid}")
@@ -2335,7 +2220,7 @@ class ContinuousBatchingEngine:
         self.stats["evictions"] = self._radix.evictions
 
     def _try_admit_prefix(self, slot: int, req: "Request",
-                          cow_wave=None) -> bool:
+                          cow_wave: list) -> bool:
         page = self.page_size
         prompt = req.prompt
         n_full = len(prompt) // page
@@ -2362,8 +2247,6 @@ class ContinuousBatchingEngine:
         pinned = matched + ([cow_src] if cow_src is not None else [])
         self._alloc.incref(pinned)
         fresh = self._alloc.alloc(fresh_n, evict=self._radix.evict_lru)
-        if fresh is None and self._overcommit:
-            fresh = self._steal_blocks(fresh_n, avoid=set(pinned))
         if fresh is None:
             self._alloc.decref(pinned)
             return False                       # pool exhausted — defer
@@ -2375,14 +2258,10 @@ class ContinuousBatchingEngine:
         cached = len(matched) * page
         if cow_src is not None:
             dst = fresh[0]
-            if cow_wave is None:
-                self._cow_copy(cow_src, dst)
-                self._alloc.decref([cow_src])  # copy done — unpin the source
-            else:
-                # fused: the whole admission wave's COW copies batch into
-                # one program (_cow_copy_batch); the source stays pinned
-                # until that dispatch so eviction cannot reclaim it first
-                cow_wave.append((cow_src, dst))
+            # the whole admission wave's COW copies batch into one program
+            # (_cow_copy_batch); the source stays pinned until that
+            # dispatch so eviction cannot reclaim it first
+            cow_wave.append((cow_src, dst))
             self.stats["cow_copies"] += 1
             blocks = matched + [dst] + fresh[1:]
             cached = len(prompt)
@@ -2414,52 +2293,14 @@ class ContinuousBatchingEngine:
                 tags=self._req_tags(req))
         return True
 
-    def _steal_blocks(self, n: int, avoid=()):
-        """DRILL-ONLY (``_unsafe_overcommit``): what a refcount-less
-        allocator does under exhaustion — rip LRU radix leaves out of the
-        cache and hand them to the new request even though live tables
-        still map them. The fault drill asserts the resulting shared-block
-        corruption; production admission defers instead."""
-        legit = list(self._alloc.alloc(min(n, self._alloc.free_blocks)) or [])
-        stolen = []
-        victims = sorted(self._radix._by_block.values(),
-                         key=lambda nd: nd.last_used)
-        for nd in victims:
-            if len(legit) + len(stolen) >= n:
-                break
-            if nd.block in avoid or nd.children:
-                continue
-            nd.parent.children.pop(nd.key, None)
-            del self._radix._by_block[nd.block]
-            self._alloc._ref[nd.block] = self._alloc._ref.get(nd.block, 0) + 1
-            stolen.append(nd.block)
-        if len(legit) + len(stolen) < n:
-            self._alloc.decref(stolen + legit)
-            return None
-        # stolen pages first: they become the thief's PROMPT blocks, so its
-        # very next prefill overwrites a page the victim still reads
-        return stolen + legit
-
-    def _cow_copy(self, src: int, dst: int):
-        if self._cow_fn is None:
-            def pt_cow_copy(kv, src, dst):
-                return [copy_layer_pages(e, src, dst) for e in kv]
-
-            self._cow_fn = jax.jit(pt_cow_copy)
-            self._note_compiled()
-        self.caches = {"kv": self._call_built("pt_cow_copy", (),
-                                              self._cow_fn,
-                                              self.caches["kv"],
-                                              np.int32(src), np.int32(dst)),
-                       "tables": self.caches["tables"]}
-
     def _prefill_tick(self):
-        """One chunk of prefill per mid-prefill slot, then the first-token
-        re-step (+ radix registration) for slots whose prompts are fully
-        written. Chunks are batched across slots at per-row offsets; the
-        re-step runs through ``paged_token_step`` so warm (cache-hit) and
-        cold admissions share one program per shape — the warm==cold
-        bit-identity guarantee (see ops.paged_prefill_attention)."""
+        """One packed prefill call over the mid-prefill slots, then the
+        first-token re-step (+ radix registration) for slots whose prompts
+        are fully written. Chunks are batched across slots at per-row
+        offsets; the re-step runs through ``paged_token_step`` so warm
+        (cache-hit) and cold admissions share one program per shape — the
+        warm==cold bit-identity guarantee (see
+        ops.paged_prefill_attention)."""
         if not self._prefill_next:
             return
         t0 = _time.perf_counter()
@@ -2481,28 +2322,20 @@ class ContinuousBatchingEngine:
     def _prefill_tick_inner(self):
         chunkers = [(s, self._slots[s]) for s in sorted(self._prefill_next)
                     if self._prefill_next[s] < len(self._slots[s].prompt)]
-        if chunkers and self._fused:
+        if chunkers:
             # prompt-packing prefill (_run_pack): several short prompts
             # — and several chunks of one long prompt — advance in ONE
-            # call per step instead of one chunk per slot per step
+            # call per step
             self._run_pack(chunkers)
             while self._brownout_active and any(
                     self._prefill_next[s] < len(r.prompt)
                     for s, r in chunkers):
+                # brownout disables chunked INTERLEAVING: the whole prompt
+                # prefills this tick, trading decode overlap for zero
+                # extra mid-prefill state under pressure. Same compiled
+                # chunk programs, run to completion.
                 self._run_pack([(s, r) for s, r in chunkers
                                 if self._prefill_next[s] < len(r.prompt)])
-        elif chunkers:
-            self._run_chunk(chunkers)
-            while self._brownout_active and any(
-                    self._prefill_next[s] < len(r.prompt)
-                    for s, r in chunkers):
-                # brownout disables chunked INTERLEAVING: the whole
-                # prompt prefills this tick (legacy admit-stalls-a-step
-                # behavior), trading decode overlap for zero extra
-                # mid-prefill state under pressure. Same compiled chunk
-                # program, run to completion.
-                self._run_chunk([(s, r) for s, r in chunkers
-                                 if self._prefill_next[s] < len(r.prompt)])
         ready = [(s, self._slots[s]) for s in sorted(self._prefill_next)
                  if self._prefill_next[s] >= len(self._slots[s].prompt)]
         if ready:
@@ -2529,11 +2362,8 @@ class ContinuousBatchingEngine:
         return row
 
     def _chunk_fn(self, g: int):
-        """The compiled prefill-chunk program for ``g`` rows — shared by
-        the legacy one-chunk-per-slot path (``_run_chunk``) and the fused
-        packed path (``_run_pack``): both dispatch the same
-        (params, ids, kv, rows, starts) program, they only lay the rows
-        out differently."""
+        """The compiled prefill-chunk program for ``g`` rows
+        (params, ids, kv, rows, starts), as ``_run_pack`` dispatches it."""
         fn = self._jit_chunk.get(g)
         if fn is None:
             from ..core import autograd_engine
@@ -2549,7 +2379,7 @@ class ContinuousBatchingEngine:
                     sub = self.model.paged_prefill_chunk(ids, sub, starts)
                 return sub["kv"]
 
-            donate = self._CHUNK_DONATE_ARGNUMS if self._donate_carry else ()
+            donate = self._CHUNK_DONATE_ARGNUMS
             if self._mesh is not None:
                 fn = self._mesh_jit(pt_prefill_chunk, self._CHUNK_ARG_NAMES,
                                     "kv", donate, name=f"prefill_chunk@{g}")
@@ -2559,38 +2389,8 @@ class ContinuousBatchingEngine:
             self._note_compiled()
         return fn
 
-    def _run_chunk(self, group):
-        C = self._chunk_tokens
-        g = len(group)
-        t0_tr = None if self.tracer is None else self.tracer.now()
-        ids = np.zeros((g, C), np.int32)
-        starts = np.zeros(g, np.int32)
-        real = np.zeros(g, np.int32)
-        rows = np.stack([self._prefill_row(s, req) for s, req in group])
-        for r, (s, req) in enumerate(group):
-            nxt = self._prefill_next[s]
-            chunk = req.prompt[nxt: nxt + C]
-            ids[r, : len(chunk)] = chunk
-            starts[r] = nxt
-            real[r] = len(chunk)
-        new_kv = self._call_built(
-            "pt_prefill_chunk", g, self._chunk_fn(g), self._params,
-            jnp.asarray(ids), self.caches["kv"], jnp.asarray(rows),
-            jnp.asarray(starts),
-            *([jnp.asarray(real)] if self._state_layers else []))
-        self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
-        for s, req in group:
-            nxt = self._prefill_next[s]
-            self._prefill_next[s] = min(nxt + C, len(req.prompt))
-            if self.tracer is not None:
-                # one span per slot per chunk, host-dispatch window, with
-                # the real (unpadded) token count this chunk advanced
-                self.tracer.prefill_chunk(
-                    req.rid, t0_tr, self._prefill_next[s] - nxt,
-                    tags=self.trace_tags)
-
     def _run_pack(self, group):
-        """Prompt-packing prefill (fused mode): flatten (slot, chunk)
+        """Prompt-packing prefill: flatten (slot, chunk)
         pairs into the rows of ONE ``paged_prefill_chunk`` call — several
         short prompts complete their whole prefill, and a long prompt
         advances several chunks, in a single device program instead of
@@ -2604,7 +2404,7 @@ class ContinuousBatchingEngine:
         SAME program, bit-identical to running the chunks sequentially.
         Rows are assigned breadth-first (one chunk per slot per pass), so
         every mid-prefill slot advances at least one chunk per step — the
-        legacy interleaving guarantee — and ``PrefixCacheConfig.pack_rows``
+        interleaving guarantee — and ``PrefixCacheConfig.pack_rows``
         bounds the extra rows. Row counts are bucketed to powers of two
         with parked dummy rows, so admission-width churn at 128+ slots
         compiles O(log max_batch) variants, not one per width."""
@@ -2655,17 +2455,15 @@ class ContinuousBatchingEngine:
         """Re-step the last REAL prompt token at its true position (k/v
         rewrite into a private/COW block, logits over exactly the real
         prompt) and sample the first token — the chunked-path analogue of
-        the legacy bucketed re-step; then register the prompt's full blocks
-        in the radix cache and promote the slot into the decode batch
-        (fused mode: activation rides the next traced scatter, and group
-        widths are bucketed to powers of two — dummy rows re-step the
-        parking page at position 0 and scatter to slot index ``max_batch``,
-        which jax drops — so admission-wave width churn never retraces)."""
-        g = len(ready)
-        if self._fused:
-            g = 1
-            while g < len(ready):
-                g *= 2
+        the bucketed re-step; then register the prompt's full blocks in the
+        radix cache and promote the slot into the decode batch (activation
+        rides the next traced scatter, and group widths are bucketed to
+        powers of two — dummy rows re-step the parking page at position 0
+        and scatter to slot index ``max_batch``, which jax drops — so
+        admission-wave width churn never retraces)."""
+        g = 1
+        while g < len(ready):
+            g *= 2
         do_sample = any(r.temperature > 0.0 for _, r in ready)
         last = np.zeros(g, np.int32)
         rows = np.full((g, self._maxp), self._park, np.int32)
@@ -2700,8 +2498,7 @@ class ContinuousBatchingEngine:
                     nxt = _greedy(logits)
                 return nxt, sub["kv"], last_tok.at[slots_].set(nxt)
 
-            donate = self._FIRST_DONATE_ARGNUMS if self._donate_carry \
-                else ()
+            donate = self._FIRST_DONATE_ARGNUMS
             if self._mesh is not None:
                 fn = self._mesh_jit(pt_first_token, self._FIRST_ARG_NAMES,
                                     ("rep", "kv", "rep"), donate,
@@ -2716,7 +2513,6 @@ class ContinuousBatchingEngine:
             jnp.asarray(rows), self._last_tok, jnp.asarray(ints),
             jnp.asarray(floats))
         self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
-        self._samp_dev = None   # sampling params change -> re-upload lazily
         any_eos = any(r.eos_token_id is not None for _, r in ready)
         firsts = None
         if any_eos:
@@ -2743,30 +2539,20 @@ class ContinuousBatchingEngine:
                 self._radix.insert(req.prompt[: n_full * self.page_size],
                                    self._slot_blocks[slot][:n_full])
             del self._prefill_next[slot]
-            self._temps[slot] = req.temperature
-            self._tops[slot] = req.top_p
-            self._topks[slot] = req.top_k
-            self._seeds[slot] = req.seed
             req._n_out += 1
             self._sched_tokens += 1
             if ft_marks is not None:
                 ft_marks.append((req.rid, req._n_out))
             self._pos[slot] = len(req.prompt) + 1
-            if self._fused:
-                # activation rides the next traced scatter: table row,
-                # position, active flag, sampling params — and on spec
-                # engines the drafter ring seeded with the prompt — in one
-                # update (no host-table mirror — the device table is
-                # authoritative)
-                self._queue_update(slot, self._slot_rows[slot],
-                                   len(req.prompt) + 1, True, req.seed,
-                                   req.temperature, req.top_p, req.top_k,
-                                   hist=(self._spec_seed(req.prompt)
-                                         if self._spec is not None
-                                         else None))
-            else:
-                self._tables_host[slot] = self._slot_rows[slot]
-                self._tables_dirty = True
+            # activation rides the next traced scatter: table row,
+            # position, active flag, sampling params — and on spec engines
+            # the drafter ring seeded with the prompt — in one update (the
+            # device table is authoritative)
+            self._queue_update(slot, self._slot_rows[slot],
+                               len(req.prompt) + 1, True, req.seed,
+                               req.temperature, req.top_p, req.top_k,
+                               hist=(self._spec_seed(req.prompt)
+                                     if self._spec is not None else None))
             if firsts is not None:
                 req.output.append(int(firsts[row]))
             else:
@@ -2814,7 +2600,6 @@ class ContinuousBatchingEngine:
             b = self._bucket(len(req.prompt))
             groups.setdefault((b, len(req.prompt) != b), []).append(
                 (slot, req))
-        self._samp_dev = None   # sampling params change -> re-upload lazily
         for (padded, _), grp in groups.items():
             # the prefill program also scatters the group's first tokens into
             # the device-resident last-token carry (no eager device ops here:
@@ -2838,10 +2623,6 @@ class ContinuousBatchingEngine:
         entries = []
         ft_marks = [] if self.tracer is not None else None
         for row, (slot, req) in enumerate(grp):
-            self._temps[slot] = req.temperature
-            self._tops[slot] = req.top_p
-            self._topks[slot] = req.top_k
-            self._seeds[slot] = req.seed
             self._slots[slot] = req
             self._occupied[slot] = req
             req._n_out += 1
@@ -2854,16 +2635,14 @@ class ContinuousBatchingEngine:
                                   tags=self._req_tags(req))
                 ft_marks.append((req.rid, req._n_out))
             self._pos[slot] = len(req.prompt) + 1
-            if self._fused:
-                # static slot-owned tables in legacy layout: activation
-                # only flips act/pos/sampling (+ the spec drafter seed)
-                # via the traced scatter
-                self._queue_update(slot, None, len(req.prompt) + 1, True,
-                                   req.seed, req.temperature, req.top_p,
-                                   req.top_k,
-                                   hist=(self._spec_seed(req.prompt)
-                                         if self._spec is not None
-                                         else None))
+            # static slot-owned tables in this layout: activation only
+            # flips act/pos/sampling (+ the spec drafter seed) via the
+            # traced scatter
+            self._queue_update(slot, None, len(req.prompt) + 1, True,
+                               req.seed, req.temperature, req.top_p,
+                               req.top_k,
+                               hist=(self._spec_seed(req.prompt)
+                                     if self._spec is not None else None))
             if firsts is not None:
                 req.output.append(int(firsts[row]))
             else:

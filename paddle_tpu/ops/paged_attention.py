@@ -454,6 +454,16 @@ def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = jnp.where(ctx > 0, out, 0.0).astype(o_ref.dtype)
 
 
+def _kernel_takes(pool) -> bool:
+    """Whether ``pt_paged_decode`` takes this pool's pages: Mosaic's page
+    DMA needs a 128-aligned trailing dim and a sublane-aligned page dim (8
+    sublanes at 4-byte, 16 at 2-byte, 32 at 1-byte); other shapes go to the
+    dense gather."""
+    _, _, page, d = pool.shape
+    sublane = {4: 8, 2: 16, 1: 32}.get(jnp.dtype(pool.dtype).itemsize, 8)
+    return d % 128 == 0 and page % sublane == 0
+
+
 def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
                            scale=None, interpret: bool = False):
     """One-token-per-sequence paged decode.
@@ -484,17 +494,13 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
         # which dequantizes in the gather (open TPU-kernel work)
         return paged_decode_reference(q, k_cache, v_cache, block_tables,
                                       context_lens, scale)
-    # Mosaic page-DMA slicing needs a 128-aligned trailing dim and a
-    # sublane-aligned page dim — 8 sublanes at 4-byte, 16 at 2-byte, 32 at
-    # 1-byte (int8 KV cache); other shapes take the dense-gather fallback
-    itemsize = jnp.dtype(k_cache.dtype).itemsize
-    sublane = {4: 8, 2: 16, 1: 32}.get(itemsize, 8)
-    shapes_ok = d % 128 == 0 and page % sublane == 0
-    if not interpret and (jax.default_backend() != "tpu" or not shapes_ok):
+    if not interpret and (jax.default_backend() != "tpu"
+                          or not _kernel_takes(k_cache)):
         return paged_decode_reference(q, k_cache, v_cache, block_tables,
                                       context_lens, scale)
     max_pages = block_tables.shape[1]
-    C = _decode_chunk_pages(max_pages, hkv, page, d, itemsize)
+    C = _decode_chunk_pages(max_pages, hkv, page, d,
+                            jnp.dtype(k_cache.dtype).itemsize)
 
     kernel = functools.partial(
         _paged_decode_kernel, page=page, C=C, max_pages=max_pages,
@@ -881,9 +887,19 @@ def append_paged_kv(k_cache, v_cache, k_new, v_new, block_tables, positions,
     if isinstance(k_cache, QuantizedKVPool):
         return (_append_quantized(k_cache, k_new, page_idx, offs),
                 _append_quantized(v_cache, v_new, page_idx, offs))
-    k_cache = k_cache.at[page_idx, :, offs, :].set(k_new)
-    v_cache = v_cache.at[page_idx, :, offs, :].set(v_new)
-    return k_cache, v_cache
+    if _kernel_takes(k_cache):
+        # the kernel reads the default layout, and a scatter indexed on
+        # page, head AND slot (one row of d a token and head) keeps it, in
+        # place. Indexed on page and slot alone, XLA lays the pools a scan
+        # carries out slot-major and converts each for the kernel every
+        # token step: 18 of chat-batch's 30 ms (PERF.md section 6, PR 30)
+        heads = jnp.arange(k_cache.shape[1], dtype=jnp.int32)[None, :]
+        at = (page_idx[:, None], heads, offs[:, None])
+    else:
+        # XLA's own gather reads these pools and XLA picks one layout for
+        # both: heads of 64 step 16% slower in the row form (same section)
+        at = (page_idx, slice(None), offs)
+    return k_cache.at[at].set(k_new), v_cache.at[at].set(v_new)
 
 
 def _append_quantized(pool: QuantizedKVPool, x_new, page_idx, offs):

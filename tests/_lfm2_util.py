@@ -64,7 +64,7 @@ def engine(model, **kw):
                                               PrefixCacheConfig)
 
     args = dict(max_batch=4, max_len=64, page_size=4, block_size=4,
-                fused=True, prefix_cache=PrefixCacheConfig(extra_blocks=8))
+                prefix_cache=PrefixCacheConfig(extra_blocks=8))
     args.update(kw)
     return ContinuousBatchingEngine(model, **args)
 

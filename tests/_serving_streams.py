@@ -29,7 +29,7 @@ def llama_streams():
         max_position_embeddings=64, initializer_range=0.1, dtype="float32"))
     eng = ContinuousBatchingEngine(
         model, max_batch=8, max_len=64, page_size=4, block_size=4,
-        fused=True, prefix_cache=PrefixCacheConfig(extra_blocks=8))
+        prefix_cache=PrefixCacheConfig(extra_blocks=8))
     rng = np.random.Generator(np.random.PCG64(28))
     prefixes = rng.integers(3, 512, (2, 8)).astype(np.int32)
     reqs = []

@@ -189,7 +189,7 @@ def test_engine_int8_init_and_gauge():
     paddle.seed(11)
     cfg = LlamaConfig.tiny(num_hidden_layers=1)
     eng = ContinuousBatchingEngine(LlamaForCausalLM(cfg), max_batch=2,
-                                   max_len=32, page_size=8, fused=True,
+                                   max_len=32, page_size=8,
                                    kv_cache="int8")
     k0 = eng.caches["kv"][0][0]
     assert isinstance(k0, QuantizedKVPool) and str(k0.dtype) == "int8"
@@ -245,7 +245,7 @@ def test_int8_engine_deterministic_and_warm_cold(model):
     def build():
         return ContinuousBatchingEngine(
             m, max_batch=2, max_len=32, page_size=8, block_size=2,
-            fused=True, kv_cache="int8",
+            kv_cache="int8",
             prefix_cache=PrefixCacheConfig(extra_blocks=4))
 
     a, b = build(), build()
@@ -267,14 +267,14 @@ def test_spec_plus_int8_is_deterministic_and_warm_cold(model):
                                               PrefixCacheConfig, SpecConfig)
 
     cfg, m = model
-    # all-greedy wave: a block containing any sampled row keeps the legacy
+    # all-greedy wave: a block containing any sampled row keeps the scan
     # mega-step, and this pin needs the spec path to actually run
     kws = [dict(kw, temperature=0.0) for kw in _requests(cfg)]
 
     def build():
         return ContinuousBatchingEngine(
             m, max_batch=2, max_len=32, page_size=8, block_size=2,
-            fused=True, kv_cache="int8", speculative=SpecConfig(k=3),
+            kv_cache="int8", speculative=SpecConfig(k=3),
             prefix_cache=PrefixCacheConfig(extra_blocks=4))
 
     a, b = build(), build()

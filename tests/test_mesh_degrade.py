@@ -71,7 +71,6 @@ def _builder(model, mesh_tp, **kw):
         mesh = None if mesh_tp is None else MeshConfig(tp=int(mesh_tp))
         return ContinuousBatchingEngine(
             m, max_batch=4, max_len=64, page_size=8, block_size=4,
-            fused=True,
             prefix_cache=PrefixCacheConfig(prefill_chunk=16, extra_blocks=8),
             mesh=mesh, **kw)
 
@@ -197,7 +196,6 @@ def test_mesh_config_validation_edges(model1):
     def mk(**kw):
         return ContinuousBatchingEngine(
             m, max_batch=4, max_len=64, page_size=8, block_size=4,
-            fused=True,
             prefix_cache=PrefixCacheConfig(prefill_chunk=16,
                                            extra_blocks=8), **kw)
 
@@ -403,8 +401,8 @@ def test_degrade_4to2_identity(model4, tmp_path):
 
 @pytest.mark.slow   # spec engines at two widths = their own compile waves
 def test_degrade_spec_decode_identity(model4, tmp_path):
-    # greedy-only wave: a batch with sampling rows keeps the legacy
-    # (non-spec) path, so the drafter would never engage post-shrink
+    # greedy-only wave: a batch with sampling rows keeps the scan
+    # (non-spec) mega-step, so the drafter would never engage post-shrink
     cfg, _ = model4
     wave = [dict(kw) for kw in _wave(cfg)]
     for kw in wave:

@@ -237,15 +237,15 @@ class TestTraceRecorder:
 # ---------------------------------------------------------------------------
 # engine / supervisor integration. Tier-1 wall clock is at its 870 s
 # ceiling (see memory / PR 5's budget rescue), so the FAST pin is a
-# minimal legacy-engine chain test; the full supervisor lifecycle +
-# crash-replay proof is slow-marked (its span semantics are all
-# unit-pinned fast above, and tools/scrape_metrics.py --selftest gates
-# the end-to-end fleet path).
+# minimal chain test on the engine without a prefix cache; the full
+# supervisor lifecycle + crash-replay proof is slow-marked (its span
+# semantics are all unit-pinned fast above, and tools/scrape_metrics.py
+# --selftest gates the end-to-end fleet path).
 # ---------------------------------------------------------------------------
 
 def test_traced_minimal_chain_fast(model):
-    """Fast integration pin: one request through the LEGACY engine (two
-    compiled programs) produces the ordered
+    """Fast integration pin: one request through the engine without a
+    prefix cache (three compiled programs) produces the ordered
     submit->admit->first_token->finish chain, exactly one terminal, a
     schema-valid chrome export, and a TTFT observation."""
     cfg, m = model

@@ -30,7 +30,7 @@ def _engine(m, tracer=None, **kw):
     kw.setdefault("prefix_cache",
                   PrefixCacheConfig(prefill_chunk=16, extra_blocks=8))
     return ContinuousBatchingEngine(m, max_batch=4, max_len=64, page_size=8,
-                                    block_size=4, fused=True, tracer=tracer,
+                                    block_size=4, tracer=tracer,
                                     **kw)
 
 
@@ -183,16 +183,18 @@ def test_first_call_runs_above_a_frame_chunk_of_its_own():
         first_call(lambda: 1 / 0)
 
 
-@pytest.mark.parametrize("fused", [True, False], ids=["fused", "legacy"])
-def test_stamps_wait_for_the_values_without_eos(model, fused):
+@pytest.mark.parametrize("prefix", [True, False],
+                         ids=["prefix_cache", "static_pool"])
+def test_stamps_wait_for_the_values_without_eos(model, prefix):
     """On the path without eos nothing is read at dispatch: first_token,
     token progress and the terminal are stamped when ``_drain_pending``
-    brings the values to the host, in lifecycle order."""
+    brings the values to the host, in lifecycle order — whether the first
+    tokens were booked by ``_emit_first`` (prefix cache) or by
+    ``_emit_group`` (the pool layout without one)."""
     cfg, m = model
     rec = TraceRecorder()
-    eng = (_engine(m, tracer=rec) if fused else ContinuousBatchingEngine(
-        m, max_batch=2, max_len=64, page_size=8, block_size=4, fused=False,
-        tracer=rec))
+    eng = (_engine(m, tracer=rec) if prefix else ContinuousBatchingEngine(
+        m, max_batch=2, max_len=64, page_size=8, block_size=4, tracer=rec))
     reqs = _requests(cfg, None, n=2)
     for r in reqs:
         eng.add_request(r)
@@ -342,12 +344,12 @@ def test_serving_programs_carry_their_names(served):
         assert "module @jit_pt_cow_copy" in _lowered(fn, kv, i32(w), i32(w))
 
 
-def test_legacy_spec_and_reset_programs_carry_their_names(model):
+def test_static_pool_spec_and_reset_programs_carry_their_names(model):
     cfg, m = model
     eng = ContinuousBatchingEngine(m, max_batch=2, max_len=64, page_size=8,
-                                   block_size=4, fused=False)
+                                   block_size=4)
     _run(eng, _requests(cfg, None, n=2))
-    assert eng._jit_step.__wrapped__.__name__ == "pt_decode_block"
+    assert eng._jit_mega.__wrapped__.__name__ == "pt_decode_block"
     assert {fn.__wrapped__.__name__ for fn in eng._jit_prefill.values()} == {
         "pt_prefill_group"}
     spec = _engine(m, speculative=SpecConfig(k=2), kv_cache="int8")

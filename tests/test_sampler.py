@@ -221,7 +221,7 @@ def test_a_decode_block_sorts_once_a_token_step_or_not_at_all(do_sample):
     paddle.seed(11)
     m = LlamaForCausalLM(LlamaConfig.tiny(num_hidden_layers=1))
     eng = ContinuousBatchingEngine(
-        m, max_batch=4, max_len=64, page_size=8, block_size=4, fused=True,
+        m, max_batch=4, max_len=64, page_size=8, block_size=4,
         prefix_cache=PrefixCacheConfig(prefill_chunk=16, extra_blocks=8))
     step = eng._build_mega_jit()
     seeds, temps, tops, topks = eng._dev_samp

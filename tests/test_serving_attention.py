@@ -121,9 +121,12 @@ def test_paged_decode_zero_length_neighbors_intact():
                                    atol=2e-5)
 
 
-def test_append_and_gather_paged_kv_roundtrip():
+@pytest.mark.parametrize("d", [32, 128], ids=["xla_reads", "kernel_reads"])
+def test_append_and_gather_paged_kv_roundtrip(d):
+    """Both forms of the append's scatter (``_kernel_takes``): a pool XLA's
+    gather reads, and one the paged kernel reads."""
     rng = np.random.default_rng(1)
-    b, hkv, d, page, maxp, npages = 3, 2, 32, 8, 4, 12
+    b, hkv, page, maxp, npages = 3, 2, 8, 4, 12
     kc = jnp.zeros((npages, hkv, page, d))
     vc = jnp.zeros((npages, hkv, page, d))
     tables = jnp.asarray(rng.permutation(npages).reshape(-1)[: b * maxp]

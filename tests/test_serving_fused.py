@@ -1,15 +1,20 @@
-"""Fused mega-step serving (inference/serving.py ``fused=``, auto at
-max_batch >= 32 — docs/SERVING.md): device-resident block tables /
-positions / sampling state updated by traced scatters, ONE jitted decode
-program over all rows with masked inactive rows, prompt-packing prefill,
-and O(active) host bookkeeping.
+"""The engine's step loop (inference/serving.py — docs/SERVING.md "The
+mega-step"): device-resident block tables / positions / sampling state
+updated by traced scatters, ONE jitted decode program over all rows with
+masked inactive rows, prompt-packing prefill, and O(active) host
+bookkeeping. One family at every ``max_batch`` since PR 30.
 
-The contract under test: fused token streams are BYTE-IDENTICAL to the
-legacy per-slot step path (greedy AND seeded), at any slot count, prefix
-cache on or off, warm or cold, across COW divergence and crash replay.
-The 128-slot acceptance pin (ISSUE 10) is slow-marked; every behavior has
-a fast 8-slot pin here — tier-1 sits near its 870 s ceiling.
+The contract under test: token streams are BYTE-IDENTICAL to
+``generate()`` (greedy) and to the streams the legacy per-slot step
+programs produced at PR 30's parent (greedy AND seeded:
+``tests/data/serving_legacy_wave_streams.json``), at any slot count,
+prefix cache on or off, warm or cold, across COW divergence and crash
+replay. The 128-slot acceptance pin (ISSUE 10) is slow-marked; every
+behavior has a fast 8-slot pin here.
 """
+
+import json
+import os
 
 import numpy as np
 import pytest
@@ -30,28 +35,48 @@ def model():
 
 
 @pytest.fixture(scope="module")
-def leg(model):
-    """Legacy per-slot reference engine (2 slots, prefix off)."""
-    _, m = model
-    return ContinuousBatchingEngine(m, max_batch=2, max_len=64, page_size=8,
-                                    block_size=4, fused=False)
+def recorded():
+    """What the legacy family (a 2-slot engine, ``fused=False``) served at
+    PR 30's parent for ``_wave`` and the eos request: the sampled streams
+    have no other reference (``generate()`` splits its key, the engine
+    folds (seed, position))."""
+    with open(os.path.join(os.path.dirname(__file__), "data",
+                           "serving_legacy_wave_streams.json")) as f:
+        return json.load(f)
+
+
+def _ref(m, prompt, n):
+    out = m.generate(paddle.to_tensor(np.asarray(prompt)[None]),
+                     max_new_tokens=n, temperature=0.0).numpy()[0]
+    return [int(t) for t in out]
+
+
+def _want(m, prompts, kws, recorded):
+    """The wave's reference streams: ``generate()`` for the greedy
+    requests (which the recording must equal too), the recording for the
+    seeded ones."""
+    want = [list(s) for s in recorded["wave"]]
+    for i, (p, kw) in enumerate(zip(prompts, kws)):
+        if "temperature" not in kw:
+            assert want[i] == _ref(m, p, kw["max_new_tokens"])
+    return want
 
 
 @pytest.fixture(scope="module")
 def fus(model):
-    """Fused engine, prefix off (8 slots — same programs the 128-slot
-    engine runs, cheaper to compile)."""
+    """Engine with the prefix cache off (8 slots — same programs the
+    128-slot engine runs, cheaper to compile)."""
     _, m = model
     return ContinuousBatchingEngine(m, max_batch=8, max_len=64, page_size=8,
-                                    block_size=4, fused=True)
+                                    block_size=4)
 
 
 @pytest.fixture(scope="module")
 def fusp(model):
-    """Fused engine with the prefix cache + packed prefill."""
+    """Engine with the prefix cache + packed prefill."""
     _, m = model
     return ContinuousBatchingEngine(
-        m, max_batch=8, max_len=64, page_size=8, block_size=4, fused=True,
+        m, max_batch=8, max_len=64, page_size=8, block_size=4,
         prefix_cache=PrefixCacheConfig(prefill_chunk=16, extra_blocks=8))
 
 
@@ -88,12 +113,13 @@ def _serve(eng, prompts, kws, stagger=True):
     return [list(r.tokens) for r in reqs]
 
 
-def test_fused_matches_legacy_greedy_and_seeded(model, leg, fus):
-    """The core contract: fused 8-slot streams == legacy 2-slot streams,
-    byte for byte, mixed greedy + seeded sampling, staggered arrivals."""
-    cfg, _ = model
+def test_fused_matches_legacy_greedy_and_seeded(model, recorded, fus):
+    """The core contract: 8-slot streams == generate() and the recorded
+    legacy 2-slot streams, byte for byte, mixed greedy + seeded sampling,
+    staggered arrivals."""
+    cfg, m = model
     prompts, kws = _wave(cfg)
-    want = _serve(leg, prompts, kws)
+    want = _want(m, prompts, kws, recorded)
     got = _serve(fus, prompts, kws)
     assert got == want
     assert fus.stats["fused_updates"] > 0      # scatters actually ran
@@ -102,40 +128,117 @@ def test_fused_matches_legacy_greedy_and_seeded(model, leg, fus):
     assert fus.active_slots() == 0 and len(fus._free_slots) == fus.max_batch
 
 
-def test_fused_prefix_warm_cold_cow_identity(model, leg, fusp):
-    """Prefix-cache fused: cold == warm == legacy. The warm wave re-serves
+def test_fused_prefix_warm_cold_cow_identity(model, recorded, fusp):
+    """Prefix cache: cold == warm == reference. The warm wave re-serves
     two full-page prompts, so the batched-COW path (one device dispatch
     for the wave's copies) and the radix hits are both on the tested
     path; the packed prefill must also have fired."""
-    cfg, _ = model
+    cfg, m = model
     prompts, kws = _wave(cfg)
-    want = _serve(leg, prompts, kws)
+    want = _want(m, prompts, kws, recorded)
     cold = _serve(fusp, prompts, kws)
     warm = _serve(fusp, prompts, kws)
     assert cold == want and warm == want
     assert fusp.stats["hit_tokens"] > 0
     assert fusp.stats["cow_copies"] > 0        # full-prompt hits -> COW
     assert fusp.stats["packed_rows"] > 0       # prompt-packing prefill ran
-    # prefix fused: every table row parked on device once drained
+    # every table row parked on device once drained
     assert (np.asarray(fusp.caches["tables"]) == fusp._park).all()
 
 
-def test_fused_eos_early_exit(model, leg, fus):
-    """eos-carrying fused batches pace at block_size and stop early,
-    exactly like the legacy path (token-for-token, including the cut)."""
-    cfg, _ = model
+def test_fused_eos_early_exit(model, recorded, fus):
+    """eos-carrying batches pace at block_size and stop early, token for
+    token as generate() and the recorded legacy stream do — with an eos id
+    the stream never meets (the recorded case) and with one it meets
+    mid-block (the cut)."""
+    cfg, m = model
     p = _prompt(cfg, 7, 401)
-    out = []
-    for eng in (leg, fus):
-        r = Request(p, max_new_tokens=12, eos_token_id=3)
-        eng.add_request(r)
-        eng.run_until_done(max_steps=200)
-        out.append(list(r.tokens))
-    assert out[0] == out[1]
+    ref = _ref(m, p, 12)
+    assert recorded["eos"] == ref and 3 not in ref
+    cut = ref.index(ref[5]) + 1            # mid-block at block_size 4
+    for eos, want in ((3, ref), (ref[5], ref[:cut])):
+        r = Request(p, max_new_tokens=12, eos_token_id=eos)
+        fus.add_request(r)
+        fus.run_until_done(max_steps=200)
+        assert list(r.tokens) == want
+
+
+def test_heads_of_128_serve_generates_streams_through_the_row_append():
+    """Heads the paged kernel takes (``d % 128 == 0``) append through the
+    row form of ``append_paged_kv``'s scatter; every other engine test
+    here has heads of 16 and takes the other form. Greedy streams equal
+    ``generate()``'s cold, and warm through prefix hits and COW."""
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.ops.paged_attention import _kernel_takes
+
+    paddle.seed(12)
+    cfg = LlamaConfig.tiny(num_hidden_layers=1, hidden_size=256,
+                           num_attention_heads=2, num_key_value_heads=1)
+    m = LlamaForCausalLM(cfg)
+    eng = ContinuousBatchingEngine(
+        m, max_batch=4, max_len=64, page_size=8, block_size=4,
+        prefix_cache=PrefixCacheConfig(prefill_chunk=16, extra_blocks=8))
+    assert _kernel_takes(eng.caches["kv"][0][0])
+    shared = _prompt(cfg, 16, 41)
+    prompts = [np.concatenate([shared, _prompt(cfg, n, 50 + n)])
+               for n in (0, 3, 9, 20)] + [_prompt(cfg, 5, 60)]
+    kws = [dict(max_new_tokens=6)] * len(prompts)
+    want = [_ref(m, p, 6) for p in prompts]
+    assert _serve(eng, prompts, kws) == want            # cold
+    hits = eng.stats["hit_tokens"]
+    assert _serve(eng, prompts, kws) == want            # warm
+    assert eng.stats["hit_tokens"] > hits and eng.stats["cow_copies"] > 0
+
+
+def test_fused_false_is_refused(model):
+    """``fused=`` chooses nothing since PR 30: None and True are accepted
+    (chipbench's cell files hand ``"fused": true`` over), False is refused
+    by name before anything is built."""
+    _, m = model
+    with pytest.raises(ValueError, match="fused=False.*PR 30"):
+        ContinuousBatchingEngine(m, max_batch=2, max_len=64, page_size=8,
+                                 fused=False)
+    ContinuousBatchingEngine(m, max_batch=2, max_len=64, page_size=8,
+                             fused=True)
+
+
+def test_eight_slots_take_the_mega_step_and_upload_no_table(model,
+                                                            monkeypatch):
+    """An engine built with max_batch=8 and no flag (the size at which the
+    parent chose the legacy family) dispatches ``jit_pt_decode_block``
+    through ``_build_mega_jit``, and between decode blocks nothing is
+    uploaded: the device table object changes only where a slot update
+    was queued (an admission or a release)."""
+    cfg, m = model
+    eng = ContinuousBatchingEngine(
+        m, max_batch=8, max_len=64, page_size=8, block_size=4,
+        prefix_cache=PrefixCacheConfig(prefill_chunk=16, extra_blocks=8))
+    built = []
+    real = eng._build_mega_jit
+    monkeypatch.setattr(eng, "_build_mega_jit",
+                        lambda: built.append(real()) or built[-1])
+    for i in range(2):
+        # an eos id no token equals: blocks of block_size, values read back
+        eng.add_request(Request(_prompt(cfg, 5, 700 + i), max_new_tokens=17,
+                                eos_token_id=-1))
+    eng.step()                              # admit, prefill, first tokens
+    seen = []
+    while eng.has_work():
+        queued = bool(eng._upd)
+        before = eng.caches["tables"]
+        eng.step()
+        seen.append((queued, eng.caches["tables"] is before))
+    assert len(built) == 1 and eng._jit_mega is built[0]
+    assert built[0].__wrapped__.__name__ == "pt_decode_block"
+    assert ("pt_decode_block", (4, False)) in eng._built
+    # steps with nothing queued kept the very same device array
+    quiet = [same for queued, same in seen if not queued]
+    assert len(quiet) >= 2 and all(quiet)
+    assert not hasattr(eng, "_tables_host")
 
 
 def test_fused_deadline_eviction_survivor_unharmed(model, fusp):
-    """Deadline eviction in fused mode: the expired slot is failed and its
+    """Deadline eviction: the expired slot is failed and its
     row parked via the update queue; the surviving stream is untouched.
     The no-deadline fast path stays O(1) (``_n_deadlined`` gate)."""
     cfg, _ = model
@@ -187,9 +290,9 @@ def test_fused_counters_track_occupancy(model, fus):
 #                     test_serving_recovery's journal-restart test (same
 #                     posture as PR 5's crash-recovery slow-mark)
 def test_fused_crash_replay_bit_identical(model, tmp_path):
-    """ServingSupervisor over a FUSED engine: a ``serving.step`` kill
+    """ServingSupervisor over the engine: a ``serving.step`` kill
     mid-wave rebuilds from the journal and the replayed streams (greedy +
-    seeded) are byte-identical to an uninterrupted fused run — the
+    seeded) are byte-identical to an uninterrupted run — the
     device-resident state is fully reconstructible from the journal, as
     the recovery contract requires."""
     cfg, m = model
@@ -198,7 +301,7 @@ def test_fused_crash_replay_bit_identical(model, tmp_path):
     def build():
         return ContinuousBatchingEngine(
             m, max_batch=4, max_len=32, page_size=8, block_size=2,
-            fused=True, prefix_cache=PrefixCacheConfig(prefill_chunk=8))
+            prefix_cache=PrefixCacheConfig(prefill_chunk=8))
 
     pa, pb = _prompt(cfg, 8, 601), _prompt(cfg, 6, 602)
 
@@ -243,7 +346,7 @@ def test_tracer_batched_stamps_equal_per_slot_stamps(values_on_host):
     for rid in (1, 2):
         a.submit(rid, 4, 8)
         b.submit(rid, 4, 8)
-    # per-slot stamping (legacy shape)
+    # per-slot stamping
     for rid in (1, 2):
         a.first_token(rid)
         a.tokens(rid, 1)
@@ -251,7 +354,7 @@ def test_tracer_batched_stamps_equal_per_slot_stamps(values_on_host):
            parent=None)
     for rid in (1, 2):
         a.tokens(rid, 5)
-    # batched stamping (fused shape)
+    # batched stamping (the engine's)
     b.first_tokens([(1, 1), (2, 1)])
     with program_span("serve.decode.dispatch", b, n_steps=4, rows=2):
         pass
@@ -272,16 +375,15 @@ def test_tracer_batched_stamps_equal_per_slot_stamps(values_on_host):
 @pytest.mark.slow   # one 128-row compile wave (~3-4 min budget class) —
 #                     the fast 8-slot pins above cover every behavior;
 #                     this is the ISSUE 10 acceptance config end-to-end
-def test_fused_128_slots_byte_identical_to_legacy(model, leg):
-    """Acceptance pin: max_batch=128 fused engine (prefix cache + packed
-    prefill + batched COW) serves a 160-request mixed wave with every
-    stream byte-identical to the legacy 8-slot-class path, cold AND warm,
-    and the engine drains clean."""
+def test_fused_128_slots_byte_identical_to_legacy(model):
+    """Acceptance pin: a max_batch=128 engine (prefix cache + packed
+    prefill + batched COW) serves a 160-request greedy wave with every
+    stream byte-identical to generate() (the reference the legacy 8-slot
+    engine was held to), cold AND warm, and the engine drains clean."""
     cfg, m = model
     eng = ContinuousBatchingEngine(
         m, max_batch=128, max_len=32, page_size=8, block_size=4,
         prefix_cache=PrefixCacheConfig(prefill_chunk=8, extra_blocks=16))
-    assert eng._fused                     # auto-enabled at big batch
     rng = np.random.default_rng(7)
     prompts = [rng.integers(0, cfg.vocab_size,
                             (8 + (i % 3) * 4,)).astype(np.int32)
@@ -298,37 +400,17 @@ def test_fused_128_slots_byte_identical_to_legacy(model, leg):
 
     cold = wave(eng)
     warm = wave(eng)
-    want = wave(ContinuousBatchingEngine(m, max_batch=8, max_len=32,
-                                         page_size=8, block_size=4,
-                                         fused=False))
+    # greedy streams are prefixes of one another: one generate() call a
+    # prompt length, cut to each request's budget
+    want = [None] * 160
+    for n in (8, 12, 16):
+        rows = [i for i, p in enumerate(prompts) if len(p) == n]
+        toks = m.generate(paddle.to_tensor(np.stack([prompts[i]
+                                                     for i in rows])),
+                          max_new_tokens=max(news),
+                          temperature=0.0).numpy()
+        for i, row in zip(rows, toks):
+            want[i] = [int(t) for t in row[:news[i]]]
     assert cold == want and warm == want
     assert eng.stats["cow_copies"] > 0 and eng.stats["packed_rows"] > 0
     assert eng.active_slots() == 0 and len(eng._free_slots) == 128
-
-
-@pytest.mark.slow   # one extra prefix-engine compile wave beside the module
-#                     fixtures (tier-1 ceiling) — the fast pins are
-#                     test_program_cost.py::test_engine_declares_mega_and_
-#                     chunk_donation (declaration covers the carries) plus
-#                     EVERY fused-vs-legacy identity test above, which runs
-#                     the donated path (donate_carry defaults True) against
-#                     the undonated legacy engine
-def test_donation_off_byte_identity(model, fusp):
-    """PT-COST triage proof (docs/STATIC_ANALYSIS.md "Program cost"):
-    donating the mega-step / prefill-chunk / first-token kv carries is a
-    memory optimization only — a donate_carry=False engine serving the
-    same mixed wave (prefix cache, packed prefill, COW, warm + cold)
-    produces byte-identical streams to the donated module fixture."""
-    cfg, m = model
-    prompts, kws = _wave(cfg)
-    want_cold = _serve(fusp, prompts, kws)
-    want_warm = _serve(fusp, prompts, kws)
-    eng = ContinuousBatchingEngine(
-        m, max_batch=8, max_len=64, page_size=8, block_size=4, fused=True,
-        prefix_cache=PrefixCacheConfig(prefill_chunk=16, extra_blocks=8),
-        donate_carry=False)
-    assert eng._donate_carry is False
-    assert fusp._donate_carry is True
-    cold = _serve(eng, prompts, kws)
-    warm = _serve(eng, prompts, kws)
-    assert cold == want_cold and warm == want_warm

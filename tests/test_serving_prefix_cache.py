@@ -41,11 +41,22 @@ def eng(model):
 @pytest.fixture(scope="module")
 def eng2(model):
     """Shared small-block engine (chunked prefill + deadline tests): one
-    compile set for both — tier-1 budget."""
+    compile set for both — tier-1 budget. A pack of TWO 8-token rows
+    (``_run_pack``'s budget is max(mid-prefill slots, pack_rows)), so a
+    24-token prompt is longer than one pack and stays mid-prefill for a
+    step."""
     _, m = model
     return ContinuousBatchingEngine(
         m, max_batch=2, max_len=32, page_size=8, block_size=2,
-        prefix_cache=PrefixCacheConfig(prefill_chunk=8))
+        prefix_cache=PrefixCacheConfig(prefill_chunk=8, pack_rows=2))
+
+
+def _drain(e):
+    """Leave the module-scoped engine as the next test expects it: nothing
+    in flight, no cached chain. Asserts nothing: it runs in ``finally``,
+    where a failed check would hide the test's own failure."""
+    e.run_until_done(max_steps=300)
+    e._radix.evict_lru(e._alloc.num_blocks)
 
 
 def _prompt(cfg, n, seed):
@@ -228,7 +239,7 @@ def test_prefix_cache_fresh_engine_determinism(model):
 # ---------------------------------------------------------------------------
 
 def test_chunked_prefill_interleaves_with_decode(model, eng2):
-    """A long admit advances one chunk per step while an active slot keeps
+    """A long admit advances one pack per step while an active slot keeps
     decoding — and both streams match single-request generate()."""
     cfg, m = model
     e = eng2
@@ -239,8 +250,10 @@ def test_chunked_prefill_interleaves_with_decode(model, eng2):
     e.step()                              # short admitted and decoding
     rl = Request(long_p, max_new_tokens=4)
     e.add_request(rl)
-    e.step()                              # long admitted: ONE chunk only
-    assert e._prefill_next and min(e._prefill_next.values()) == 8
+    e.step()                              # long admitted: ONE pack only,
+    #                                       two rows of 8 of its 24 tokens
+    assert list(e._prefill_next.values()) == [16]
+    assert not rs.done                    # decode goes on beside it
     decoded_mid_prefill = rs._n_out
     e.run_until_done(max_steps=300)
     assert rs._n_out > decoded_mid_prefill or rs.done
@@ -262,23 +275,35 @@ def test_deadline_eviction_decrefs_not_frees_shared_blocks(model, eng2):
     pA = np.concatenate([shared, _prompt(cfg, 4, 110)])
     pB = np.concatenate([shared, _prompt(cfg, 5, 111)])
     refA = _ref(m, pA, 12)
-    rA = Request(pA, max_new_tokens=12)
-    e.add_request(rA)
-    for _ in range(10):                   # A chunk-prefills; its prompt
-        e.step()                          # blocks register at first token
-        if rA._n_out:
-            break
-    assert rA._n_out and not rA.done
-    hits0 = e.stats["hit_tokens"]
-    rB = Request(pB, max_new_tokens=11, deadline_s=0.05)
-    e.add_request(rB)
-    e.step()                              # B admitted sharing A's prefix
-    assert e.stats["hit_tokens"] - hits0 >= 16   # the share is real
-    time.sleep(0.1)
-    e.run_until_done(max_steps=300)
-    assert rB.failed and rB.done and "deadline" in rB.error
-    assert rA.done and not rA.failed
-    assert rA.tokens == refA              # survivor undisturbed
+    try:
+        rA = Request(pA, max_new_tokens=12)
+        e.add_request(rA)
+        for _ in range(10):               # A chunk-prefills; its prompt
+            e.step()                      # blocks register at first token
+            if rA._n_out:
+                break
+        assert rA._n_out and not rA.done
+        hits0 = e.stats["hit_tokens"]
+        rB = Request(pB, max_new_tokens=11, deadline_s=0.05)
+        # exercise EVICTION, not submit shedding: on a slow machine the
+        # feasibility shedder would refuse the doomed deadline at submit
+        # (that path has its own tests)
+        e.shed_infeasible = False
+        try:
+            e.add_request(rB)
+        finally:
+            e.shed_infeasible = True
+        e.step()                          # B admitted sharing A's prefix
+        assert e.stats["hit_tokens"] - hits0 >= 16   # the share is real
+        time.sleep(0.1)
+        e.run_until_done(max_steps=300)
+        assert rB.failed and rB.done and "deadline" in rB.error
+        assert rA.done and not rA.failed
+        assert rA.tokens == refA          # survivor undisturbed
+        _drain(e)
+        assert e._alloc.free_blocks == e._alloc.num_blocks  # no page leaked
+    finally:
+        _drain(e)
 
 
 def test_deadline_eviction_mid_chunked_prefill_releases_pages(model, eng2):
@@ -289,15 +314,17 @@ def test_deadline_eviction_mid_chunked_prefill_releases_pages(model, eng2):
     next admission into the freed slot."""
     cfg, m = model
     e = eng2
-    # deterministic eviction: the feasibility shedder would refuse the
-    # doomed deadline at submit on a warm engine (that path has its own
-    # tests) — this test needs the request ADMITTED so eviction can bite
-    e.shed_infeasible = False
-    # start from a drained pool: leftover cached chains from earlier tests
-    # would make the conservation check depend on test history
-    e._radix.evict_lru(e._alloc.num_blocks)
-    assert e._alloc.free_blocks == e._alloc.num_blocks
     try:
+        # start from a drained pool: leftover cached chains (or a request a
+        # failed neighbour left in flight) would make the conservation
+        # check depend on test history
+        _drain(e)
+        assert e._alloc.free_blocks == e._alloc.num_blocks
+        # deterministic eviction: the feasibility shedder would refuse the
+        # doomed deadline at submit on a warm engine (that path has its
+        # own tests); this test needs the request ADMITTED so eviction
+        # can bite
+        e.shed_infeasible = False
         pa, pb, pc = _prompt(cfg, 6, 130), _prompt(cfg, 24, 131), \
             _prompt(cfg, 6, 132)
         refA = _ref(m, pa, 26)
@@ -306,9 +333,9 @@ def test_deadline_eviction_mid_chunked_prefill_releases_pages(model, eng2):
         e.step()                          # A decoding (4 pages)
         rB = Request(pb, max_new_tokens=4, deadline_s=0.25)
         e.add_request(rB)
-        e.step()                          # B admitted: ONE chunk prefilled
+        e.step()                          # B admitted: ONE pack prefilled
         slot_b = next(iter(e._prefill_next))
-        assert e._prefill_next[slot_b] < len(pb)   # genuinely mid-prefill
+        assert e._prefill_next[slot_b] == 16 < len(pb)   # mid-prefill
         blocks_b = list(e._slot_blocks[slot_b])    # all 4 pages parked
         assert len(blocks_b) == 4 and e._alloc.free_blocks == 0
         time.sleep(0.3)
@@ -316,7 +343,11 @@ def test_deadline_eviction_mid_chunked_prefill_releases_pages(model, eng2):
         assert rB.failed and rB.done and "deadline" in rB.error
         assert slot_b not in e._prefill_next       # out of the prefill group
         assert e._slots[slot_b] is None
-        assert (e._tables_host[slot_b] == e._park).all()
+        # the release rode the step's traced scatter: row parked and
+        # inactive on the device (there is no host table)
+        assert slot_b not in e._upd
+        assert (np.asarray(e.caches["tables"])[slot_b] == e._park).all()
+        assert not np.asarray(e._dev_act)[slot_b]
         # every parked/partial page back in the pool — B never registered,
         # so nothing may linger cached-idle either
         for b in blocks_b:
@@ -327,9 +358,11 @@ def test_deadline_eviction_mid_chunked_prefill_releases_pages(model, eng2):
         e.run_until_done(max_steps=300)
         assert rA.tokens == refA                   # survivor undisturbed
         assert rC.tokens == _ref(m, pc, 4)
+        e._radix.evict_lru(e._alloc.num_blocks)    # A's and C's chains
         assert e._alloc.free_blocks == e._alloc.num_blocks  # no page leaked
     finally:
         e.shed_infeasible = True
+        _drain(e)
 
 
 @pytest.mark.slow   # the fault drill (CI-gated) covers this end-to-end

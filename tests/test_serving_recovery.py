@@ -238,10 +238,11 @@ def test_resume_submit_never_shed(model, tmp_path):
 # supervisor: crash recovery, restart + backpressure, brownout
 # ---------------------------------------------------------------------------
 
-def _build_prefix(m, max_queue=None):
+def _build_prefix(m, max_queue=None, pack_rows=None):
     return ContinuousBatchingEngine(
         m, max_batch=2, max_len=32, page_size=8, block_size=2,
-        prefix_cache=PrefixCacheConfig(prefill_chunk=8), max_queue=max_queue)
+        prefix_cache=PrefixCacheConfig(prefill_chunk=8, pack_rows=pack_rows),
+        max_queue=max_queue)
 
 
 @pytest.mark.slow   # two full supervisor cycles of engine compiles; the
@@ -300,14 +301,21 @@ def test_journal_restart_replays_with_backpressure_in_flight(model, tmp_path):
                _prompt(cfg, 6, 232), _prompt(cfg, 6, 233)]
     refs = {i: _ref(m, p, 4) for i, p in enumerate(prompts[:3])}
 
-    sup1 = ServingSupervisor(lambda: _build_prefix(m, max_queue=1), path)
+    # a pack of ONE 8-token row (pack_rows=1; _run_pack's budget is
+    # max(mid-prefill slots, pack_rows)): the 24-token prompts are three
+    # packs long, so prefills stay in flight across steps
+    def build():
+        return _build_prefix(m, max_queue=1, pack_rows=1)
+
+    sup1 = ServingSupervisor(build, path)
     r0 = Request(prompts[0], max_new_tokens=4)
     sup1.submit(r0)
     sup1.step()                                 # slot 0: chunk 1 of 3
+    assert sup1.engine._prefill_next == {0: 8}
     r1 = Request(prompts[1], max_new_tokens=4)
     sup1.submit(r1)
-    sup1.step()                                 # slot 1: chunk 1 of 3
-    assert len(sup1.engine._prefill_next) == 2  # chunked prefills IN FLIGHT
+    sup1.step()                                 # one row a slot: 2/3, 1/3
+    assert sup1.engine._prefill_next == {0: 16, 1: 8}   # prefills IN FLIGHT
     r2 = Request(prompts[2], max_new_tokens=4)
     sup1.submit(r2)                             # queued (high-water mark)
     with pytest.raises(EngineSaturated):
@@ -316,7 +324,7 @@ def test_journal_restart_replays_with_backpressure_in_flight(model, tmp_path):
     sup1.step()
     sup1.close()                                # "process death" mid-flight
 
-    sup2 = ServingSupervisor(lambda: _build_prefix(m, max_queue=1), path)
+    sup2 = ServingSupervisor(build, path)
     assert sorted(sup2.requests) == sorted(rids)    # replay set == journal
     sup2.run_until_done(max_steps=500)
     sup2.close()

@@ -1,4 +1,4 @@
-"""Speculative multi-token decoding in the fused mega-step
+"""Speculative multi-token decoding in the mega-step
 (inference/serving.py ``speculative=SpecConfig(...)`` — docs/SERVING.md
 "Speculative decode").
 
@@ -90,7 +90,9 @@ def test_spec_config_validation():
         _validate_engine(speculative=_Cfg)
     with pytest.raises(ValueError, match="history .* too short"):
         _validate_engine(speculative=SpecConfig(k=4, history=4))
-    with pytest.raises(ValueError, match="fused"):
+    # one decode family since PR 30: the flag chooses nothing, and asking
+    # for the family that left is refused by name
+    with pytest.raises(ValueError, match="fused=False.*PR 30"):
         _validate_engine(speculative=True, fused=False)
     with pytest.raises(ValueError, match="unsupported KV cache dtype"):
         KVCacheConfig(dtype="int4")
@@ -101,7 +103,6 @@ def _validate_engine(**kw):
 
     paddle.seed(11)
     cfg = LlamaConfig.tiny(num_hidden_layers=1)
-    kw.setdefault("fused", True)
     return ContinuousBatchingEngine(LlamaForCausalLM(cfg), max_batch=2,
                                     max_len=32, page_size=8, **kw)
 
@@ -184,18 +185,18 @@ def test_spec_byte_identity_cross_widths_warm_cold_cow(model):
     cfg, m = model
     prompts, kws = _wave(cfg)
     ref = _serve(ContinuousBatchingEngine(
-        m, max_batch=4, max_len=64, page_size=8, block_size=2, fused=True),
+        m, max_batch=4, max_len=64, page_size=8, block_size=2),
         prompts, kws)
     # width 4, prefix off
     s4 = ContinuousBatchingEngine(
-        m, max_batch=4, max_len=64, page_size=8, block_size=2, fused=True,
+        m, max_batch=4, max_len=64, page_size=8, block_size=2,
         speculative=SpecConfig(k=3))
     assert _serve(s4, prompts, kws) == ref
     # cross slot width (6 slots, different mega shape) + prefix cache:
     # cold then warm re-serve — the warm wave takes the full-prompt-hit
     # COW path for the repeated 16-token prompts
     s6 = ContinuousBatchingEngine(
-        m, max_batch=6, max_len=64, page_size=8, block_size=2, fused=True,
+        m, max_batch=6, max_len=64, page_size=8, block_size=2,
         speculative=SpecConfig(k=3),
         prefix_cache=PrefixCacheConfig(prefill_chunk=16, extra_blocks=12))
     cold = _serve(s6, prompts, kws)
@@ -221,7 +222,7 @@ def test_spec_crash_replay_byte_identical(model, tmp_path):
     def build():
         return ContinuousBatchingEngine(
             m, max_batch=4, max_len=64, page_size=8, block_size=2,
-            fused=True, speculative=SpecConfig(k=3),
+            speculative=SpecConfig(k=3),
             prefix_cache=PrefixCacheConfig(extra_blocks=8))
 
     ref_eng = build()
@@ -259,7 +260,7 @@ def test_spec_stream_survives_migration(model, tmp_path):
     def build():
         return ContinuousBatchingEngine(
             m, max_batch=2, max_len=64, page_size=8, block_size=2,
-            fused=True, speculative=SpecConfig(k=3), prefix_cache=True)
+            speculative=SpecConfig(k=3), prefix_cache=True)
 
     ref_eng = build()
     r0 = Request(prompt, max_new_tokens=16)
@@ -289,7 +290,7 @@ def test_spec_eos_and_mixed_sampling_fallback(model):
     def build(**kw):
         return ContinuousBatchingEngine(
             m, max_batch=4, max_len=64, page_size=8, block_size=2,
-            fused=True, **kw)
+            **kw)
 
     # eos: pick a token the greedy stream actually emits so early-exit
     # fires inside a speculative dispatch
@@ -300,7 +301,7 @@ def test_spec_eos_and_mixed_sampling_fallback(model):
     got = _serve(build(speculative=SpecConfig(k=3)), prompts, kws_eos,
                  stagger=False)
     assert got == ref
-    # mixed greedy + seeded sampling: sampled blocks keep the legacy
+    # mixed greedy + seeded sampling: sampled blocks keep the scan
     # mega-step; streams still match the non-spec engine exactly
     kws_mix = [dict(kws[0]), dict(kws[1], temperature=0.9, seed=7),
                dict(kws[2]), dict(kws[3], temperature=1.1, seed=3)]

@@ -328,3 +328,39 @@ def test_llama_engine_streams_equal_the_parents_byte_for_byte(
     assert mine and len(got["streams"]) == len(want["streams"])
     assert [got["streams"][i] for i in mine] == [want["streams"][i]
                                                  for i in mine]
+
+
+@pytest.mark.parametrize("d", [32, 128], ids=["xla_reads", "kernel_reads"])
+@pytest.mark.parametrize("prefill", [False, True], ids=["decode", "prefill"])
+def test_append_paged_kv_writes_each_token_once_in_both_scatter_forms(
+        d, prefill):
+    """``append_paged_kv`` picks its scatter by who reads the pool
+    (``_kernel_takes``: rows of ``d`` a head where the paged kernel does,
+    ``[heads, d]`` windows where XLA's gather does); both put token t of
+    row r at ``tables[r, pos // page]``, slot ``pos % page``, and touch
+    nothing else."""
+    from paddle_tpu.ops.paged_attention import append_paged_kv
+
+    rng = np.random.default_rng(3)
+    heads, page, pages = 2, 16, 7
+    tables = np.array([[1, 2], [3, 4], [5, 6]], np.int32)
+    if prefill:
+        seq = np.repeat(np.arange(3), 5).astype(np.int32)
+        pos = np.tile(np.arange(14, 19), 3).astype(np.int32)   # crosses a page
+    else:
+        seq, pos = None, np.array([0, 17, 31], np.int32)
+    n = len(pos)
+    k_new, v_new = (rng.normal(size=(n, heads, d)).astype(np.float32)
+                    for _ in range(2))
+    k0, v0 = (rng.normal(size=(pages, heads, page, d)).astype(np.float32)
+              for _ in range(2))
+    k1, v1 = append_paged_kv(
+        jnp.asarray(k0), jnp.asarray(v0), jnp.asarray(k_new),
+        jnp.asarray(v_new), jnp.asarray(tables), jnp.asarray(pos),
+        None if seq is None else jnp.asarray(seq))
+    for t in range(n):
+        r = t if seq is None else seq[t]
+        k0[tables[r, pos[t] // page], :, pos[t] % page] = k_new[t]
+        v0[tables[r, pos[t] // page], :, pos[t] % page] = v_new[t]
+    np.testing.assert_array_equal(np.asarray(k1), k0)
+    np.testing.assert_array_equal(np.asarray(v1), v0)

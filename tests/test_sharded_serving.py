@@ -4,7 +4,7 @@ The tp-sharded engine holds one identity CONTRACT: every tp-sharded
 weight splits along its OUTPUT dimension (column-parallel), so the only
 collectives are all_gathers of disjoint shards and every device computes
 byte-identical values — greedy AND seeded-sampled streams at mesh=N must
-equal the 1-device legacy path bit-for-bit. These tests pin that
+equal the 1-device engine's bit-for-bit. These tests pin that
 contract over a REAL 2-wide CPU device mesh (tests/conftest.py forces
 ``--xla_force_host_platform_device_count=8``), the abstract-mesh trace
 path the PT-COMM/PT-COST gates audit through, the procfleet per-worker
@@ -62,31 +62,30 @@ def _mk(model, mesh=None, max_batch=8, **kw):
     _, m = model
     return ContinuousBatchingEngine(
         m, max_batch=max_batch, max_len=64, page_size=8, block_size=4,
-        fused=True,
         prefix_cache=PrefixCacheConfig(prefill_chunk=16, extra_blocks=8),
         mesh=mesh, **kw)
 
 
 @pytest.fixture(scope="module")
-def legacy_tokens(model):
-    """The 1-device legacy-path streams every mesh arm must reproduce."""
+def one_device_tokens(model):
+    """The 1-device streams every mesh arm must reproduce."""
     cfg, _ = model
     prompts, kws = _wave(cfg)
     return _serve(_mk(model), prompts, kws)
 
 
-def test_mesh_identity_greedy_and_sampled(model, legacy_tokens):
+def test_mesh_identity_greedy_and_sampled(model, one_device_tokens):
     """mesh=1 and mesh=2 greedy/seeded streams are bit-equal to the
-    1-device legacy path, the mesh counters tick, and the pt_serving_*
+    1-device engine's, the mesh counters tick, and the pt_serving_*
     collector families render on sharded AND unsharded engines (they are
     REQUIRED in tools/scrape_metrics.py — they must never vanish)."""
     from paddle_tpu.observability import engine_collector
 
     cfg, _ = model
     prompts, kws = _wave(cfg)
-    assert _serve(_mk(model, mesh=1), prompts, kws) == legacy_tokens
+    assert _serve(_mk(model, mesh=1), prompts, kws) == one_device_tokens
     e2 = _mk(model, mesh=2)
-    assert _serve(e2, prompts, kws) == legacy_tokens
+    assert _serve(e2, prompts, kws) == one_device_tokens
     assert e2.stats["mesh_decode_steps"] > 0
     assert e2.stats["mesh_collective_bytes"] > 0
     # the first-dispatch census recorded per program variant
@@ -160,7 +159,7 @@ def test_mesh_validation(model):
     _, m = model
     with pytest.raises(ValueError, match="prefix"):
         ContinuousBatchingEngine(m, max_batch=4, max_len=64, page_size=8,
-                                 fused=True, mesh=2)
+                                 mesh=2)
     with pytest.raises(ValueError, match="divisible|divide"):
         _mk(model, mesh=3)         # 4 heads / 2 kv heads: tp=3 can't split
     with pytest.raises(ValueError):
@@ -170,7 +169,7 @@ def test_mesh_validation(model):
     g = GPTForCausalLM(GPTConfig.tiny(num_hidden_layers=1))
     with pytest.raises(ValueError, match="tp_serving"):
         ContinuousBatchingEngine(
-            g, max_batch=4, max_len=64, page_size=8, fused=True,
+            g, max_batch=4, max_len=64, page_size=8,
             prefix_cache=PrefixCacheConfig(), mesh=2)
 
 
@@ -185,7 +184,7 @@ def test_abstract_mesh_trace_all_gather_only(model):
 
     _, m = model
     eng = ContinuousBatchingEngine(
-        m, max_batch=8, max_len=64, page_size=8, block_size=4, fused=True,
+        m, max_batch=8, max_len=64, page_size=8, block_size=4,
         prefix_cache=PrefixCacheConfig(prefill_chunk=16, extra_blocks=8),
         speculative=SpecConfig(k=3), mesh=MeshConfig(tp=2, abstract=True))
     disp = eng._build_mega_jit()
@@ -216,7 +215,7 @@ def test_reshard_trace_span(model):
     _, m = model
     tr = TraceRecorder()
     ContinuousBatchingEngine(
-        m, max_batch=4, max_len=64, page_size=8, block_size=4, fused=True,
+        m, max_batch=4, max_len=64, page_size=8, block_size=4,
         prefix_cache=PrefixCacheConfig(prefill_chunk=16, extra_blocks=8),
         mesh=2, tracer=tr)
     assert "reshard" in {e["name"] for e in tr.events}

@@ -93,7 +93,7 @@ def record_mega_step(slots: int, mesh: int = 0):
     m = LlamaForCausalLM(cfg)
     eng = ContinuousBatchingEngine(
         m, max_batch=slots, max_len=32, page_size=8, block_size=2,
-        fused=True, prefix_cache=PrefixCacheConfig(prefill_chunk=8),
+        prefix_cache=PrefixCacheConfig(prefill_chunk=8),
         mesh=MeshConfig(tp=mesh, abstract=True) if mesh else None)
     jf = eng._build_mega_jit()
     names, tensors = _collect_state(m)
@@ -158,7 +158,7 @@ def record_spec_verify(slots: int, mesh: int = 0):
     m = LlamaForCausalLM(cfg)
     eng = ContinuousBatchingEngine(
         m, max_batch=slots, max_len=32, page_size=8, block_size=2,
-        fused=True, speculative=SpecConfig(k=3, ngram=2, history=16),
+        speculative=SpecConfig(k=3, ngram=2, history=16),
         prefix_cache=PrefixCacheConfig(prefill_chunk=8),
         mesh=MeshConfig(tp=mesh, abstract=True) if mesh else None)
     jf = eng._build_spec_jit()
@@ -218,7 +218,7 @@ def record_prefill_chunk(mesh: int = 0):
     cfg = LlamaConfig.tiny(num_hidden_layers=1)
     m = LlamaForCausalLM(cfg)
     eng = ContinuousBatchingEngine(
-        m, max_batch=8, max_len=32, page_size=8, block_size=2, fused=True,
+        m, max_batch=8, max_len=32, page_size=8, block_size=2,
         prefix_cache=PrefixCacheConfig(prefill_chunk=8),
         mesh=MeshConfig(tp=mesh, abstract=True) if mesh else None)
     g, C = 4, eng._chunk_tokens

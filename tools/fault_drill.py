@@ -5,7 +5,7 @@ enabled (the injected fault must be absorbed) and with it disabled (the
 same fault must flip the exit code). ``--selftest`` runs the whole seeded
 matrix — heartbeat loss, store stall, checkpoint shard corruption, serving
 engine saturation, serving deadline, prefix-cache block-pool exhaustion,
-128-slot fused big-batch saturation (docs/SERVING.md), speculative-decode
+128-slot big-batch saturation (docs/SERVING.md), speculative-decode
 divergence (verification disabled — accept-all), the numeric
 classes (NaN gradient, loss spike,
 poisoned batch — docs/NUMERIC_GUARD.md), a composed multi-site chaos plan
@@ -386,6 +386,34 @@ def drill_serving_deadline(recover: bool):
 # drill: prefix-cache block-pool exhaustion -> backpressure, not corruption
 # ---------------------------------------------------------------------------
 
+def _overcommit(eng):
+    """DRILL-ONLY: turn ``eng``'s allocator into what a refcount-less one
+    is under exhaustion. When the pool cannot serve a request it hands
+    over, oldest first, blocks that live tables still map, and never one
+    the caller itself holds (admission pins the blocks it matched with an
+    incref before it allocates: those read 2 and more). Stolen pages come
+    first, so they become the thief's PROMPT blocks and its very next
+    prefill overwrites a page the victim still reads. The drills assert
+    the corruption; production admission defers instead."""
+    from paddle_tpu.ops.paged_attention import BlockAllocator
+
+    class Refcountless(BlockAllocator):
+        def alloc(self, n, evict=None):
+            got = super().alloc(n, evict=evict)
+            if got is not None:
+                return got
+            mapped = [b for b, rc in self._ref.items() if rc == 1]
+            free = super().alloc(min(n, self.free_blocks))
+            if len(mapped) + len(free) < n:
+                self.decref(free)
+                return None
+            stolen = mapped[:n - len(free)]
+            self.incref(stolen)
+            return stolen + free
+
+    eng._alloc.__class__ = Refcountless
+
+
 def drill_prefix_cache_exhaustion(recover: bool):
     """Seeded KV block-pool exhaustion mid-admission (docs/SERVING.md).
 
@@ -394,7 +422,7 @@ def drill_prefix_cache_exhaustion(recover: bool):
     Recovery = the refcounted allocator DEFERS the admission (the queue
     backs up into EngineSaturated) and serves it only once completed
     requests release blocks — both token streams exactly match generate().
-    Without recovery (``_unsafe_overcommit``: what a refcount-less
+    Without recovery (``_overcommit``: what a refcount-less
     allocator does) the second request is handed pages the first still
     reads, and the survivor's tokens are silently corrupted."""
     import numpy as np
@@ -420,10 +448,14 @@ def drill_prefix_cache_exhaustion(recover: bool):
     # evictable (A holds its blocks) < 3 -> a correct allocator must defer.
     eng = ContinuousBatchingEngine(m, max_batch=2, max_len=32, page_size=8,
                                    block_size=2, prefix_cache=True,
-                                   max_queue=1,
-                                   _unsafe_overcommit=not recover)
-    ra = Request(pa, max_new_tokens=16)
-    rb = Request(pb, max_new_tokens=16)
+                                   max_queue=1)
+    if not recover:
+        _overcommit(eng)
+    # an eos id no token equals: decode paces at block_size tokens a step
+    # (without one a block runs block_size * 2^k steps), so A still has
+    # most of its 16 tokens to decode over the stolen page when B arrives
+    ra = Request(pa, max_new_tokens=16, eos_token_id=-1)
+    rb = Request(pb, max_new_tokens=16, eos_token_id=-1)
     plan = FaultPlan(seed=9, specs=[
         FaultSpec("serving.block_pool", "exhaust", at=1, count=1, arg=3)])
     saturated = deferred = False
@@ -465,16 +497,16 @@ def drill_prefix_cache_exhaustion(recover: bool):
 
 
 def drill_big_batch_saturation(recover: bool):
-    """Seeded pool exhaustion mid-wave on the 128-slot FUSED engine
+    """Seeded pool exhaustion mid-wave on a 128-slot engine
     (docs/SERVING.md mega-step section): a 6-request wave is decoding
-    through the fused mega-step (device-resident tables, packed prefill)
+    through the mega-step (device-resident tables, packed prefill)
     when the block pool is exhausted under a late admission.
 
     Recovery = the refcounted allocator DEFERS the admission (its table
     scatter never reaches the device), the queue backs up into
     EngineSaturated, and once the wave's blocks release the deferred
     request is served — every survivor's stream byte-identical to
-    generate(). Without recovery (``_unsafe_overcommit``) the late request
+    generate(). Without recovery (``_overcommit``) the late request
     is handed radix pages live tables still map; its packed prefill then
     overwrites k/v a decoding survivor reads mid-stream — silent
     corruption at 128 slots, exactly what the deferral exists to
@@ -501,10 +533,9 @@ def drill_big_batch_saturation(recover: bool):
     pb = rng.integers(0, cfg.vocab_size, (8,)).astype(np.int32)
     eng = ContinuousBatchingEngine(
         m, max_batch=128, max_len=40, page_size=8, block_size=4,
-        fused=True, prefix_cache=PrefixCacheConfig(prefill_chunk=8),
-        _unsafe_overcommit=not recover)
-    if not eng._fused:
-        return False, "engine did not take the fused mega-step path"
+        prefix_cache=PrefixCacheConfig(prefill_chunk=8))
+    if not recover:
+        _overcommit(eng)
     wave_reqs = [Request(p, max_new_tokens=30) for p in wave]
     rb = Request(pb, max_new_tokens=30)
     # the wave's 6 admissions are block-pool events 0-5; the late
@@ -551,7 +582,7 @@ def drill_big_batch_saturation(recover: bool):
         return False, (f"survivors {wrong} corrupted despite refcounting")
     if list(rb.tokens) != ref(pb, 30):
         return False, "deferred request served wrong tokens after release"
-    return True, ("128-slot fused wave: admission deferred at exhaustion, "
+    return True, ("128-slot wave: admission deferred at exhaustion, "
                   "EngineSaturated raised, all 7 streams exact "
                   f"(packed_rows={eng.stats['packed_rows']}, "
                   f"fused_updates={eng.stats['fused_updates']})")
@@ -866,7 +897,7 @@ def _mesh_build(mesh_tp=4):
 
     mesh = None if mesh_tp is None else MeshConfig(tp=int(mesh_tp))
     return ContinuousBatchingEngine(
-        m, max_batch=2, max_len=32, page_size=8, block_size=2, fused=True,
+        m, max_batch=2, max_len=32, page_size=8, block_size=2,
         prefix_cache=PrefixCacheConfig(extra_blocks=4), mesh=mesh)
 
 
@@ -954,7 +985,7 @@ def drill_serving_stall(recover: bool):
     supervisor rebuilds-from-journal; streams stay bit-identical. Without
     the watchdog the stall silently blows the per-step latency SLO.
 
-    Runs on the legacy (cache-off) engine, WARMED with an identical wave
+    Runs on the engine without a prefix cache, WARMED with an identical wave
     first so every armed step reuses compiled programs — a compile-heavy
     step is indistinguishable from a stall, which is exactly why the
     supervisor warms before arming (and graces steps after a rebuild)."""
@@ -1247,13 +1278,11 @@ def drill_spec_decode_divergence(recover: bool):
 
     if "spec_refs" not in _SERVING:
         _SERVING["spec_refs"] = wave(ContinuousBatchingEngine(
-            m, max_batch=4, max_len=64, page_size=8, block_size=2,
-            fused=True))
+            m, max_batch=4, max_len=64, page_size=8, block_size=2))
     refs = _SERVING["spec_refs"]
     spec = SpecConfig(k=3, _unsafe_accept_all=not recover)
     eng = ContinuousBatchingEngine(m, max_batch=4, max_len=64, page_size=8,
-                                   block_size=2, fused=True,
-                                   speculative=spec)
+                                   block_size=2, speculative=spec)
     streams = wave(eng)
     wrong = [i for i, (s, f) in enumerate(zip(streams, refs)) if s != f]
     if not recover:

@@ -135,7 +135,7 @@ def record_serving():
     cfg = LlamaConfig.tiny(num_hidden_layers=1)
     m = LlamaForCausalLM(cfg)
     eng = ContinuousBatchingEngine(
-        m, max_batch=8, max_len=32, page_size=8, block_size=2, fused=True,
+        m, max_batch=8, max_len=32, page_size=8, block_size=2,
         prefix_cache=PrefixCacheConfig(prefill_chunk=8))
     run = eng._mega_step_fn()
     names, tensors = _collect_state(m)
