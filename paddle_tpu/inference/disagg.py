@@ -81,8 +81,8 @@ from typing import Callable, List, Optional, Set
 import numpy as np
 
 from ..ops.paged_attention import (gather_chain_pages, gather_chain_scales,
-                                   pool_geometry, require_kv_layers,
-                                   scatter_chain_pages)
+                                   logical_page_shape, pool_geometry,
+                                   require_kv_layers, scatter_chain_pages)
 from .fleet import FleetRouter, ReplicaState, _Replica
 from .recovery import _admit_record, _request_from
 from .serving import ContinuousBatchingEngine, EngineSaturated, Request
@@ -151,7 +151,10 @@ class KVChainCodec:
         n_cached = pos - 1                  # tokens already in the cache
         n_written = -(-n_cached // page)
         kv = engine.caches["kv"]
-        pages = gather_chain_pages(kv, blocks[:n_written])
+        # the artifact keeps the logical [kv_heads, page, head_dim] order,
+        # whatever form the pools are stored in
+        pages = gather_chain_pages(kv, blocks[:n_written],
+                                   head_dim=engine.model.config.head_dim)
         # int8 block format: the payload is the RAW int8 page bytes (crc
         # covers them exactly as stored); the per-block dequant scales ride
         # the header, integrity-protected by the chain digest like every
@@ -310,7 +313,9 @@ class KVChainCodec:
             raise ValueError("KV-chain splice needs a prefix-cache engine")
         kv = engine.caches["kv"]
         require_kv_layers(kv, "the KV-chain splice (import_chain)")
-        pool_shape = tuple(int(d) for d in kv[0][0].shape[1:])
+        # the chain is in the logical order; a lane-dense pool folds it on
+        # the way in (scatter_chain_pages)
+        pool_shape = logical_page_shape(kv[0][0], hdr["hd"])
         want = (hdr["kvh"], hdr["page_size"], hdr["hd"])
         if (engine.page_size != hdr["page_size"] or len(kv) != hdr["layers"]
                 or pool_shape != want

@@ -121,7 +121,8 @@ def _greedy(logits):
 # here as the serving-facing API surface
 from ..ops.paged_attention import (BlockAllocator, LayerStateError,
                                    RadixPrefixCache, copy_layer_pages,
-                                   layer_kinds, pool_num_pages, state_bytes)
+                                   kernel_layers, layer_kinds,
+                                   pool_num_pages, state_bytes)
 
 __all__ = ["AutoscaleConfig", "BlockAllocator", "BrownoutConfig",
            "ContinuousBatchingEngine", "EngineSaturated", "FleetConfig",
@@ -685,9 +686,12 @@ class ContinuousBatchingEngine:
             # +1 page: parked decode rows (free / still-prefilling slots)
             # write their dummy token into a dedicated parking page, never
             # into a block another request may share
+            # a tp mesh cuts the pools along their KV heads: a lane-dense
+            # pool has to fold each shard's own heads
             self.caches = model._init_paged_caches(
                 max_batch, max_len, page_size, num_blocks=n_blocks + 1,
-                kv_dtype=self._kv_dtype)
+                kv_dtype=self._kv_dtype,
+                kv_shards=1 if mesh is None else int(mesh.tp))
             self._park = n_blocks
             self._alloc = BlockAllocator(n_blocks)
             self._radix = RadixPrefixCache(page_size, self._alloc)
@@ -799,11 +803,17 @@ class ContinuousBatchingEngine:
         # miss_tokens feed serving_prefix_hit_rate; cow_copies / evictions
         # expose block lifecycle; compile_cache_entries is the
         # bounded-compile-cache telemetry, warned past ``compile_cache_cap``)
+        n_kernel, n_kv = kernel_layers(self.caches["kv"])
         self.stats = {"admit_host_s": 0.0, "decode_host_s": 0.0,
                       "steps": 0, "step_wall_s": 0.0, "device_wait_s": 0.0,
                       "decode_blocks": 0, "decode_block_steps": 0,
                       "programs_built": 0, "step_max_s": 0.0,
                       "step_max_wait_s": 0.0,
+                      # facts of the build, not rates: the layers that keep
+                      # K and V, and those of them whose pools the paged
+                      # kernel reads and the append writes in place
+                      # (ops.paged_attention._kernel_takes)
+                      "paged_kernel_layers": n_kernel, "kv_layers": n_kv,
                       "compile_cache_entries": 0, "shed": 0,
                       "retry_attempts": 0, "retry_giveups": 0,
                       "fused_updates": 0,
@@ -1711,8 +1721,11 @@ class ContinuousBatchingEngine:
     def _kv_spec(self):
         """ONE PartitionSpec prefix covering EVERY kv-pool leaf: pools
         are [pages, kv_heads, page, head_dim] (the int8 format adds
-        [pages, kv_heads] absmax scales) — all shard axis 1, the
-        kv_heads axis, matching the column-sharded k/v projections.
+        [pages, kv_heads] absmax scales; a lane-dense pool of narrow
+        heads is [pages, kv_heads // f, page, 128], folded so that each
+        shard holds whole groups: ``kv_pool_shape(shards=tp)``) — all
+        shard axis 1, the kv_heads axis, matching the column-sharded
+        k/v projections.
         Appends, decode gathers, COW page copies, quant resets and the
         int8 scatter-max scales are then shard-local forever: no decode
         step ever reshards the pool, and per-(page, head) quantization

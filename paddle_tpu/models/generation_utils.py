@@ -159,7 +159,7 @@ class GenerationMixin:
         return prefill, block
 
     def _init_paged_caches(self, b, max_len, page_size=64, num_blocks=None,
-                           kv_dtype=None):
+                           kv_dtype=None, kv_shards=1):
         """Paged-KV pools (serving layout, ops/paged_attention.py): per-layer
         page pools + a shared block table with pages statically assigned per
         sequence. ``num_blocks`` overrides the pool size (>= b * pages_per_
@@ -168,6 +168,9 @@ class GenerationMixin:
         ``kv_dtype="int8"`` builds pools in the int8 block format
         (``QuantizedKVPool``: int8 pages + per-(page, head) absmax scales,
         quantize-on-append / dequantize-in-gather — serving.KVCacheConfig).
+        Pools of heads narrower than the 128 lanes are made lane-dense where
+        the shapes divide (``kv_pool_shape``; ``kv_shards``: the ``tp``
+        shards the engine cuts the KV heads into).
         Families with a different cache layout override this."""
         cfg = self.config
         kvh = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
@@ -192,8 +195,11 @@ class GenerationMixin:
             raise ValueError(f"unsupported kv_dtype {kv_dtype!r} "
                              "(supported: None/'param', 'int8')")
         else:
-            kv = [(jnp.zeros((npages, kvh, page_size, hd), dtype),
-                   jnp.zeros((npages, kvh, page_size, hd), dtype))
+            from ..ops.paged_attention import kv_pool_shape
+
+            shape = kv_pool_shape(npages, kvh, page_size, hd, dtype,
+                                  shards=kv_shards)
+            kv = [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
                   for _ in range(cfg.num_hidden_layers)]
         return {"kv": kv, "tables": tables}
 

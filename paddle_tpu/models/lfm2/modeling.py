@@ -425,11 +425,12 @@ class Lfm2ForCausalLM(Layer):
 
     # ---- serving hooks (contracts: models/llama/modeling.py) --------------
     def _init_paged_caches(self, b, max_len, page_size=64, num_blocks=None,
-                           kv_dtype=None):
+                           kv_dtype=None, kv_shards=1):
         """What each layer keeps, for the engine: an attention layer a
-        ``(k_pages, v_pages)`` pair [pages, kv_heads, page, head_dim], a
-        conv layer a ``PageState`` ring [pages, L, hidden]."""
-        from ...ops.paged_attention import PageState
+        ``(k_pages, v_pages)`` pair [pages, kv_heads, page, head_dim] in
+        the form ``kv_pool_shape`` gives (heads of 64: two to a 128-lane
+        row), a conv layer a ``PageState`` ring [pages, L, hidden]."""
+        from ...ops.paged_attention import PageState, kv_pool_shape
 
         cfg = self.config
         if kv_dtype not in (None, "param"):
@@ -451,8 +452,9 @@ class Lfm2ForCausalLM(Layer):
                     (npages, cfg.conv_L_cache, cfg.hidden_size), dtype),
                     page_size))
             else:
-                shape = (npages, cfg.num_key_value_heads, page_size,
-                         cfg.head_dim)
+                shape = kv_pool_shape(npages, cfg.num_key_value_heads,
+                                      page_size, cfg.head_dim, dtype,
+                                      shards=kv_shards)
                 kv.append((jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)))
         tables = jnp.arange(b * maxp, dtype=jnp.int32).reshape(b, maxp)
         return {"kv": kv, "tables": tables}

@@ -7,7 +7,13 @@ KV lives in a pool of fixed-size pages; each sequence owns a list of pages via a
 block table, so cache memory is bounded by total tokens, not batch × max_len.
 
 Layouts (reference block_multihead_attention):
-  k_cache/v_cache: [num_pages, kv_heads, page_size, head_dim]
+  k_cache/v_cache: [num_pages, kv_heads, page_size, head_dim], the logical
+                   form; or lane-dense, [num_pages, kv_heads // f, page_size,
+                   128] with f = 128 // head_dim heads side by side in a row
+                   (``kv_pool_shape``: where head_dim divides the 128 lanes
+                   and f divides the heads; the same bytes, one tile a page
+                   and head group). A call reads the form off its own shapes:
+                   the pool's trailing width against the query's head_dim.
   block_tables:    [batch, pages_per_seq] int32 (-1 = unassigned)
   context_lens:    [batch] int32 — tokens already in cache (incl. current step)
 
@@ -221,14 +227,24 @@ def pool_num_pages(kv) -> int:
 
 
 def pool_geometry(kv) -> List[tuple]:
-    """Per layer ``(kind, shape of a page's block, dtype)``: two engines can
-    exchange pages only where these agree."""
+    """Per layer ``(kind, shape of a page's block as stored, dtype)``: two
+    engines can exchange pages only where these agree (a lane-dense block
+    reads ``(kv_heads // f, page, 128)``: engines of one model make one
+    form, ``kv_pool_shape``)."""
     out = []
     for e in kv:
         a = e.ring if isinstance(e, PageState) else e[0]
         out.append(("state" if isinstance(e, PageState) else "kv",
                     tuple(a.shape[1:]), str(a.dtype)))
     return out
+
+
+def kernel_layers(kv) -> tuple:
+    """``(layers whose pools pt_paged_decode reads, layers that keep K and
+    V)``: whether the kernel and the in-place append engage is fixed with
+    the pools' shapes, when an engine is built."""
+    pools = [e[0] for e in kv if not isinstance(e, PageState)]
+    return sum(_kernel_takes(p) for p in pools), len(pools)
 
 
 def state_bytes(kv) -> int:
@@ -282,15 +298,94 @@ def dequantize_kv(q, scale):
     return q.astype(jnp.float32) * (scale.astype(jnp.float32) / KV_QMAX)
 
 
-def _gather_pages(cache, tables):
+_LANES = 128
+
+
+def _sublanes(dtype) -> int:
+    """Rows of a tile: 8 sublanes at 4-byte, 16 at 2-byte, 32 at 1-byte."""
+    return {4: 8, 2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
+
+
+def kv_pool_shape(num_pages, kv_heads, page, head_dim, dtype,
+                  shards: int = 1) -> tuple:
+    """The shape in which one side (K or V) of a layer's pool is stored; who
+    makes pools for an engine asks here (``_init_paged_caches``).
+
+    Heads narrower than the 128 lanes are stored lane-dense, ``f = 128 //
+    head_dim`` KV heads to a row: ``[pages, kv_heads // f, page, 128]``, row
+    ``(p, j, s)`` holding heads ``j*f .. j*f+f-1`` of slot ``s`` side by
+    side. The bytes are the logical form's; a page's block of one head group
+    is whole tiles, which is what ``pt_paged_decode``'s page DMA needs
+    (``_kernel_takes``). The rule reads shapes alone, never the backend:
+    ``head_dim`` divides 128, ``f`` divides the KV heads of each of the
+    ``shards`` a ``tp`` mesh cuts the pool into (axis 1: a shard reads the
+    form off its local shapes like everyone), the page fills the dtype's
+    sublanes. Whatever does not divide (head_dim 96 or 80, one KV head of
+    64) keeps the logical ``[pages, kv_heads, page, head_dim]``, as does
+    every int8 pool."""
+    f = _LANES // head_dim if head_dim < _LANES else 1
+    if (f > 1 and _LANES % head_dim == 0 and kv_heads % (f * shards) == 0
+            and page % _sublanes(dtype) == 0):
+        return (num_pages, kv_heads // f, page, _LANES)
+    return (num_pages, kv_heads, page, head_dim)
+
+
+def _pool_fold(pool, d) -> int:
+    """KV heads of width ``d`` in a row of ``pool``: 1 in the logical form
+    (trailing width ``d``), ``128 // d`` lane-dense (trailing width 128)."""
+    w = pool.shape[-1]
+    if w == d:
+        return 1
+    if w != _LANES or w % d or isinstance(pool, QuantizedKVPool):
+        raise ValueError(
+            f"a pool of trailing width {w} holds no heads of {d}: it is "
+            f"neither [pages, kv_heads, page, {d}] nor lane-dense "
+            f"[pages, kv_heads // f, page, {_LANES}]")
+    return w // d
+
+
+def logical_page_shape(pool, head_dim) -> tuple:
+    """``(kv_heads, page, head_dim)`` of a page's block in the logical order
+    (the PTKV1 artifact's), for a pool in either form; a pool that holds no
+    heads of ``head_dim`` gives its stored shape."""
+    groups, page, w = (int(n) for n in pool.shape[1:])
+    if (w != head_dim and w == _LANES and w % head_dim == 0
+            and not isinstance(pool, QuantizedKVPool)):
+        return (groups * (w // head_dim), page, head_dim)
+    return (groups, page, w)
+
+
+def fold_kv_pages(pages, f: int):
+    """Logical page blocks [n, kv_heads, page, d] -> lane-dense
+    [n, kv_heads // f, page, f * d] (jax or numpy; ``f`` 1: as given)."""
+    if f == 1:
+        return pages
+    *lead, h, page, d = pages.shape
+    return pages.reshape(*lead, h // f, f, page, d).swapaxes(-2, -3).reshape(
+        *lead, h // f, page, f * d)
+
+
+def unfold_kv_pages(pages, d: int):
+    """The inverse: [..., groups, page, f * d] -> [..., groups * f, page, d]."""
+    *lead, g, page, w = pages.shape
+    if w == d:
+        return pages
+    f = w // d
+    return pages.reshape(*lead, g, page, f, d).swapaxes(-2, -3).reshape(
+        *lead, g * f, page, d)
+
+
+def _gather_pages(cache, tables, d):
     """Dense page gather with dequantize-on-gather for int8 pools:
     returns [*tables.shape, kv_heads, page, d] — fp32 when quantized,
-    the pool dtype otherwise."""
+    the pool dtype otherwise. The ONE place where what reads a pool through
+    XLA un-folds a lane-dense one (a reshape and a transpose of the gathered
+    pages, never of the pool)."""
     if isinstance(cache, QuantizedKVPool):
         pages = cache.data[tables].astype(jnp.float32)
         s = cache.scale[tables]                       # [..., kv_heads]
         return pages * (s[..., None, None] / KV_QMAX)
-    return cache[tables]
+    return unfold_kv_pages(cache[tables], d)
 
 
 # ---------------------------------------------------------------------------
@@ -301,16 +396,17 @@ def paged_decode_reference(q, k_cache, v_cache, block_tables, context_lens,
                            scale=None):
     """Dense-gather paged decode: q [b, hq, d] -> out [b, hq, d]."""
     b, hq, d = q.shape
-    n_pages, hkv, page, _ = k_cache.shape
+    page = k_cache.shape[2]
+    hkv = k_cache.shape[1] * _pool_fold(k_cache, d)
     group = hq // hkv
     if scale is None:
         scale = d ** -0.5
     max_pages = block_tables.shape[1]
     safe_tables = jnp.maximum(block_tables, 0)
     # [b, max_pages, hkv, page, d] -> [b, hkv, L, d]
-    kg = jnp.swapaxes(_gather_pages(k_cache, safe_tables),
+    kg = jnp.swapaxes(_gather_pages(k_cache, safe_tables, d),
                       2, 3).reshape(b, max_pages * page, hkv, d)
-    vg = jnp.swapaxes(_gather_pages(v_cache, safe_tables),
+    vg = jnp.swapaxes(_gather_pages(v_cache, safe_tables, d),
                       2, 3).reshape(b, max_pages * page, hkv, d)
     kg = jnp.swapaxes(kg, 1, 2)
     vg = jnp.swapaxes(vg, 1, 2)
@@ -454,21 +550,75 @@ def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = jnp.where(ctx > 0, out, 0.0).astype(o_ref.dtype)
 
 
+@functools.lru_cache(maxsize=None)
+def _decode_call(b, hkv, group, w, page, C, max_pages, scale, q_dtype,
+                 k_dtype, v_dtype, interpret):
+    """The ``pallas_call`` of one shape class, made once: every layer and
+    every program of an engine calls the same object, so jax traces the
+    kernel's body once a class and not once a call."""
+    kernel = functools.partial(
+        _paged_decode_kernel, page=page, C=C, max_pages=max_pages,
+        scale=scale, batch=b)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, hkv, group, w), lambda bi, *_: (bi, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, hkv, group, w),
+                               lambda bi, *_: (bi, 0, 0, 0)),
+        scratch_shapes=[
+            # [slot, kv head, page of the chunk, token, w]: a head's chunk is
+            # one [chunk_tokens, w] tile, a page's DMA lands in every head's
+            pltpu.VMEM((2, hkv, C, page, w), k_dtype),
+            pltpu.VMEM((2, hkv, C, page, w), v_dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        name="pt_paged_decode",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, w), q_dtype),
+        # "arbitrary": the prefetch chain carries the buffer slot and the
+        # DMAs in flight from one row to the next, so the rows may not
+        # be split across cores
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret)
+
+
 def _kernel_takes(pool) -> bool:
-    """Whether ``pt_paged_decode`` takes this pool's pages: Mosaic's page
-    DMA needs a 128-aligned trailing dim and a sublane-aligned page dim (8
-    sublanes at 4-byte, 16 at 2-byte, 32 at 1-byte); other shapes go to the
-    dense gather."""
-    _, _, page, d = pool.shape
-    sublane = {4: 8, 2: 16, 1: 32}.get(jnp.dtype(pool.dtype).itemsize, 8)
-    return d % 128 == 0 and page % sublane == 0
+    """Whether ``pt_paged_decode`` reads this pool, and with it whether the
+    append scatters rows in place: Mosaic's page DMA needs a 128-aligned
+    trailing dim and a sublane-aligned page dim. Heads of 128 have that in
+    the logical form; narrower heads have it lane-dense, ``128 // d`` KV
+    heads to a row (``kv_pool_shape``), and there only: a logical pool of
+    narrow heads, like every int8 pool, goes to the dense gather and the
+    slot-major scatter. On the v5e a call takes 0.139 ms at 24 rows x 8
+    heads of 128 and 1,000 tokens a row (87% of its bytes' time) and 0.303
+    ms at 64 rows x 8 heads of 64 and the chat-batch-64 cell's contexts
+    (55%: 16 KB a page DMA for 32; the gather it replaces 1.27 ms; PERF.md
+    section 6, PR 32)."""
+    _, _, page, w = pool.shape
+    return (not isinstance(pool, QuantizedKVPool)
+            and w % _LANES == 0 and page % _sublanes(pool.dtype) == 0)
 
 
 def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
                            scale=None, interpret: bool = False):
     """One-token-per-sequence paged decode.
 
-    q: [batch, q_heads, head_dim]; caches [num_pages, kv_heads, page, d];
+    q: [batch, q_heads, head_dim]; caches [num_pages, kv_heads, page, d] or
+    lane-dense [num_pages, kv_heads // f, page, 128], f = 128 // d, which
+    the one kernel reads as ``kv_heads // f`` head groups of width 128: a
+    head's query rows sit in its own d lanes with zeros beside them, so
+    ``q.K^T`` is each head's own scores, and of ``p.V`` each head keeps its
+    own lanes (0.303 ms a call at 64 rows x 32/8 heads of 64 on the v5e,
+    0.139 ms at 24 rows x 16/8 heads of 128: PERF.md section 6, PR 32);
     block_tables [batch, max_pages_per_seq] int32; context_lens [batch] int32
     (number of valid cache tokens INCLUDING the current position's k/v, which
     must already be appended via append_paged_kv; rows with length 0 return
@@ -484,62 +634,39 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
     9.2 us against 7.0 — 2 us a call is not worth a second path.
     """
     b, hq, d = q.shape
-    n_pages, hkv, page, _ = k_cache.shape
-    group = hq // hkv
     if scale is None:
         scale = d ** -0.5
-    if isinstance(k_cache, QuantizedKVPool):
-        # int8 block format: the Pallas kernel does not carry the
-        # per-block dequant yet — route to the dense-gather reference,
-        # which dequantizes in the gather (open TPU-kernel work)
+    # int8 block format: the Pallas kernel does not carry the per-block
+    # dequant yet — the dense-gather reference dequantizes in the gather
+    # (open TPU-kernel work), and serves whatever Mosaic cannot slice
+    if isinstance(k_cache, QuantizedKVPool) or not interpret and (
+            jax.default_backend() != "tpu" or not _kernel_takes(k_cache)):
         return paged_decode_reference(q, k_cache, v_cache, block_tables,
                                       context_lens, scale)
-    if not interpret and (jax.default_backend() != "tpu"
-                          or not _kernel_takes(k_cache)):
-        return paged_decode_reference(q, k_cache, v_cache, block_tables,
-                                      context_lens, scale)
+    f = _pool_fold(k_cache, d)
+    _, hkv, page, w = k_cache.shape       # hkv head groups of f heads each
+    group = hq // hkv                     # query rows a head group
+    if f > 1:
+        # head j*f + i of group j: its query rows in lanes i*d .. (i+1)*d,
+        # zeros in the lanes of the group's other heads
+        eye = jnp.eye(f, dtype=q.dtype)[:, None, :, None]
+        q = q.reshape(b, hkv, f, group // f, 1, d) * eye
     max_pages = block_tables.shape[1]
-    C = _decode_chunk_pages(max_pages, hkv, page, d,
+    C = _decode_chunk_pages(max_pages, hkv, page, w,
                             jnp.dtype(k_cache.dtype).itemsize)
 
-    kernel = functools.partial(
-        _paged_decode_kernel, page=page, C=C, max_pages=max_pages,
-        scale=float(scale), batch=b)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, hkv, group, d), lambda bi, *_: (bi, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, hkv, group, d),
-                               lambda bi, *_: (bi, 0, 0, 0)),
-        scratch_shapes=[
-            # [slot, kv head, page of the chunk, token, d]: a head's chunk is
-            # one [chunk_tokens, d] tile, a page's DMA lands in every head's
-            pltpu.VMEM((2, hkv, C, page, d), k_cache.dtype),
-            pltpu.VMEM((2, hkv, C, page, d), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((1,), jnp.int32),
-        ],
-    )
+    call = _decode_call(b, hkv, group, w, page, C, max_pages, float(scale),
+                        jnp.dtype(q.dtype), jnp.dtype(k_cache.dtype),
+                        jnp.dtype(v_cache.dtype), interpret)
     # the kernel's name reaches the HLO instruction and the scope its name
     # stack: traces find the kernel by name, not by a shape
     with jax.named_scope("pt_paged_decode"):
-        out = pl.pallas_call(
-            kernel,
-            name="pt_paged_decode",
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-            # "arbitrary": the prefetch chain carries the buffer slot and the
-            # DMAs in flight from one row to the next, so the rows may not
-            # be split across cores
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",)),
-            interpret=interpret,
-        )(context_lens, block_tables.reshape(-1),
-          q.reshape(b, hkv, group, d), k_cache, v_cache)
+        out = call(context_lens, block_tables.reshape(-1),
+                   q.reshape(b, hkv, group, w), k_cache, v_cache)
+    if f > 1:
+        # of a head's rows, the lanes that hold its own V
+        out = out.reshape(b, hkv, f, group // f, f, d)
+        out = jnp.stack([out[:, :, i, :, i] for i in range(f)], axis=2)
     return out.reshape(b, hq, d)
 
 
@@ -575,16 +702,17 @@ def paged_prefill_attention(q, k_cache, v_cache, block_tables, chunk_starts,
     kernel): prefill is projection/MLP-bound at serving chunk sizes and this
     runs once per admitted chunk, unlike the per-token decode kernel."""
     b, s, hq, d = q.shape
-    n_pages, hkv, page, _ = k_cache.shape
+    page = k_cache.shape[2]
+    hkv = k_cache.shape[1] * _pool_fold(k_cache, d)
     group = hq // hkv
     if scale is None:
         scale = d ** -0.5
     max_pages = block_tables.shape[1]
     L = max_pages * page
     safe_tables = jnp.maximum(block_tables, 0)
-    kg = jnp.swapaxes(_gather_pages(k_cache, safe_tables),
+    kg = jnp.swapaxes(_gather_pages(k_cache, safe_tables, d),
                       2, 3).reshape(b, L, hkv, d)
-    vg = jnp.swapaxes(_gather_pages(v_cache, safe_tables),
+    vg = jnp.swapaxes(_gather_pages(v_cache, safe_tables, d),
                       2, 3).reshape(b, L, hkv, d)
     kg = jnp.swapaxes(kg, 1, 2).astype(jnp.float32)      # [b, hkv, L, d]
     vg = jnp.swapaxes(vg, 1, 2).astype(jnp.float32)
@@ -877,7 +1005,11 @@ def append_paged_kv(k_cache, v_cache, k_new, v_new, block_tables, positions,
     k_new/v_new: [n_tokens, kv_heads, d]; positions [n_tokens] absolute
     position of each token within its sequence; seq_ids [n_tokens] row of
     block_tables per token (defaults to arange — one token per sequence,
-    the decode step). Returns updated (k_cache, v_cache)."""
+    the decode step). Returns updated (k_cache, v_cache). A pool the kernel
+    reads (``_kernel_takes``: heads of 128, or narrower heads lane-dense,
+    ``128 // d`` to a row) takes one row of 128 lanes a token and head
+    group, in place in the default layout; any other pool the slot-major
+    scatter that XLA's gather reads."""
     n_tokens = k_new.shape[0]
     page = k_cache.shape[2]
     if seq_ids is None:
@@ -887,17 +1019,24 @@ def append_paged_kv(k_cache, v_cache, k_new, v_new, block_tables, positions,
     if isinstance(k_cache, QuantizedKVPool):
         return (_append_quantized(k_cache, k_new, page_idx, offs),
                 _append_quantized(v_cache, v_new, page_idx, offs))
+    groups, w = k_cache.shape[1], k_cache.shape[3]
+    _pool_fold(k_cache, k_new.shape[-1])
+    # a lane-dense pool's row holds f heads side by side: the new rows
+    # [n, kv_heads, d] are [n, kv_heads // f, 128] as they stand
+    k_new = k_new.reshape(n_tokens, groups, w)
+    v_new = v_new.reshape(n_tokens, groups, w)
     if _kernel_takes(k_cache):
         # the kernel reads the default layout, and a scatter indexed on
-        # page, head AND slot (one row of d a token and head) keeps it, in
-        # place. Indexed on page and slot alone, XLA lays the pools a scan
-        # carries out slot-major and converts each for the kernel every
-        # token step: 18 of chat-batch's 30 ms (PERF.md section 6, PR 30)
-        heads = jnp.arange(k_cache.shape[1], dtype=jnp.int32)[None, :]
+        # page, head AND slot (one row of 128 lanes a token and head group)
+        # keeps it, in place. Indexed on page and slot alone, XLA lays the
+        # pools a scan carries out slot-major and converts each for the
+        # kernel every token step: 18 of chat-batch's 30 ms (PERF.md
+        # section 6, PR 30)
+        heads = jnp.arange(groups, dtype=jnp.int32)[None, :]
         at = (page_idx[:, None], heads, offs[:, None])
     else:
-        # XLA's own gather reads these pools and XLA picks one layout for
-        # both: heads of 64 step 16% slower in the row form (same section)
+        # XLA's own gather reads these pools (a width that does not fill
+        # the lanes) and XLA picks one layout for the gather and the scatter
         at = (page_idx, slice(None), offs)
     return k_cache.at[at].set(k_new), v_cache.at[at].set(v_new)
 
@@ -931,13 +1070,16 @@ def _append_quantized(pool: QuantizedKVPool, x_new, page_idx, offs):
     return QuantizedKVPool(data, new_scale)
 
 
-def gather_chain_pages(kv, blocks):
+def gather_chain_pages(kv, blocks, head_dim=None):
     """Host-materialize a block chain's page bytes from every layer's
     (k, v) pool pair — the EXPORT half of KV-block migration
     (inference/disagg.py): ``kv`` is the engine's per-layer
     ``[(k_pages, v_pages), ...]`` list, ``blocks`` the chain's page ids in
     block-table order. Returns ``[(k_np, v_np), ...]`` with arrays of
-    shape ``[len(blocks), kv_heads, page, head_dim]``. The np.asarray
+    shape ``[len(blocks), kv_heads, page, head_dim]``: the logical order,
+    whatever the pool's form (``head_dim``, the model's, un-folds the pages
+    of a lane-dense pool; None takes the pool's trailing width), so an
+    artifact is the same bytes from either form. The np.asarray
     readback fences any in-flight append/decode program that wrote these
     pages, so the bytes are exactly what the next decode step would have
     attended. int8 pools export their RAW int8 page bytes (the dequant
@@ -952,7 +1094,10 @@ def gather_chain_pages(kv, blocks):
         if isinstance(k, QuantizedKVPool):
             out.append((np.asarray(k.data[idx]), np.asarray(v.data[idx])))
         else:
-            out.append((np.asarray(k[idx]), np.asarray(v[idx])))
+            d = k.shape[-1] if head_dim is None else head_dim
+            _pool_fold(k, d)
+            out.append((unfold_kv_pages(np.asarray(k[idx]), d),
+                        unfold_kv_pages(np.asarray(v[idx]), d)))
     return out
 
 
@@ -976,8 +1121,9 @@ def scatter_chain_pages(kv, blocks, pages, scales=None):
     takes one eager scatter (control-plane dispatch — migration happens
     once per request, never on the decode hot path). int8 pools take the
     per-block ``scales`` (from :func:`gather_chain_scales` or the PTKV1
-    header) alongside the raw int8 bytes. Returns the updated per-layer
-    ``[(k_pages, v_pages), ...]`` list."""
+    header) alongside the raw int8 bytes. A lane-dense pool folds the
+    logical pages it is handed (their trailing width is the head_dim).
+    Returns the updated per-layer ``[(k_pages, v_pages), ...]`` list."""
     require_kv_layers(kv, "the KV-chain import (scatter_chain_pages)")
     idx = jnp.asarray(blocks, jnp.int32)
     out = []
@@ -994,22 +1140,26 @@ def scatter_chain_pages(kv, blocks, pages, scales=None):
                     v.data.at[idx].set(jnp.asarray(pv, jnp.int8)),
                     v.scale.at[idx].set(jnp.asarray(vs, jnp.float32)))))
         else:
-            out.append((k.at[idx].set(jnp.asarray(pk, k.dtype)),
-                        v.at[idx].set(jnp.asarray(pv, v.dtype))))
+            f = _pool_fold(k, pk.shape[-1])
+            out.append(tuple(
+                pool.at[idx].set(fold_kv_pages(jnp.asarray(pg, pool.dtype), f))
+                for pool, pg in ((k, pk), (v, pv))))
     return out
 
 
-def gather_paged_kv(k_cache, v_cache, block_tables, max_len):
+def gather_paged_kv(k_cache, v_cache, block_tables, max_len, head_dim=None):
     """Dense [b, max_len, hkv, d] views of the paged cache (prefill path /
     debugging; int8 pools come back dequantized fp32). max_len must be a
-    multiple of page size."""
+    multiple of page size. ``head_dim`` names d where the pool may be
+    lane-dense (None: the pool's trailing width)."""
     b = block_tables.shape[0]
     page = k_cache.shape[2]
-    hkv, d = k_cache.shape[1], k_cache.shape[3]
+    d = k_cache.shape[3] if head_dim is None else head_dim
+    hkv = k_cache.shape[1] * _pool_fold(k_cache, d)
     n = max_len // page
     tables = jnp.maximum(block_tables[:, :n], 0)
-    kg = jnp.swapaxes(_gather_pages(k_cache, tables),
+    kg = jnp.swapaxes(_gather_pages(k_cache, tables, d),
                       2, 3).reshape(b, max_len, hkv, d)
-    vg = jnp.swapaxes(_gather_pages(v_cache, tables),
+    vg = jnp.swapaxes(_gather_pages(v_cache, tables, d),
                       2, 3).reshape(b, max_len, hkv, d)
     return kg, vg

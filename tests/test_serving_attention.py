@@ -16,8 +16,11 @@ import paddle_tpu as paddle
 import paddle_tpu.incubate.nn.functional as IF
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.ops.paged_attention import (
+    _kernel_takes,
     append_paged_kv,
+    fold_kv_pages,
     gather_paged_kv,
+    kv_pool_shape,
     paged_decode_attention,
     paged_decode_reference,
 )
@@ -50,13 +53,15 @@ def _chunk_tokens(hkv, d, page, maxp, dtype):
                                       jnp.dtype(dtype).itemsize)
 
 
-def _cell_class_lens(hkv, maxp):
-    """Lengths around every edge of the kernel's units at the chat-batch
-    cell's shape class (head_dim 128, page 16, bf16 pools): an empty row, one
-    token, exactly a page, exactly a chunk, a chunk plus one, a ragged middle
-    and the full table."""
+def _cell_class_lens(hkv, maxp, part_chunk=True):
+    """Lengths around every edge of the kernel's units at the serving cells'
+    shape classes (128 lanes a row of the pool, page 16, bf16): an empty row,
+    one token, exactly a page, exactly a chunk, a chunk plus one, a ragged
+    middle and the full table. ``hkv``: the rows of 128 lanes a page holds
+    (KV heads of 128, or groups of lane-dense narrower heads)."""
     ct = _chunk_tokens(hkv, 128, 16, maxp, jnp.bfloat16)
-    assert ct < maxp * 16 and (maxp * 16) % ct, "maxp leaves no part chunk"
+    assert ct < maxp * 16
+    assert bool((maxp * 16) % ct) == part_chunk, "maxp leaves no part chunk"
     return [0, 1, 16, ct, ct + 1, ct + 16 * 3 + 5, maxp * 16]
 
 
@@ -73,12 +78,28 @@ _DECODE_CASES = [
     # of chunks
     ("f32-chunks", 4, 2, 128, 8, 150, jnp.float32,
      lambda hkv, maxp: [0, 1, 8, 512, 513, 777, 1200]),
+    # the chat-batch-64 cell's class: 32/8 heads of 64, two KV heads to a
+    # 128-lane row of the pool (f = 2), 160 pages a row: five whole chunks
+    ("lfm2-gqa32x8-d64", 32, 8, 64, 16, 160, jnp.bfloat16,
+     lambda hkv, maxp: _cell_class_lens(hkv // 2, maxp, part_chunk=False)),
+    # f = 4: heads of 32, and one query row a KV head
+    ("mha8-d32", 8, 8, 32, 16, 72, jnp.bfloat16,
+     lambda hkv, maxp: _cell_class_lens(hkv // 4, maxp)),
+    ("f32-d64", 8, 4, 64, 8, 40, jnp.float32,
+     lambda hkv, maxp: [0, 1, 8, 300, 320]),
+    # what does not divide keeps the logical pool and the dense gather
+    ("gqa8x4-d96", 8, 4, 96, 16, 8, jnp.bfloat16, [37, 0, 128]),
+    ("one-kv-d64", 4, 1, 64, 16, 8, jnp.bfloat16, [37, 0, 128]),
 ]
+#: the cases whose pools ``kv_pool_shape`` stores lane-dense
+LANE_DENSE_CASES = ("lfm2-gqa32x8-d64", "mha8-d32", "f32-d64")
+#: narrow heads that keep the logical form (never the kernel's)
+LOGICAL_NARROW_CASES = ("gqa8x4-d96", "one-kv-d64")
 
 
 @pytest.mark.parametrize("case", _DECODE_CASES, ids=lambda c: c[0])
-def test_paged_decode_matches_reference(case):
-    _, hq, hkv, d, page, maxp, dtype, lens = case
+def test_paged_decode_matches_reference(case, monkeypatch):
+    name, hq, hkv, d, page, maxp, dtype, lens = case
     if callable(lens):
         lens = lens(hkv, maxp)
     rng = np.random.default_rng(0)
@@ -94,7 +115,25 @@ def test_paged_decode_matches_reference(case):
     tables = jnp.asarray(tables)
     lens = jnp.asarray(lens, jnp.int32)
     ref = paged_decode_reference(q, kc, vc, tables, lens)
-    out = paged_decode_attention(q, kc, vc, tables, lens, interpret=True)
+    # the reference reads the logical pools; the kernel the form in which
+    # an engine would store them
+    stored = kv_pool_shape(npages, hkv, page, d, dtype)
+    f = stored[-1] // d
+    assert (f > 1) == (d < 128 and name not in LOGICAL_NARROW_CASES)
+    kc, vc = fold_kv_pages(kc, f), fold_kv_pages(vc, f)
+    assert kc.shape == stored and kc.size == npages * hkv * page * d
+    if name in LOGICAL_NARROW_CASES:
+        # on a TPU these must not reach Mosaic (lowering it here would fail)
+        assert not _kernel_takes(kc)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        out = paged_decode_attention(q, kc, vc, tables, lens)
+    else:
+        assert _kernel_takes(kc)
+        out = paged_decode_attention(q, kc, vc, tables, lens, interpret=True)
+        # the gather reads the lane-dense pool as it reads the logical one
+        np.testing.assert_array_equal(
+            np.asarray(paged_decode_reference(q, kc, vc, tables, lens),
+                       np.float32), np.asarray(ref, np.float32))
     assert out.dtype == q.dtype and out.shape == q.shape
     # bf16 results may land one rounding apart
     atol = 2e-5 if dtype == jnp.float32 else 1e-2
@@ -121,14 +160,23 @@ def test_paged_decode_zero_length_neighbors_intact():
                                    atol=2e-5)
 
 
-@pytest.mark.parametrize("d", [32, 128], ids=["xla_reads", "kernel_reads"])
-def test_append_and_gather_paged_kv_roundtrip(d):
-    """Both forms of the append's scatter (``_kernel_takes``): a pool XLA's
-    gather reads, and one the paged kernel reads."""
+@pytest.mark.parametrize("form", ["stored", "logical"])
+@pytest.mark.parametrize("d", [32, 64, 96, 128])
+def test_append_and_gather_paged_kv_roundtrip(d, form):
+    """Both forms of the append's scatter (``_kernel_takes``) and the
+    gather's un-fold, read back through ``gather_paged_kv``: the pool as an
+    engine stores it (``kv_pool_shape``: lane-dense at 32 and 64, the row
+    scatter there and at 128, the slot-major one at 96) and the logical
+    pool a caller builds by hand (narrow heads: XLA's gather and the
+    slot-major scatter, as ever)."""
     rng = np.random.default_rng(1)
-    b, hkv, page, maxp, npages = 3, 2, 8, 4, 12
-    kc = jnp.zeros((npages, hkv, page, d))
-    vc = jnp.zeros((npages, hkv, page, d))
+    b, hkv, page, maxp, npages = 3, 4, 8, 4, 12
+    logical = (npages, hkv, page, d)
+    shape = (kv_pool_shape(npages, hkv, page, d, jnp.float32)
+             if form == "stored" else logical)
+    assert (shape != logical) == (form == "stored" and d in (32, 64))
+    kc, vc = jnp.zeros(shape), jnp.zeros(shape)
+    assert _kernel_takes(kc) == (shape[-1] == 128)
     tables = jnp.asarray(rng.permutation(npages).reshape(-1)[: b * maxp]
                          .reshape(b, maxp), jnp.int32)
     lens = np.array([5, 17, 2])
@@ -138,7 +186,9 @@ def test_append_and_gather_paged_kv_roundtrip(d):
     kn = _rand((int(lens.sum()), hkv, d), 3)
     vn = _rand((int(lens.sum()), hkv, d), 4)
     kc, vc = append_paged_kv(kc, vc, kn, vn, tables, pos, seq_ids)
-    kg, vg = gather_paged_kv(kc, vc, tables, maxp * page)
+    assert kc.shape == shape
+    kg, vg = gather_paged_kv(kc, vc, tables, maxp * page, head_dim=d)
+    assert kg.shape == (b, maxp * page, hkv, d)
     off = 0
     for i, n in enumerate(lens):
         np.testing.assert_allclose(np.asarray(kg[i, :n]),
@@ -146,6 +196,15 @@ def test_append_and_gather_paged_kv_roundtrip(d):
         np.testing.assert_allclose(np.asarray(vg[i, :n]),
                                    np.asarray(vn[off:off + n]))
         off += n
+    # a decode-style append (one token a row) lands after the runs
+    k1, v1 = _rand((b, hkv, d), 5), _rand((b, hkv, d), 6)
+    kc, vc = append_paged_kv(kc, vc, k1, v1, tables, jnp.asarray(lens))
+    kg, vg = gather_paged_kv(kc, vc, tables, maxp * page, head_dim=d)
+    for i, n in enumerate(lens):
+        np.testing.assert_allclose(np.asarray(kg[i, n]), np.asarray(k1[i]))
+        np.testing.assert_allclose(np.asarray(vg[i, n]), np.asarray(v1[i]))
+        np.testing.assert_allclose(np.asarray(kg[i, n - 1]),
+                                   np.asarray(kn[lens[:i + 1].sum() - 1]))
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from paddle_tpu.ops.paged_attention import paged_decode_attention
+from paddle_tpu.ops.paged_attention import (kv_pool_shape,
+                                            paged_decode_attention)
 
 
 @pytest.fixture(scope="module")
@@ -40,6 +41,12 @@ _PAGED_DECODE_SHAPES = [
     # a table shorter than any chunk, and float32 pools
     ("two-pages", 8, 16, 16, 128, 16, 2, 17, jnp.bfloat16),
     ("f32-pools", 4, 8, 2, 128, 8, 150, 600, jnp.float32),
+    # the chat-batch-64 cell: lfm2-24b-a2b, 64 slots of 2560, page 16; heads
+    # of 64 stored two to a 128-lane row, read by the same kernel
+    ("cell-lfm2-gqa32x8-d64", 64, 32, 8, 64, 16, 160, 10497, jnp.bfloat16),
+    # four heads of 32 to a row, and float32 pools of heads of 64
+    ("mha8-d32", 8, 8, 8, 32, 16, 72, 600, jnp.bfloat16),
+    ("f32-d64", 4, 8, 4, 64, 8, 150, 600, jnp.float32),
 ]
 
 
@@ -49,11 +56,84 @@ def test_paged_decode_compiles_for_v5e(shape, one_chip, monkeypatch):
     # the dispatch guard asks for the backend; steer it here, not by an option
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    # the pool as an engine stores it: lane-dense where heads are narrow
+    pool = kv_pool_shape(n_pages, hkv, page, d, dtype)
+    assert pool[-1] == 128
     lowered = jax.jit(paged_decode_attention).trace(
-        sds((b, hq, d), dtype), sds((n_pages, hkv, page, d), dtype),
-        sds((n_pages, hkv, page, d), dtype), sds((b, maxp), jnp.int32),
+        sds((b, hq, d), dtype), sds(pool, dtype), sds(pool, dtype),
+        sds((b, maxp), jnp.int32),
         sds((b,), jnp.int32)).lower(lowering_platforms=("tpu",))
     text = lowered.as_text()
     assert text.count("tpu_custom_call") == 1, "one Pallas call, no fallback"
     assert "pt_paged_decode" in text
     lowered.compile()
+
+
+@pytest.mark.parametrize("shape", [
+    # heads that do not fill the lanes and do not fold: the dense gather
+    ("gqa8x4-d96", 8, 8, 4, 96, 16, 8, 64, jnp.bfloat16),
+    ("one-kv-d64", 8, 4, 1, 64, 16, 8, 64, jnp.bfloat16),
+    # a hand-built logical pool of heads of 64 keeps the parent's path
+    ("logical-d64", 8, 32, 8, 64, 16, 8, 64, jnp.bfloat16),
+], ids=lambda s: s[0])
+def test_what_does_not_fold_lowers_to_the_gather(shape, one_chip,
+                                                 monkeypatch):
+    name, b, hq, hkv, d, page, maxp, n_pages, dtype = shape
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    pool = (n_pages, hkv, page, d)
+    if name != "logical-d64":
+        assert kv_pool_shape(n_pages, hkv, page, d, dtype) == pool
+    text = jax.jit(paged_decode_attention).trace(
+        sds((b, hq, d), dtype), sds(pool, dtype), sds(pool, dtype),
+        sds((b, maxp), jnp.int32),
+        sds((b,), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in text
+
+
+def test_lfm2_decode_block_holds_the_kernel_and_no_pool_copy(one_chip,
+                                                             monkeypatch):
+    """The decode block of an LFM2 engine at the chat-batch-64 cell's head
+    shapes (32/8 heads of 64, page 16, bf16; two attention layers of five,
+    small experts and vocabulary), lowered and compiled for the described v5e: one
+    ``pt_paged_decode`` call an attention layer, the pools appended in place
+    in the default layout, and no pool-shaped ``copy`` (the layout
+    conversions PR 30 found round a scatter that XLA lays out slot-major)."""
+    import re
+
+    from _lfm2_util import TINY, engine
+    from chipbench.adapters import lfm2_block
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dict(TINY, hidden_size=2048, num_attention_heads=32,
+               num_key_value_heads=8, layer_types=[
+                   "conv", "full_attention", "conv", "full_attention",
+                   "conv"])
+    model = lfm2_block.build_model(cfg, max_positions=512, dtype="bfloat16")
+    eng = engine(model, max_batch=8, max_len=512, page_size=16, block_size=4)
+    pools = [e for e in eng.caches["kv"] if isinstance(e, tuple)]
+    assert len(pools) == 2 and eng.stats["paged_kernel_layers"] == 2
+    shape = tuple(pools[0][0].shape)
+    assert shape[1:] == (4, 16, 128)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    tree = lambda t: jax.tree_util.tree_map(sds, t)
+    B = eng.max_batch
+    vec = lambda dt: jax.ShapeDtypeStruct((B,), dt, sharding=one_chip)
+    lowered = eng._build_mega_jit().trace(
+        tree(eng._params), vec(jnp.int32), tree(eng.caches["kv"]),
+        sds(eng.caches["tables"]), vec(jnp.int32), vec(jnp.bool_),
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.float32), vec(jnp.int32),
+        n_steps=4, do_sample=True).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert text.count("pt_paged_decode") >= 2
+    hlo = lowered.compile().as_text()
+    dims = ",".join(map(str, shape))
+    pool_ops = re.findall(
+        rf"= bf16\[{dims}\]\{{([0-9,]+)[^}}]*\}} (\S+?)\(", hlo)
+    assert pool_ops, "the pools appear in the compiled program"
+    # every pool-shaped value keeps the default layout (a conversion would
+    # show as {3,1,2,0}), and none is a copy; an asynchronous copy-start /
+    # copy-done may move a pool this small between memory spaces
+    assert {layout for layout, _ in pool_ops} == {"3,2,1,0"}, pool_ops
+    assert "copy" not in {op for _, op in pool_ops}, pool_ops
