@@ -122,13 +122,14 @@ def _greedy(logits):
 from ..ops.paged_attention import (BlockAllocator, LayerStateError,
                                    RadixPrefixCache, copy_layer_pages,
                                    kernel_layers, layer_kinds,
-                                   pool_num_pages, state_bytes)
+                                   page_append_layers, pool_num_pages,
+                                   state_bytes)
 
 __all__ = ["AutoscaleConfig", "BlockAllocator", "BrownoutConfig",
            "ContinuousBatchingEngine", "EngineSaturated", "FleetConfig",
            "FleetRouter", "KVCacheConfig", "KVChainCodec", "KVChainCorrupt",
-           "LayerStateError", "MeshConfig", "MeshDegraded", "PrefixCacheConfig",
-           "RadixPrefixCache",
+           "LayerStateError", "MeshConfig", "MeshDegraded",
+           "PageAlignmentError", "PrefixCacheConfig", "RadixPrefixCache",
            "ReplicaState",
            "Request", "RequestJournal", "RequestShed", "SLOAutoscaler",
            "ServingSupervisor", "SpecConfig", "StepWatchdog", "TieredRouter"]
@@ -197,6 +198,16 @@ class MeshDegraded(RuntimeError):
         super().__init__(msg)
         self.lost = int(lost)
         self.survivors = max(0, int(survivors))
+
+
+class PageAlignmentError(ValueError):
+    """PT-SRV-010: a packed-prefill row's offset is no multiple of the page.
+    ``jit_pt_prefill_chunk`` writes K and V by the page
+    (``ops.paged_attention.append_paged_chunk``), which is right only for
+    rows that start on a page; admission and ``_run_pack`` keep every offset
+    there (a prefix hit's whole pages, then whole chunks), so this names a
+    bug in the engine, before it can write a page's tokens into the wrong
+    slots."""
 
 
 class EngineSaturated(RuntimeError):
@@ -685,7 +696,10 @@ class ContinuousBatchingEngine:
                         + max(0, int(prefix_cache.extra_blocks)))
             # +1 page: parked decode rows (free / still-prefilling slots)
             # write their dummy token into a dedicated parking page, never
-            # into a block another request may share
+            # into a block another request may share. The pools come back
+            # with that count rounded up to the tile's rows
+            # (ops.paged_attention.pool_pages); the spare pages lie after
+            # the parking page and the allocator never sees them
             # a tp mesh cuts the pools along their KV heads: a lane-dense
             # pool has to fold each shard's own heads
             self.caches = model._init_paged_caches(
@@ -814,6 +828,12 @@ class ContinuousBatchingEngine:
                       # kernel reads and the append writes in place
                       # (ops.paged_attention._kernel_takes)
                       "paged_kernel_layers": n_kernel, "kv_layers": n_kv,
+                      # and those whose packed prefill chunk appends by the
+                      # page (append_paged_chunk); 0 where no chunk is packed
+                      "page_append_layers": (
+                          page_append_layers(self.caches["kv"],
+                                             self._chunk_tokens)
+                          if prefix_cache is not None else 0),
                       "compile_cache_entries": 0, "shed": 0,
                       "retry_attempts": 0, "retry_giveups": 0,
                       "fused_updates": 0,
@@ -2444,6 +2464,11 @@ class ContinuousBatchingEngine:
         real = np.zeros(g, np.int32)       # parked dummy rows: no real token
         trows = np.full((g, self._maxp), self._park, np.int32)
         for r, (s, req, off) in enumerate(rows):
+            if off % self.page_size:
+                raise PageAlignmentError(
+                    f"PT-SRV-010: packed prefill of rid={req.rid} (slot {s}) "
+                    f"at offset {off}, no multiple of the page "
+                    f"({self.page_size}): the chunk writes whole pages")
             chunk = req.prompt[off: off + C]
             ids[r, : len(chunk)] = chunk
             starts[r] = off

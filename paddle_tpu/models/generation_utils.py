@@ -170,8 +170,12 @@ class GenerationMixin:
         quantize-on-append / dequantize-in-gather — serving.KVCacheConfig).
         Pools of heads narrower than the 128 lanes are made lane-dense where
         the shapes divide (``kv_pool_shape``; ``kv_shards``: the ``tp``
-        shards the engine cuts the KV heads into).
+        shards the engine cuts the KV heads into). A pool gets the pages
+        asked for rounded up to its dtype's tile rows (``pool_pages``); the
+        spare ones lie last and no table names them.
         Families with a different cache layout override this."""
+        from ..ops.paged_attention import pool_pages
+
         cfg = self.config
         kvh = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
         hd = cfg.head_dim
@@ -182,6 +186,7 @@ class GenerationMixin:
             raise ValueError(f"num_blocks {npages} < {b * maxp} — the pool "
                              "cannot back every slot's table")
         tables = jnp.arange(b * maxp, dtype=jnp.int32).reshape(b, maxp)
+        npages = pool_pages(npages, jnp.int8 if kv_dtype == "int8" else dtype)
         if kv_dtype == "int8":
             from ..ops.paged_attention import QuantizedKVPool
 

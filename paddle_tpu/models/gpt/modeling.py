@@ -122,28 +122,24 @@ class GPTAttention(Layer):
         return (jnp.matmul(out, self.out_weight._data)
                 + self.out_bias._data, k_pages, v_pages)
 
-    def paged_prefill_chunk(self, x, k_pages, v_pages, tables, starts):
+    def paged_prefill_chunk(self, x, k_pages, v_pages, tables, starts,
+                            page_aligned=False):
         """Prefill CHUNK at per-row absolute offsets over cached history
-        (prefix-cache / chunked-prefill serving path) — llama analogue."""
-        from ...ops.paged_attention import (append_paged_kv,
+        (prefix-cache / chunked-prefill serving path) — llama analogue,
+        ``page_aligned`` included."""
+        from ...ops.paged_attention import (append_paged_chunk,
                                             paged_prefill_attention)
 
         x = _raw(x)
         b, s, h = x.shape
         hd = self.config.head_dim
-        page = k_pages.shape[2]
-        max_len = tables.shape[1] * page
         qkv = jnp.matmul(x, self.qkv_weight._data) + self.qkv_bias._data
         q, k, v = jnp.split(qkv, 3, axis=-1)
         q = q.reshape(b, s, self.num_heads, hd)
         k = k.reshape(b, s, self.num_heads, hd)
         v = v.reshape(b, s, self.num_heads, hd)
-        seq_ids = jnp.repeat(jnp.arange(b, dtype=jnp.int32), s)
-        positions = jnp.clip(starts[:, None] + jnp.arange(s, dtype=jnp.int32),
-                             0, max_len - 1).reshape(-1)
-        k_pages, v_pages = append_paged_kv(
-            k_pages, v_pages, k.reshape(b * s, self.num_heads, hd),
-            v.reshape(b * s, self.num_heads, hd), tables, positions, seq_ids)
+        k_pages, v_pages = append_paged_chunk(
+            k_pages, v_pages, k, v, tables, starts, page_aligned)
         out = paged_prefill_attention(q, k_pages, v_pages, tables, starts)
         out = out.reshape(b, s, h)
         return (jnp.matmul(out, self.out_weight._data)
@@ -252,10 +248,11 @@ class GPTDecoderLayer(Layer):
         x = x + _raw(self.mlp(self.ln_2(x)))
         return x, k_pages, v_pages
 
-    def paged_prefill_chunk(self, hidden, k_pages, v_pages, tables, starts):
+    def paged_prefill_chunk(self, hidden, k_pages, v_pages, tables, starts,
+                            page_aligned=False):
         x = _raw(hidden)
         a, k_pages, v_pages = self.attn.paged_prefill_chunk(
-            self.ln_1(x), k_pages, v_pages, tables, starts)
+            self.ln_1(x), k_pages, v_pages, tables, starts, page_aligned)
         x = x + a
         x = x + _raw(self.mlp(self.ln_2(x)))
         return x, k_pages, v_pages
@@ -350,8 +347,9 @@ class GPTForCausalLM(GenerationMixin, Layer):
         """Serving hook (see the llama analogue): one prefill chunk per row
         at per-row absolute offsets over cached history; returns caches.
         Honors the packed-rows contract (``_run_pack``): rows may share
-        one sequence's table at different starts, and k/v appends land
-        before any row's attention gathers per layer."""
+        one sequence's table at different starts, every one a multiple
+        of the page, and k/v appends land before any row's attention
+        gathers per layer."""
         ids = _raw(ids)
         b, s = ids.shape
         positions = jnp.clip(starts[:, None] + jnp.arange(s)[None, :], 0,
@@ -361,7 +359,8 @@ class GPTForCausalLM(GenerationMixin, Layer):
         tables = caches["tables"]
         new_kv = []
         for layer, (kp, vp) in zip(self.gpt.layers, caches["kv"]):
-            x, kp, vp = layer.paged_prefill_chunk(x, kp, vp, tables, starts)
+            x, kp, vp = layer.paged_prefill_chunk(x, kp, vp, tables, starts,
+                                                  page_aligned=True)
             new_kv.append((kp, vp))
         return {"kv": new_kv, "tables": tables}
 

@@ -281,21 +281,16 @@ class Lfm2Attention(Layer):
         return jnp.matmul(out.reshape(b, s, -1), self.o_proj_weight._data)
 
     @_scope("pt.attn")
-    def paged_chunk(self, x, cos, sin, k_pages, v_pages, tables, starts):
-        from ...ops.paged_attention import (append_paged_kv,
+    def paged_chunk(self, x, cos, sin, k_pages, v_pages, tables, starts,
+                    page_aligned=False):
+        from ...ops.paged_attention import (append_paged_chunk,
                                             paged_prefill_attention)
 
         x = _raw(x)
         b, s, _ = x.shape
         q, k, v = self._qkv(x, cos, sin)
-        nkv, hd = k.shape[2], k.shape[3]
-        max_len = tables.shape[1] * k_pages.shape[2]
-        seq_ids = jnp.repeat(jnp.arange(b, dtype=jnp.int32), s)
-        positions = jnp.clip(starts[:, None] + jnp.arange(s, dtype=jnp.int32),
-                             0, max_len - 1).reshape(-1)
-        k_pages, v_pages = append_paged_kv(
-            k_pages, v_pages, k.reshape(b * s, nkv, hd),
-            v.reshape(b * s, nkv, hd), tables, positions, seq_ids)
+        k_pages, v_pages = append_paged_chunk(
+            k_pages, v_pages, k, v, tables, starts, page_aligned)
         out = paged_prefill_attention(q, k_pages, v_pages, tables, starts)
         return (jnp.matmul(out.reshape(b, s, -1), self.o_proj_weight._data),
                 k_pages, v_pages)
@@ -429,8 +424,11 @@ class Lfm2ForCausalLM(Layer):
         """What each layer keeps, for the engine: an attention layer a
         ``(k_pages, v_pages)`` pair [pages, kv_heads, page, head_dim] in
         the form ``kv_pool_shape`` gives (heads of 64: two to a 128-lane
-        row), a conv layer a ``PageState`` ring [pages, L, hidden]."""
-        from ...ops.paged_attention import PageState, kv_pool_shape
+        row), a conv layer a ``PageState`` ring [pages, L, hidden]; the
+        pages asked for rounded up to the tile's rows (``pool_pages``: the
+        rings' scatter then collapses ``[L, pages]`` without a copy)."""
+        from ...ops.paged_attention import (PageState, kv_pool_shape,
+                                            pool_pages)
 
         cfg = self.config
         if kv_dtype not in (None, "param"):
@@ -445,6 +443,7 @@ class Lfm2ForCausalLM(Layer):
         if npages < b * maxp:
             raise ValueError(f"num_blocks {npages} < {b * maxp} — the pool "
                              "cannot back every slot's table")
+        npages = pool_pages(npages, dtype)
         kv = []
         for kind in cfg.layer_types:
             if kind == CONV:
@@ -518,7 +517,8 @@ class Lfm2ForCausalLM(Layer):
                                                   valid)
             else:
                 a, kp, vp = layer.self_attn.paged_chunk(
-                    n, cos, sin, entry[0], entry[1], tables, starts)
+                    n, cos, sin, entry[0], entry[1], tables, starts,
+                    page_aligned=True)
                 entry = (kp, vp)
             new_kv.append(entry)
             x, _ = layer.ffn(x + a)

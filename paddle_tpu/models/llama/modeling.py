@@ -265,43 +265,36 @@ class LlamaAttention(Layer):
 
     @_scope("pt.attn")
     def paged_prefill_chunk(self, x, cos, sin, k_pages, v_pages, tables,
-                            starts):
+                            starts, page_aligned=False):
         """Prefill CHUNK at PER-ROW absolute offsets over cached history
         (prefix-cache / chunked-prefill serving path). x: [b, s, h] — row b
         holds tokens at absolute positions [starts[b], starts[b]+s);
         cos/sin [b, s, d] gathered per row. The chunk's k/v scatter into the
         pages first, then attention gathers the FULL table extent with an
         absolute-position causal mask — see paged_prefill_attention for the
-        bit-identity-across-chunkings argument.
+        bit-identity-across-chunkings argument. ``page_aligned`` (static):
+        every ``starts[b]`` is a multiple of the page, so the append may
+        write whole pages (``ops.append_paged_chunk``); the packed prefill
+        says so, the speculative verify window, which runs this body at any
+        position, does not.
 
         Head counts come off the weight/pool shapes (not config), so the
         same body serves a tp shard inside the engine's serving shard_map
         (LOCAL heads + local kv pages per device — all math head-local);
         the attention output is all-gathered before the replicated o_proj
         (serving_sharding.py's column-parallel identity discipline)."""
-        from ...ops.paged_attention import (append_paged_kv,
+        from ...ops.paged_attention import (append_paged_chunk,
                                             paged_prefill_attention)
 
         x = x._data if isinstance(x, Tensor) else x
         b, s, _ = x.shape
         hd = self.config.head_dim
-        page = k_pages.shape[2]
-        max_len = tables.shape[1] * page
         q = jnp.matmul(x, self.q_proj_weight._data).reshape(b, s, -1, hd)
         k = jnp.matmul(x, self.k_proj_weight._data).reshape(b, s, -1, hd)
         v = jnp.matmul(x, self.v_proj_weight._data).reshape(b, s, -1, hd)
-        nkv = k.shape[2]
         q, k = apply_rotary_pos_emb(q, k, cos, sin)
-        seq_ids = jnp.repeat(jnp.arange(b, dtype=jnp.int32), s)
-        # pad rows of a final chunk land past the prompt; clipping keeps the
-        # scatter in-table (garbage there is masked, then overwritten as
-        # decode advances — the standard padded-prefill invariant)
-        positions = jnp.clip(starts[:, None] + jnp.arange(s, dtype=jnp.int32),
-                             0, max_len - 1).reshape(-1)
-        k_pages, v_pages = append_paged_kv(
-            k_pages, v_pages, k.reshape(b * s, nkv, hd),
-            v.reshape(b * s, nkv, hd), tables, positions,
-            seq_ids)
+        k_pages, v_pages = append_paged_chunk(
+            k_pages, v_pages, k, v, tables, starts, page_aligned)
         out = paged_prefill_attention(q, k_pages, v_pages, tables, starts)
         out = gather_output_shards(out.reshape(b, s, -1))
         return jnp.matmul(out, self.o_proj_weight._data), k_pages, v_pages
@@ -493,11 +486,11 @@ class LlamaDecoderLayer(Layer):
         return x, k_pages, v_pages
 
     def paged_prefill_chunk(self, hidden, cos, sin, k_pages, v_pages, tables,
-                            starts):
+                            starts, page_aligned=False):
         x = hidden._data if isinstance(hidden, Tensor) else hidden
         a, k_pages, v_pages = self.self_attn.paged_prefill_chunk(
             self.input_layernorm(x), cos, sin, k_pages, v_pages, tables,
-            starts)
+            starts, page_aligned)
         x = x + a
         y = self.mlp(self.post_attention_layernorm(x))
         x = x + (y._data if isinstance(y, Tensor) else y)
@@ -736,7 +729,9 @@ class LlamaForCausalLM(GenerationMixin, Layer):
         gathers — so a later chunk reads an earlier chunk's pages written
         in this very program; the absolute-position mask keeps the result
         bit-identical to sequential chunk calls (see
-        ops.paged_prefill_attention)."""
+        ops.paged_prefill_attention). Every ``starts[b]`` is a multiple of
+        the page (the engine's ``_run_pack`` holds its offsets to that), so
+        the layers append by the page."""
         cfg = self.config
         model = self.model
         x = jnp.take(model.embed_tokens_weight._data, ids, axis=0)
@@ -753,7 +748,8 @@ class LlamaForCausalLM(GenerationMixin, Layer):
         new_kv = []
         for layer, (kp, vp) in zip(model.layers, caches["kv"]):
             x, kp, vp = layer.paged_prefill_chunk(x, cos, sin, kp, vp,
-                                                  tables, starts)
+                                                  tables, starts,
+                                                  page_aligned=True)
             new_kv.append((kp, vp))
         return {"kv": new_kv, "tables": tables}
 

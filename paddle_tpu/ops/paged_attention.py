@@ -247,6 +247,13 @@ def kernel_layers(kv) -> tuple:
     return sum(_kernel_takes(p) for p in pools), len(pools)
 
 
+def page_append_layers(kv, chunk_tokens: int) -> int:
+    """Layers that keep K and V whose packed chunk of ``chunk_tokens`` a row
+    appends by the page (``append_paged_chunk``)."""
+    return sum(_appends_by_page(e[0], chunk_tokens) for e in kv
+               if not isinstance(e, PageState))
+
+
 def state_bytes(kv) -> int:
     """Bytes of the state rings kept with the pages (0 without such
     layers)."""
@@ -328,6 +335,20 @@ def kv_pool_shape(num_pages, kv_heads, page, head_dim, dtype,
             and page % _sublanes(dtype) == 0):
         return (num_pages, kv_heads // f, page, _LANES)
     return (num_pages, kv_heads, page, head_dim)
+
+
+def pool_pages(num_pages: int, dtype) -> int:
+    """Pages a pool is made with: the count asked for, rounded up to the
+    rows of the dtype's tile (``_sublanes``). The device keeps a state ring
+    ``[pages, slots, width]`` slot-major (a few slots would otherwise pad to
+    a tile of eight or sixteen), so its scatter collapses ``[slots, pages]``
+    to rows, and that is a bitcast only where the tile divides the pages:
+    at 10,497 pages of bf16 the ring was copied whole, in and out, every
+    chunk and conv layer (129 MB of temporaries for a described v5e; none at
+    10,512: PERF.md section 6, PR 35). The spare pages lie after the last
+    one asked for; no table names them."""
+    rows = _sublanes(dtype)
+    return -(-int(num_pages) // rows) * rows
 
 
 def _pool_fold(pool, d) -> int:
@@ -1039,6 +1060,67 @@ def append_paged_kv(k_cache, v_cache, k_new, v_new, block_tables, positions,
         # the lanes) and XLA picks one layout for the gather and the scatter
         at = (page_idx, slice(None), offs)
     return k_cache.at[at].set(k_new), v_cache.at[at].set(v_new)
+
+
+def _appends_by_page(pool, s: int) -> bool:
+    """Whether a page-aligned chunk of ``s`` tokens a row goes into ``pool``
+    as whole page blocks: the pools the kernel reads (a page of a head group
+    is one contiguous block in their default layout), whole pages only."""
+    return _kernel_takes(pool) and s % pool.shape[2] == 0
+
+
+def append_paged_chunk(k_cache, v_cache, k_new, v_new, block_tables, starts,
+                       page_aligned: bool = False):
+    """Scatter a chunk's tokens into the page pool: k_new/v_new [b, s,
+    kv_heads, d], row ``r`` holding absolute positions ``starts[r] ..
+    starts[r] + s - 1`` of the sequence whose table is ``block_tables[r]``.
+
+    ``page_aligned`` (static) is the caller's word that every ``starts[r]``
+    is a multiple of the page: the packed prefill gives it, a speculative
+    verify window cannot. With it, in a pool the kernel reads and for ``s``
+    a multiple of the page, a row's tokens are ``s // page`` whole pages,
+    each one contiguous block ``[groups, page, 128]`` in the default layout,
+    and go in as ``b * s // page`` updates indexed on the page alone, in
+    place; pages past the table's width are dropped. The row form spends one
+    update a token and head group on the same bytes, and the scatter is
+    bound by update issue, not bytes: on the v5e, 8 x 128 tokens x 8 heads
+    of 128 into a pool of 3,329 pages take 0.566 ms by the row (69 ns a
+    row) and 0.016 ms by the page (PERF.md section 6, PR 35). Anything
+    else (int8, a width that does not fill the lanes, a logical pool of
+    narrow heads, ``s % page != 0``, no promise of alignment) is
+    ``append_paged_kv`` at positions clipped into the table, as before."""
+    b, s, nkv, d = k_new.shape
+    page = k_cache.shape[2]
+    if not (page_aligned and _appends_by_page(k_cache, s)):
+        # pad rows of a final chunk land past the prompt; clipping keeps the
+        # scatter in-table (garbage there is masked, then overwritten as
+        # decode advances: the standard padded-prefill invariant)
+        max_len = block_tables.shape[1] * page
+        positions = jnp.clip(starts[:, None] + jnp.arange(s, dtype=jnp.int32),
+                             0, max_len - 1).reshape(-1)
+        seq_ids = jnp.repeat(jnp.arange(b, dtype=jnp.int32), s)
+        return append_paged_kv(k_cache, v_cache, k_new.reshape(b * s, nkv, d),
+                               v_new.reshape(b * s, nkv, d), block_tables,
+                               positions, seq_ids)
+    _pool_fold(k_cache, d)
+    groups, w = k_cache.shape[1], k_cache.shape[3]
+    n = s // page
+    cols = starts[:, None] // page + jnp.arange(n, dtype=jnp.int32)  # [b, n]
+    # past the table's width: a page out of range, whose update jax drops
+    page_idx = jnp.take_along_axis(
+        block_tables, cols, axis=1, mode="fill",
+        fill_value=k_cache.shape[0]).reshape(-1)
+
+    def blocks(x):
+        # [b, s, kv_heads, d] is [b, n, page, groups, 128] as it stands (a
+        # lane-dense row holds f heads side by side); a pool's page block
+        # has the head group before the slot
+        x = x.reshape(b, n, page, groups, w)
+        return jnp.swapaxes(x, 2, 3).reshape(b * n, groups, page, w)
+
+    with jax.named_scope("pt.kv_write"):
+        return (k_cache.at[page_idx].set(blocks(k_new), mode="drop"),
+                v_cache.at[page_idx].set(blocks(v_new), mode="drop"))
 
 
 def _append_quantized(pool: QuantizedKVPool, x_new, page_idx, offs):

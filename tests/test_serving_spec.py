@@ -138,6 +138,40 @@ def test_spec_metrics_families_render_at_zero():
         assert fams[name].samples
 
 
+def test_verify_window_keeps_the_row_append_where_the_chunk_writes_pages():
+    """The verify window runs the packed chunk's layer body at ANY position,
+    so it may not write whole pages: even with a window as long as a page,
+    in pools the page form takes (two KV heads of 64, lane-dense), its
+    lowered append is the row form (one index a token and head group) and
+    the packed chunk's the page form (one index a page)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    paddle.seed(11)
+    cfg = LlamaConfig.tiny(num_hidden_layers=1, hidden_size=128,
+                           num_attention_heads=2, num_key_value_heads=2)
+    model = LlamaForCausalLM(cfg)
+    caches = model._init_paged_caches(2, 32, page_size=8, num_blocks=12)
+    pool = caches["kv"][0][0].shape
+    assert pool[1:] == (1, 8, 128)
+    toks = jnp.zeros((2, 8), jnp.int32)            # K + 1 = 8 = one page
+    pos = jnp.asarray([8, 16], jnp.int32)
+
+    def scatters(fn):
+        text = jax.jit(fn).lower(toks, caches, pos).as_text()
+        return re.findall(r"\}\) : \(tensor<[^>]*>, (tensor<[^>]*>), "
+                          r"(tensor<[^>]*>)\) -> tensor", text)
+
+    by_row = ("tensor<16x1x3xi32>", "tensor<16x1x128xf32>")
+    by_page = ("tensor<2x1xi32>", "tensor<2x1x8x128xf32>")
+    assert scatters(lambda t, c, p: model.paged_verify_step(t, c, p)[1]) == [
+        by_row, by_row]
+    assert scatters(model.paged_prefill_chunk) == [by_page, by_page]
+
+
 # ---------------------------------------------------------------------------
 # engine waves (slow): byte-identity across widths/warm/cold/COW/replay
 # ---------------------------------------------------------------------------

@@ -15,7 +15,7 @@ from _lfm2_util import engine, reference_logits, seeded_model, serve
 from paddle_tpu.inference.serving import (LayerStateError, PrefixCacheConfig,
                                           Request)
 from paddle_tpu.ops.paged_attention import (PageState, layer_kinds,
-                                            page_state_read)
+                                            page_state_read, pool_pages)
 
 PAGE = 4
 
@@ -47,13 +47,16 @@ def test_model_states_what_each_layer_keeps(lfm2):
                                          "state"]
     ring = caches["kv"][0]
     assert isinstance(ring, PageState) and ring.page == PAGE
-    assert ring.ring.shape == (11, 3, 64)          # conv_L_cache slots a page
+    # the pages asked for, rounded up to the tile's rows (pool_pages)
+    n = pool_pages(11, ring.ring.dtype)
+    assert n % 8 == 0 and 11 <= n < 11 + 16
+    assert ring.ring.shape == (n, 3, 64)           # conv_L_cache slots a page
     k, v = caches["kv"][1]
-    assert k.shape == v.shape == (11, 2, PAGE, 16)
+    assert k.shape == v.shape == (n, 2, PAGE, 16)
     eng = engine(model)
     assert eng._state_layers == [0, 2, 3, 4]
     assert eng.stats["state_snapshot_bytes"] == 4 * ring.ring[0].nbytes * (
-        4 * 16 + 8 + 1)
+        pool_pages(4 * 16 + 8 + 1, ring.ring.dtype))
 
 
 # ---- decode ---------------------------------------------------------------

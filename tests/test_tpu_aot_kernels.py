@@ -8,13 +8,18 @@ The topology is described inside a fixture, never at import: only the
 worker that runs this file may load the TPU's library.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from paddle_tpu.ops.paged_attention import (kv_pool_shape,
-                                            paged_decode_attention)
+from paddle_tpu.ops.paged_attention import (PageState, append_paged_chunk,
+                                            kv_pool_shape, page_state_write,
+                                            paged_decode_attention,
+                                            pool_pages)
 
 
 @pytest.fixture(scope="module")
@@ -137,3 +142,63 @@ def test_lfm2_decode_block_holds_the_kernel_and_no_pool_copy(one_chip,
     # copy-done may move a pool this small between memory spaces
     assert {layout for layout, _ in pool_ops} == {"3,2,1,0"}, pool_ops
     assert "copy" not in {op for _, op in pool_ops}, pool_ops
+
+
+# (id, chunk rows, chunk tokens, kv heads, head_dim, pages a row, pages asked)
+_CHUNK_APPEND_SHAPES = [
+    # the chat-batch cell's packed chunk: 24 slots of 2048 + 256 + parking
+    ("cell-chat-batch", 8, 128, 8, 128, 128, 3329),
+    # chat-batch-64's, lane-dense, at 8 rows and at warm-up's widest wave
+    ("cell-chat-batch-64", 8, 128, 8, 64, 160, 10497),
+    ("cell-chat-batch-64-wave", 64, 128, 8, 64, 160, 10497),
+]
+
+
+@pytest.mark.parametrize("shape", _CHUNK_APPEND_SHAPES, ids=lambda s: s[0])
+def test_chunk_append_compiles_in_place_by_the_page(shape, one_chip):
+    """The packed chunk's append for the described v5e: one scatter a pool
+    on ``rows * tokens // page`` page indices (the row form spends ``rows *
+    tokens * head groups``), the donated pools aliased, nothing copied."""
+    _, b, s, hkv, d, maxp, asked = shape
+    bf, page = jnp.bfloat16, 16
+    sds = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    pool = kv_pool_shape(pool_pages(asked, bf), hkv, page, d, bf)
+    assert pool[0] % 16 == 0 and pool[-1] == 128
+    lowered = jax.jit(
+        functools.partial(append_paged_chunk, page_aligned=True),
+        donate_argnums=(0, 1)).trace(
+        sds(pool, bf), sds(pool, bf), sds((b, s, hkv, d), bf),
+        sds((b, s, hkv, d), bf), sds((b, maxp), jnp.int32),
+        sds((b,), jnp.int32)).lower(lowering_platforms=("tpu",))
+    n = b * s // page
+    text = lowered.as_text()
+    assert text.count('"stablehlo.scatter"(') == 2
+    assert text.count(f"xbf16>, tensor<{n}x1xi32>, tensor<{n}x") == 2
+    ma = lowered.compile().memory_analysis()
+    assert ma.alias_size_in_bytes == 2 * 2 * int(np.prod(pool))
+    assert ma.temp_size_in_bytes == 0
+
+
+def test_ring_write_at_the_engines_page_count_copies_nothing(one_chip):
+    """``page_state_write`` on a conv ring of chat-batch-64 (hidden 2048,
+    three slots, 16 x 128 tokens): the device keeps the ring slot-major and
+    the scatter collapses ``[slots, pages]`` to rows, a bitcast where the
+    tile's rows divide the pages. At the 10,497 pages the engine asks for
+    the compiled program copied the ring in and out (temporaries
+    129,265,664 B); at ``pool_pages`` of them it copies nothing."""
+    bf, rows = jnp.bfloat16, 16 * 128
+    sds = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    pages = pool_pages(10497, bf)
+    assert pages == 10512
+
+    def write(ring, vals, tables, pos, seq, valid):
+        return page_state_write(PageState(ring, 16), vals, tables, pos, seq,
+                                valid).ring
+
+    ma = jax.jit(write, donate_argnums=(0,)).trace(
+        sds((pages, 3, 2048), bf), sds((rows, 2048), bf),
+        sds((16, 160), jnp.int32), sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32), sds((rows,), jnp.bool_)).lower(
+        lowering_platforms=("tpu",)).compile().memory_analysis()
+    assert ma.alias_size_in_bytes == pages * 3 * 2048 * 2
+    assert ma.temp_size_in_bytes == 0
