@@ -243,6 +243,17 @@ class SwiGLUExpertFFN(Layer):
         return grouped_dot(jax.nn.silu(g) * u, self.w_down._data,
                            group_sizes)
 
+    def forward_dense(self, tokens, gate_of):
+        """Dropless form for a few rows: every expert multiplies every
+        token and reads its weights once. tokens [n, d]; ``gate_of`` [n, E]
+        float32, a token's gate for each expert, 0 where it was not chosen.
+        Returns the gated sum [n, d]."""
+        g = jnp.einsum("nd,edf->nef", tokens, self.w_gate._data)
+        u = jnp.einsum("nd,edf->nef", tokens, self.w_up._data)
+        act = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+               * gate_of[:, :, None]).astype(tokens.dtype)
+        return jnp.einsum("nef,efd->nd", act, self.w_down._data)
+
     def forward_pgmm(self, x_pad, tile_gids, tile_m=None, interpret=False):
         """Dropless grouped swiglu via the Pallas padded grouped matmul
         (dispatch_mode="pgmm", ops/grouped_matmul.py)."""
@@ -254,6 +265,49 @@ class SwiGLUExpertFFN(Layer):
         u = pgmm(x_pad, self.w_up._data, tile_gids, tile_m, interpret)
         return pgmm(jax.nn.silu(g) * u, self.w_down._data, tile_gids,
                     tile_m, interpret)
+
+
+class Relu2ExpertFFN(Layer):
+    """Experts of two matrices, not gated: ``relu(x W_up)^2 W_down``
+    (Nemotron-H's ``relu2``), stacked over the expert axis, no bias."""
+
+    def __init__(self, num_experts: int, d_model: int, d_hidden: int,
+                 dtype: str = "float32", initializer_range: float = 0.02):
+        super().__init__()
+        self.num_experts = num_experts
+        init = I.Normal(std=initializer_range)
+        mk = lambda shape: self.create_parameter(shape, dtype=dtype,
+                                                 default_initializer=init)
+        self.w_up = annotate(mk([num_experts, d_model, d_hidden]),
+                             "expert", "embed", "expert_mlp")
+        self.w_down = annotate(mk([num_experts, d_hidden, d_model]),
+                               "expert", "expert_mlp", "embed")
+
+    def forward(self, x):
+        """x [E, C, d_model], batched over the expert axis."""
+        x = _raw(x)
+        u = jnp.einsum("ecd,edm->ecm", x, self.w_up._data)
+        h = constrain(jnp.square(jax.nn.relu(u)), "expert", None,
+                      "expert_mlp")
+        out = jnp.einsum("ecm,emd->ecd", h, self.w_down._data)
+        return constrain(out, "expert", None, "embed")
+
+    def forward_ragged(self, x, group_sizes, expert_ids):
+        """Dropless grouped form: x [m, d] sorted by expert."""
+        from .....ops.grouped_matmul import grouped_dot
+
+        x = _raw(x)
+        u = grouped_dot(x, self.w_up._data, group_sizes)
+        return grouped_dot(jnp.square(jax.nn.relu(u)), self.w_down._data,
+                           group_sizes)
+
+    def forward_dense(self, tokens, gate_of):
+        """Dropless form for a few rows (``SwiGLUExpertFFN.forward_dense``):
+        tokens [n, d], ``gate_of`` [n, E] float32; returns [n, d]."""
+        u = jnp.einsum("nd,edf->nef", tokens, self.w_up._data)
+        act = (jnp.square(jax.nn.relu(u.astype(jnp.float32)))
+               * gate_of[:, :, None]).astype(tokens.dtype)
+        return jnp.einsum("nef,efd->nd", act, self.w_down._data)
 
 
 class MoELayer(Layer):
@@ -374,12 +428,14 @@ def dropless_arm(rows: int) -> str:
 
 
 def dropless_ffn(tokens, expert_idx, gates, experts, first: int = 0):
-    """Routed SwiGLU FFN with no capacity: every chosen (token, expert)
-    pair whose expert this layer holds is computed, none is dropped.
+    """Routed FFN with no capacity: every chosen (token, expert) pair whose
+    expert this layer holds is computed, none is dropped.
 
     tokens [n, d]; expert_idx [n, k] int32 over ALL experts; gates [n, k]
-    float32; ``experts`` a :class:`SwiGLUExpertFFN` holding experts
-    ``[first, first + experts.num_experts)``. Returns this share's part of
+    float32; ``experts`` a :class:`SwiGLUExpertFFN` (three matrices, gated)
+    or a :class:`Relu2ExpertFFN` (two, ``relu^2``) holding experts
+    ``[first, first + experts.num_experts)``: the stack does the arithmetic
+    (``forward_dense`` or ``forward_ragged``). Returns this share's part of
     the layer's output [n, d] (the shares of a partition of the experts sum
     to the whole layer: expert parallelism's form) and ``rows`` [local
     experts] int32, the rows each local expert got."""
@@ -391,19 +447,13 @@ def dropless_ffn(tokens, expert_idx, gates, experts, first: int = 0):
     gates = jnp.where(mine, gates, 0.0)
     local = jnp.where(mine, local, e)              # e: nobody's
     rows = jnp.zeros((e + 1,), jnp.int32).at[local.reshape(-1)].add(1)[:e]
-    wg, wu, wd = (experts.w_gate._data, experts.w_up._data,
-                  experts.w_down._data)
     with jax.named_scope("pt.moe.experts"):
         if dropless_arm(n) == "dense":
             # [n, e]: a token's gate for each local expert, 0 if unchosen
             gate_of = jnp.einsum(
                 "nk,nke->ne", gates,
                 jax.nn.one_hot(local, e, dtype=jnp.float32))
-            g = jnp.einsum("nd,edf->nef", tokens, wg)
-            u = jnp.einsum("nd,edf->nef", tokens, wu)
-            act = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
-                   * gate_of[:, :, None]).astype(tokens.dtype)
-            out = jnp.einsum("nef,efd->nd", act, wd)
+            out = experts.forward_dense(tokens, gate_of)
         else:
             flat = local.reshape(-1)                              # [n*k]
             order = jnp.argsort(flat, stable=True)   # nobody's rows last
@@ -427,11 +477,19 @@ class DroplessMoE(Layer):
 
     ``forward(x)`` returns the layer's (share of the) output with x's
     shape; ``forward(x, with_rows=True)`` also returns the rows each local
-    expert got ([count] int32), for the serving engine's counters."""
+    expert got ([count] int32), for the serving engine's counters.
+
+    ``experts`` is the stack of the ``count`` local experts where they are
+    not SwiGLU ones (a :class:`Relu2ExpertFFN`); ``shared`` a layer every
+    token goes through beside the routed ones (``x -> [.., d_model]``),
+    added whole to this share's output: what every chip of an
+    expert-parallel layer computes alike."""
 
     def __init__(self, d_model: int, num_experts: int, d_hidden: int,
                  gate: BaseGate, first: int = 0, count: Optional[int] = None,
-                 dtype: str = "float32", initializer_range: float = 0.02):
+                 dtype: str = "float32", initializer_range: float = 0.02,
+                 experts: Optional[Layer] = None,
+                 shared: Optional[Layer] = None):
         super().__init__()
         count = num_experts - first if count is None else int(count)
         if not 0 <= first <= first + count <= num_experts:
@@ -440,8 +498,14 @@ class DroplessMoE(Layer):
         self.num_experts, self.first = int(num_experts), int(first)
         self.gate = gate
         self.top_k = gate.top_k
-        self.experts = SwiGLUExpertFFN(count, d_model, d_hidden, dtype=dtype,
-                                       initializer_range=initializer_range)
+        if experts is None:
+            experts = SwiGLUExpertFFN(count, d_model, d_hidden, dtype=dtype,
+                                      initializer_range=initializer_range)
+        elif experts.num_experts != count:
+            raise ValueError(f"the stack holds {experts.num_experts} "
+                             f"experts, the layer {count}")
+        self.experts = experts
+        self.shared = shared
 
     @functools.partial(jax.named_call, name="pt.moe")
     def forward(self, x, with_rows: bool = False):
@@ -451,5 +515,8 @@ class DroplessMoE(Layer):
             idx, gates = self.gate.route(tokens)
         out, rows = dropless_ffn(tokens, idx, gates, self.experts,
                                  self.first)
+        if self.shared is not None:
+            with jax.named_scope("pt.moe.shared"):
+                out = out + _raw(self.shared(tokens))
         out = out.reshape(x.shape)
         return (out, rows) if with_rows else out

@@ -734,19 +734,30 @@ class ContinuousBatchingEngine:
         # caches["kv"], is shared, copied on write, evicted and migrated
         # with its page, and needs from the engine only each chunk row's
         # count of real tokens (a padded tail must leave no trace in it).
-        self._state_layers = [i for i, k in enumerate(
-            layer_kinds(self.caches["kv"])) if k == "state"]
+        # "seq" is a state kept a SEQUENCE (ops.paged_attention.SeqState: a
+        # row a slot, too large to ride the pages). It rides the programs
+        # inside caches["kv"] too; the engine tells the model which slot
+        # each row of a program is, keeps every chunk row's state short of
+        # the prompt's last token (the first-token program steps that one),
+        # and leaves the radix trie alone: no page carries the state a hit
+        # would have to resume from.
+        kinds = layer_kinds(self.caches["kv"])
+        self._state_layers = [i for i, k in enumerate(kinds) if k == "state"]
+        self._seq_layers = [i for i, k in enumerate(kinds) if k == "seq"]
         for what, on in (
                 ("speculative decoding (a rejected draft cannot be taken "
-                 "back out of a state ring)", self._spec is not None),
+                 "back out of the state)", self._spec is not None),
                 ("an engine without a prefix cache (bucketed prompts are "
                  "prefilled through generate()'s dense-cache hook)",
                  prefix_cache is None)):
-            if on and self._state_layers:
-                raise LayerStateError(
-                    f"PT-SRV-009: {type(model).__name__} keeps layers "
-                    f"{self._state_layers} of kind 'state' (PageState); "
-                    f"they cannot be served by {what}")
+            for kind, layers, held in (
+                    ("state", self._state_layers, "PageState"),
+                    ("seq", self._seq_layers, "SeqState")):
+                if on and layers:
+                    raise LayerStateError(
+                        f"PT-SRV-009: {type(model).__name__} keeps layers "
+                        f"{layers} of kind {kind!r} ({held}); they cannot "
+                        f"be served by {what}")
         self._ctr_layout: List[tuple] = []
         self._slots: List[Optional[Request]] = [None] * max_batch
         # O(active) bookkeeping (big-batch refactor): occupied slots in a
@@ -855,7 +866,19 @@ class ContinuousBatchingEngine:
                       # expert's rows, each summed over expert layers and
                       # token steps, and the number of those (layer, step)
                       "moe_rows_routed": 0, "moe_experts_touched": 0,
-                      "moe_layer_steps": 0, "moe_rows_max_expert": 0}
+                      "moe_layer_steps": 0, "moe_rows_max_expert": 0,
+                      # picks the routers made (rows x experts a token, a
+                      # layer and token step), where the model returns
+                      # "moe_picks": of them moe_rows_routed went to experts
+                      # held here (all, unless the layers hold a share)
+                      "moe_picks": 0,
+                      # state kept a sequence ("seq" layers): its bytes, the
+                      # chunk rows that started one from zero, and the
+                      # admissions that went without the radix trie for it
+                      "seq_state_bytes": state_bytes(self.caches["kv"],
+                                                     "seq"),
+                      "seq_state_starts": 0,
+                      "prefix_declined_admissions": 0}
         # per-program collective census (label -> per-dispatch wire bytes),
         # filled lazily as each sharded program first dispatches — feeds
         # the serving collector and mirrors the PT-COMM contract entries
@@ -1325,7 +1348,9 @@ class ContinuousBatchingEngine:
             size = int(np.prod(shape))
             a = tail[off:off + size].T.reshape((tail.shape[1],) + shape)
             off += size
-            if name == "moe_rows":
+            if name == "moe_picks":
+                self.stats["moe_picks"] += int(a.sum())
+            elif name == "moe_rows":
                 st = self.stats
                 st["moe_rows_routed"] += int(a.sum())
                 st["moe_experts_touched"] += int((a > 0).sum())
@@ -1513,6 +1538,11 @@ class ContinuousBatchingEngine:
             raise ValueError("KV-chain splice needs a prefix-cache engine "
                              "(dynamic block tables over the refcounted "
                              "pool)")
+        if self._seq_layers:
+            raise LayerStateError(
+                f"PT-SRV-009: layers {self._seq_layers} are of kind 'seq' "
+                f"(SeqState, kept a slot): a migrated chain brings pages, "
+                f"and the state of rid={req.rid} would be lost")
         if not self._free_slots:
             raise EngineSaturated(
                 f"no free slot for migrated rid={req.rid} "
@@ -1913,6 +1943,10 @@ class ContinuousBatchingEngine:
 
             def body(carry, _):
                 tok, cs, p = carry
+                if self._seq_layers:
+                    # row i is slot i; a row that does not decode leaves
+                    # its slot's state as it is
+                    cs = dict(cs, seq_live=act)
                 with autograd_engine.no_grad(), _Swap(self._tensors, params):
                     logits, cs = self.model.paged_token_step(tok, cs, p)
                 ctr = cs.pop("counters", None)
@@ -2261,7 +2295,13 @@ class ContinuousBatchingEngine:
         # every block is freshly allocated (still through the refcounted
         # pool), which is exactly the cache-off working-set shape
         matched = (self._radix.match(prompt[: n_full * page])
-                   if n_full and not self._brownout_active else [])
+                   if n_full and not self._brownout_active
+                   and not self._seq_layers else [])
+        if self._seq_layers:
+            # no page carries the state a hit would resume from: the trie is
+            # neither asked nor fed (_emit_first), and the prompt prefills
+            # from position 0, which starts the slot's state from zero
+            self.stats["prefix_declined_admissions"] += 1
         cow_src = None
         if matched and len(matched) * page == len(prompt):
             # FULL-prompt hit: nothing to prefill, but the first-token
@@ -2402,12 +2442,17 @@ class ContinuousBatchingEngine:
             from ..core import autograd_engine
             from ..jit.api import _Swap
 
-            def pt_prefill_chunk(params, ids, kv, rows, starts, *valid):
-                # valid (state layers only): each row's count of real
-                # tokens, so that a padded tail leaves their state alone
+            def pt_prefill_chunk(params, ids, kv, rows, starts, *extra):
+                # extra, by what the layers keep: ("state") each row's
+                # count of real tokens, so that a padded tail leaves their
+                # state alone; ("seq") each row's slot and its count of
+                # positions whose state is kept
                 sub = {"kv": kv, "tables": rows}
-                if valid:
-                    sub["valid"] = valid[0]
+                extra = list(extra)
+                if self._state_layers:
+                    sub["valid"] = extra.pop(0)
+                if self._seq_layers:
+                    sub["seq"] = (extra.pop(0), extra.pop(0))
                 with autograd_engine.no_grad(), _Swap(self._tensors, params):
                     sub = self.model.paged_prefill_chunk(ids, sub, starts)
                 return sub["kv"]
@@ -2462,6 +2507,11 @@ class ContinuousBatchingEngine:
         ids = np.zeros((g, C), np.int32)
         starts = np.zeros(g, np.int32)
         real = np.zeros(g, np.int32)       # parked dummy rows: no real token
+        # "seq" layers: each row's slot (dummy rows: none) and how many of
+        # its positions leave their state behind: all but the prompt's last
+        # token, which the first-token program steps at its true position
+        seq_slot = np.full(g, self.max_batch, np.int32)
+        seq_keep = np.zeros(g, np.int32)
         trows = np.full((g, self._maxp), self._park, np.int32)
         for r, (s, req, off) in enumerate(rows):
             if off % self.page_size:
@@ -2473,14 +2523,21 @@ class ContinuousBatchingEngine:
             ids[r, : len(chunk)] = chunk
             starts[r] = off
             real[r] = len(chunk)
+            seq_slot[r] = s
+            seq_keep[r] = min(len(chunk), len(req.prompt) - 1 - off)
             trows[r] = self._prefill_row(s, req)
         new_kv = self._call_built(
             "pt_prefill_chunk", g, self._chunk_fn(g), self._params,
             jnp.asarray(ids), self.caches["kv"], jnp.asarray(trows),
             jnp.asarray(starts),
-            *([jnp.asarray(real)] if self._state_layers else []))
+            *([jnp.asarray(real)] if self._state_layers else []),
+            *([jnp.asarray(seq_slot), jnp.asarray(seq_keep)]
+              if self._seq_layers else []))
         self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
         self.stats["packed_rows"] += len(rows)
+        if self._seq_layers:
+            self.stats["seq_state_starts"] += sum(
+                1 for _, _, off in rows if off == 0)
         for s, req in group:
             nxt = self._prefill_next[s]
             if offs[s] > nxt:
@@ -2526,6 +2583,9 @@ class ContinuousBatchingEngine:
                                                  ints[:, 2], ints[:, 3])
                 temp, top_p = floats[:, 0], floats[:, 1]
                 sub = {"kv": kv, "tables": rows}
+                if self._seq_layers:
+                    # a dummy row's slot is out of range: no state changes
+                    sub["seq_slots"] = slots_
                 with autograd_engine.no_grad(), _Swap(self._tensors, params):
                     logits, sub = self.model.paged_token_step(
                         last, sub, true_len - 1)
@@ -2568,7 +2628,8 @@ class ContinuousBatchingEngine:
         ft_marks = [] if self.tracer is not None else None
         for row, (slot, req) in enumerate(ready):
             n_full = len(req.prompt) // self.page_size
-            if n_full and not self._brownout_active:
+            if n_full and not self._brownout_active \
+                    and not self._seq_layers:
                 # register AFTER the full prompt (incl. the re-step rewrite)
                 # is scheduled — later admissions are device-ordered behind
                 # these writes; first writer wins on duplicate chains.

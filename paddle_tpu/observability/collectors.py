@@ -75,6 +75,7 @@ def _stat_families(prefix: str, stats: dict, kinds: dict,
 # stats-dict keys that are level readings, not monotonic totals
 _ENGINE_GAUGE_KEYS = {"compile_cache_entries", "step_max_s",
                       "step_max_wait_s", "state_snapshot_bytes",
+                      "seq_state_bytes",
                       "kv_layers", "paged_kernel_layers",
                       "page_append_layers"}
 # stats-dict keys NOT exported from engine.stats: "evictions" is a lagging

@@ -214,15 +214,62 @@ def page_state_read(state: PageState, block_tables, positions, back: int,
     return jnp.where((q >= 0)[..., None], vals, jnp.zeros((), vals.dtype))
 
 
+@jax.tree_util.register_pytree_node_class
+class SeqState:
+    """A layer's state kept a SEQUENCE, not with the pages: what is too
+    large to ride them and is no function of a few last inputs (a Mamba-2
+    layer: ``ssm`` [slots, heads, head_dim, state] float32, the recurrence's
+    matrix, and ``conv`` [slots, taps - 1, width], the inputs its short
+    convolution still needs). Row ``i`` belongs to the engine's slot ``i``
+    for as long as a request holds the slot. Nothing resets it: a packed
+    chunk row that starts at position 0, and a token step at position 0,
+    start from zero; a later chunk row and a decode step resume from the
+    slot's row; rows that do not decode, dummy rows and padded positions
+    leave it as it was; when the request leaves, the row is dead until the
+    next one starts from zero. No page carries it, so it cannot be shared by
+    a prefix hit, exported with a chain or moved to another slot: what would
+    need that raises ``LayerStateError`` (docs/SERVING.md "State that is not
+    pages"). Registered as a pytree: it rides jit carries and donation
+    inside ``caches["kv"]`` like the entries beside it."""
+
+    __slots__ = ("ssm", "conv")
+
+    def __init__(self, ssm, conv):
+        self.ssm = ssm
+        self.conv = conv
+
+    def tree_flatten(self):
+        return (self.ssm, self.conv), None
+
+    @classmethod
+    def tree_unflatten(cls, _, children):
+        return cls(*children)
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(a.size) * jnp.dtype(a.dtype).itemsize
+                   for a in (self.ssm, self.conv))
+
+    def __repr__(self):
+        return (f"SeqState(ssm={tuple(self.ssm.shape)} {self.ssm.dtype}, "
+                f"conv={tuple(self.conv.shape)} {self.conv.dtype})")
+
+
+def _kind(entry) -> str:
+    return ("state" if isinstance(entry, PageState) else
+            "seq" if isinstance(entry, SeqState) else "kv")
+
+
 def layer_kinds(kv) -> List[str]:
     """What each layer of ``caches["kv"]`` keeps: "kv" (pages of K and V a
-    token) or "state" (a fixed block, ``PageState``)."""
-    return ["state" if isinstance(e, PageState) else "kv" for e in kv]
+    token), "state" (a fixed block kept with the page, ``PageState``) or
+    "seq" (a state kept a sequence, by slot: ``SeqState``)."""
+    return [_kind(e) for e in kv]
 
 
 def pool_num_pages(kv) -> int:
-    """Pages in the pool, whatever the first layer keeps."""
-    e = kv[0]
+    """Pages in the pool, by the first layer that keeps pages."""
+    e = next(e for e in kv if not isinstance(e, SeqState))
     return int((e.ring if isinstance(e, PageState) else e[0]).shape[0])
 
 
@@ -233,9 +280,9 @@ def pool_geometry(kv) -> List[tuple]:
     form, ``kv_pool_shape``)."""
     out = []
     for e in kv:
-        a = e.ring if isinstance(e, PageState) else e[0]
-        out.append(("state" if isinstance(e, PageState) else "kv",
-                    tuple(a.shape[1:]), str(a.dtype)))
+        a = (e.ring if isinstance(e, PageState) else
+             e.ssm if isinstance(e, SeqState) else e[0])
+        out.append((_kind(e), tuple(a.shape[1:]), str(a.dtype)))
     return out
 
 
@@ -243,7 +290,7 @@ def kernel_layers(kv) -> tuple:
     """``(layers whose pools pt_paged_decode reads, layers that keep K and
     V)``: whether the kernel and the in-place append engage is fixed with
     the pools' shapes, when an engine is built."""
-    pools = [e[0] for e in kv if not isinstance(e, PageState)]
+    pools = [e[0] for e in kv if _kind(e) == "kv"]
     return sum(_kernel_takes(p) for p in pools), len(pools)
 
 
@@ -251,13 +298,13 @@ def page_append_layers(kv, chunk_tokens: int) -> int:
     """Layers that keep K and V whose packed chunk of ``chunk_tokens`` a row
     appends by the page (``append_paged_chunk``)."""
     return sum(_appends_by_page(e[0], chunk_tokens) for e in kv
-               if not isinstance(e, PageState))
+               if _kind(e) == "kv")
 
 
-def state_bytes(kv) -> int:
-    """Bytes of the state rings kept with the pages (0 without such
-    layers)."""
-    return sum(e.nbytes for e in kv if isinstance(e, PageState))
+def state_bytes(kv, kind: str = "state") -> int:
+    """Bytes of the state rings kept with the pages ("state") or of the
+    state kept a sequence ("seq"); 0 without such layers."""
+    return sum(e.nbytes for e in kv if _kind(e) == kind)
 
 
 def copy_layer_pages(entry, src, dst):
@@ -272,11 +319,13 @@ def copy_layer_pages(entry, src, dst):
 
 def require_kv_layers(kv, what: str):
     kinds = layer_kinds(kv)
-    if "state" in kinds:
-        raise LayerStateError(
-            f"PT-SRV-009: {what} carries pages of K and V only; layer(s) "
-            f"{[i for i, k in enumerate(kinds) if k == 'state']} are of "
-            f"kind 'state' (PageState rings), which it would drop")
+    for kind, held in (("state", "PageState rings"),
+                       ("seq", "SeqState, kept a slot")):
+        if kind in kinds:
+            raise LayerStateError(
+                f"PT-SRV-009: {what} carries pages of K and V only; layer(s) "
+                f"{[i for i, k in enumerate(kinds) if k == kind]} are of "
+                f"kind {kind!r} ({held}), which it would drop")
 
 
 def kv_absmax(x):

@@ -49,6 +49,9 @@ _PAGED_DECODE_SHAPES = [
     # the chat-batch-64 cell: lfm2-24b-a2b, 64 slots of 2560, page 16; heads
     # of 64 stored two to a 128-lane row, read by the same kernel
     ("cell-lfm2-gqa32x8-d64", 64, 32, 8, 64, 16, 160, 10497, jnp.bfloat16),
+    # the chat-short-batch-32 cell: nemotron-3-nano, 32 slots of 1536, two
+    # KV heads of 128 under 16 query heads each
+    ("cell-nemotron-gqa32x2", 32, 32, 2, 128, 16, 96, 3152, jnp.bfloat16),
     # four heads of 32 to a row, and float32 pools of heads of 64
     ("mha8-d32", 8, 8, 8, 32, 16, 72, 600, jnp.bfloat16),
     ("f32-d64", 4, 8, 4, 64, 8, 150, 600, jnp.float32),
@@ -202,3 +205,91 @@ def test_ring_write_at_the_engines_page_count_copies_nothing(one_chip):
         lowering_platforms=("tpu",)).compile().memory_analysis()
     assert ma.alias_size_in_bytes == pages * 3 * 2048 * 2
     assert ma.temp_size_in_bytes == 0
+
+
+# ---- Mamba-2's scan and step, the relu^2 experts (chat-short-batch-32) -------
+
+_SSD = dict(heads=64, p=64, groups=8, n=128)     # nemotron-3-nano's widths
+
+
+def _ssd_args(sds, lead):
+    bf, f32 = jnp.bfloat16, jnp.float32
+    d = _SSD
+    return (sds((*lead, d["heads"], d["p"]), bf),           # x
+            sds((*lead, d["heads"]), f32),                   # dt
+            sds((d["heads"],), f32),                         # A
+            sds((*lead, d["groups"], d["n"]), bf),           # B
+            sds((*lead, d["groups"], d["n"]), bf),           # C
+            sds((d["heads"],), f32))                         # D
+
+
+def test_ssd_packed_chunk_compiles_for_v5e_with_the_pool_in_place(one_chip):
+    """``ssd_scan_pooled`` on the cell's widest pack (32 rows of one chunk
+    of 128) over the pool of 32 slots: the pool is donated and comes back
+    aliased; the temporaries (the ``[chunk, chunk]`` decays of 64 heads a
+    row in float32, the rows' states) stay under 1.2 GB."""
+    from paddle_tpu.ops import ssd
+
+    sds = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    pool = (32, 64, 64, 128)
+    ma = jax.jit(functools.partial(ssd.ssd_scan_pooled, chunk=128),
+                 donate_argnums=(0,)).trace(
+        sds(pool, jnp.float32), *_ssd_args(sds, (32, 128)),
+        sds((32,), jnp.int32), sds((32,), jnp.bool_),
+        sds((32,), jnp.int32)).lower(
+        lowering_platforms=("tpu",)).compile().memory_analysis()
+    assert ma.alias_size_in_bytes == int(np.prod(pool)) * 4
+    assert ma.temp_size_in_bytes < 1.2e9
+
+
+@pytest.mark.parametrize("by", ["live", "slots"])
+def test_ssd_step_compiles_for_v5e_and_updates_the_pool_in_place(by,
+                                                                 one_chip):
+    """The one-token step over 32 slots: donated, the pool comes back
+    aliased (no second copy of a layer's 64 MB); by the decode block's mask
+    the pass needs no temporary of the pool's size at all."""
+    from paddle_tpu.ops import ssd
+
+    sds = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    pool = (32, 64, 64, 128)
+    key = sds((32,), jnp.bool_) if by == "live" else sds((32,), jnp.int32)
+
+    def step(pool, x, dt, a, b, c, d, fresh, key):
+        return ssd.ssd_step(pool, x, dt, a, b, c, d, fresh, **{by: key})
+
+    ma = jax.jit(step, donate_argnums=(0,)).trace(
+        sds(pool, jnp.float32), *_ssd_args(sds, (32,)),
+        sds((32,), jnp.bool_), key).lower(
+        lowering_platforms=("tpu",)).compile().memory_analysis()
+    assert ma.alias_size_in_bytes == int(np.prod(pool)) * 4
+    if by == "live":
+        assert ma.temp_size_in_bytes < 8e6
+
+
+def test_relu2_dense_arm_compiles_for_v5e_at_32_rows_of_16_experts(one_chip):
+    """``dropless_ffn``'s dense arm on the cell's decode shape: 32 rows, 16
+    held experts of 2688 x 1856 out of 128 routed, 6 a token."""
+    import paddle_tpu
+    from paddle_tpu.incubate.distributed.models.moe import moe_layer
+    from paddle_tpu.jit.api import _Swap
+
+    sds = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    bf = jnp.bfloat16
+    assert moe_layer.dropless_arm(32) == "dense"
+
+    with paddle_tpu.LazyGuard():            # shapes only: no 319 MB drawn
+        experts = moe_layer.Relu2ExpertFFN(16, 2688, 1856, dtype="bfloat16")
+
+    def ffn(tokens, idx, gates, w_up, w_down):
+        with _Swap([experts.w_up, experts.w_down], [w_up, w_down]):
+            return moe_layer.dropless_ffn(tokens, idx, gates, experts,
+                                          first=0)
+
+    compiled = jax.jit(ffn).trace(
+        sds((32, 2688), bf), sds((32, 6), jnp.int32),
+        sds((32, 6), jnp.float32), sds((16, 2688, 1856), bf),
+        sds((16, 1856, 2688), bf)).lower(
+        lowering_platforms=("tpu",)).compile()
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes >= 2 * 16 * 2688 * 1856 * 2
+    assert ma.temp_size_in_bytes < 64e6
