@@ -129,8 +129,8 @@ __all__ = ["AutoscaleConfig", "BlockAllocator", "BrownoutConfig",
            "ContinuousBatchingEngine", "EngineSaturated", "FleetConfig",
            "FleetRouter", "KVCacheConfig", "KVChainCodec", "KVChainCorrupt",
            "LayerStateError", "MeshConfig", "MeshDegraded",
-           "PageAlignmentError", "PrefixCacheConfig", "RadixPrefixCache",
-           "ReplicaState",
+           "PackOrderError", "PageAlignmentError", "PrefixCacheConfig",
+           "RadixPrefixCache", "ReplicaState",
            "Request", "RequestJournal", "RequestShed", "SLOAutoscaler",
            "ServingSupervisor", "SpecConfig", "StepWatchdog", "TieredRouter"]
 
@@ -208,6 +208,17 @@ class PageAlignmentError(ValueError):
     there (a prefix hit's whole pages, then whole chunks), so this names a
     bug in the engine, before it can write a page's tokens into the wrong
     slots."""
+
+
+class PackOrderError(ValueError):
+    """PT-SRV-011: the rows of a pack for a model whose layers keep a state
+    a sequence (kind ``"seq"``) are not by sequence: the chunks of one slot
+    adjacent, each starting where the one before it ended.
+    ``ops.ssd.ssd_scan_pooled`` reads a slot's state once a run of adjacent
+    rows and writes it back once, so a slot in two runs would resume both
+    from the same state and keep one of them; it cannot raise on a traced
+    value, and ``_run_pack`` orders the rows itself, so this names a bug in
+    the engine, before the call."""
 
 
 class EngineSaturated(RuntimeError):
@@ -873,11 +884,14 @@ class ContinuousBatchingEngine:
                       # held here (all, unless the layers hold a share)
                       "moe_picks": 0,
                       # state kept a sequence ("seq" layers): its bytes, the
-                      # chunk rows that started one from zero, and the
-                      # admissions that went without the radix trie for it
+                      # chunk rows that started one from zero, the runs of
+                      # adjacent chunk rows of one sequence (a state is read
+                      # and written back once a run), and the admissions
+                      # that went without the radix trie for it
                       "seq_state_bytes": state_bytes(self.caches["kv"],
                                                      "seq"),
                       "seq_state_starts": 0,
+                      "seq_state_runs": 0,
                       "prefix_declined_admissions": 0}
         # per-program collective census (label -> per-dispatch wire bytes),
         # filled lazily as each sharded program first dispatches — feeds
@@ -2483,9 +2497,14 @@ class ContinuousBatchingEngine:
         Rows are assigned breadth-first (one chunk per slot per pass), so
         every mid-prefill slot advances at least one chunk per step — the
         interleaving guarantee — and ``PrefixCacheConfig.pack_rows``
-        bounds the extra rows. Row counts are bucketed to powers of two
-        with parked dummy rows, so admission-width churn at 128+ slots
-        compiles O(log max_batch) variants, not one per width."""
+        bounds the extra rows. A model whose layers keep a state a
+        sequence (kind ``"seq"``) gets the rows so picked BY SEQUENCE,
+        ordered by (slot, offset): its scan carries one sequence's state
+        from a row to the next (``ops.ssd.ssd_scan_pooled``), and K and V
+        do not care, by the argument above. Row counts are bucketed to
+        powers of two with parked dummy rows, last in either order, so
+        admission-width churn at 128+ slots compiles O(log max_batch)
+        variants, not one per width."""
         C = self._chunk_tokens
         budget = max(len(group), self._pack_rows)
         offs = {s: self._prefill_next[s] for s, _ in group}
@@ -2500,6 +2519,9 @@ class ContinuousBatchingEngine:
                     rows.append((s, req, offs[s]))
                     offs[s] = min(offs[s] + C, len(req.prompt))
                     progress = True
+        if self._seq_layers:
+            rows.sort(key=lambda row: (row[0], row[2]))
+            self.stats["seq_state_runs"] += self._seq_runs(rows)
         g = 1
         while g < len(rows):
             g *= 2
@@ -2545,6 +2567,28 @@ class ContinuousBatchingEngine:
                 if self.tracer is not None:
                     self.tracer.prefill_chunk(req.rid, t0_tr, offs[s] - nxt,
                                               tags=self.trace_tags)
+
+    def _seq_runs(self, rows) -> int:
+        """The runs of adjacent rows of one slot among a pack's rows
+        ``(slot, request, offset)`` for ``"seq"`` layers; PackOrderError
+        (PT-SRV-011) unless every slot has ONE run whose chunks follow one
+        another."""
+        runs, seen, prev = 0, set(), (None, None)
+        for s, req, off in rows:
+            if s != prev[0]:
+                runs += 1
+                ok = s not in seen
+                seen.add(s)
+            else:
+                ok = off == prev[1] + self._chunk_tokens
+            if not ok:
+                raise PackOrderError(
+                    f"PT-SRV-011: packed prefill of rid={req.rid} (slot {s}) "
+                    f"at offset {off} follows slot {prev[0]} at offset "
+                    f"{prev[1]}: the rows of a sequence whose layers keep "
+                    f"its state must be adjacent and rising")
+            prev = (s, off)
+        return runs
 
     def _first_token(self, ready):
         """Re-step the last REAL prompt token at its true position (k/v

@@ -261,9 +261,11 @@ class NemotronHMamba2(Layer):
     @_scope("pt.ssm")
     def paged_chunk(self, u, state, slots, starts, count):
         """u [b, s, hidden] at positions ``starts[b] + i`` of the sequences
-        in ``slots``; the first ``count[b]`` positions' state is kept. Rows
-        are taken in order (``ssd_scan_pooled``): a row resumes from what an
-        earlier row of its sequence left, in this very program."""
+        in ``slots``; the first ``count[b]`` positions' state is kept. The
+        rows of one sequence are adjacent and rising, as the engine orders
+        a pack (``ssd_scan_pooled``): a row resumes from what the row
+        before it left, in this very program, and a slot's matrix state is
+        read once and written back once a run of its rows."""
         from ...ops.paged_attention import SeqState
 
         u = _raw(u)

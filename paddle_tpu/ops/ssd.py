@@ -21,15 +21,16 @@ chunk of ``L`` positions,
 
 so inside a chunk the work is matmuls over ``[L, L]`` (``chunk_terms``),
 and between chunks only the state passes. ``ssd_scan`` passes it along each
-row of independent sequences; ``ssd_scan_pooled`` passes it through a pool
-``[slots, H, P, N]`` kept a SEQUENCE (``ops.paged_attention.SeqState``): the
-chunks of the serving engine's packed rows, each reading its slot's state
-and writing it back, in the order they are given, so that several chunks of
-one prompt in one call chain. ``ssd_step`` is the one-token form over the
-same pool. The cumulative sums, the decays and the state are float32
-whatever the model's type; the matmuls take ``x``'s type and accumulate in
-float32 (the state is rounded to it where it is a matmul's operand, as the
-published kernels do).
+row of independent sequences; ``ssd_scan_pooled`` serves a pool ``[slots, H,
+P, N]`` kept a SEQUENCE (``ops.paged_attention.SeqState``): the chunks of
+the serving engine's packed rows, the rows of one sequence adjacent and
+rising, so that several chunks of one prompt in one call chain through a
+carry of one sequence's state; a slot's state is gathered before the scan
+and the last of its run scattered back after it. ``ssd_step`` is the
+one-token form over the same pool. The cumulative sums, the decays and the
+state are float32 whatever the model's type; the matmuls take ``x``'s type
+and accumulate in float32 (the state is rounded to it where it is a
+matmul's operand, as the published kernels do).
 """
 
 from __future__ import annotations
@@ -134,36 +135,54 @@ def ssd_scan_pooled(pool, x, dt, A, B, C, D, slots, fresh, count,
     sequence whose state is ``pool[slots[r]]`` ([slots, H, P, N] float32),
     from zero where ``fresh[r]`` (the row starts its sequence), over its
     first ``count[r]`` positions (the caller has zeroed ``dt`` past them).
-    Rows are taken in order, each reading its slot's state and writing it
-    back, so two rows of one sequence chain when the earlier comes first. A
-    row with ``count == 0`` changes nothing, whatever its slot (the pack's
-    dummy rows). Returns ``y`` [b, s, H, P] and the pool."""
+    The rows of one sequence are ADJACENT and rising (the engine orders a
+    pack so and checks it, PT-SRV-011): a run of rows with one slot reads
+    the slot's state once, before the scan, passes it on from row to row
+    through a carry of ONE state, and the run's last row writes it back,
+    in one scatter after the scan. Two runs with one slot would both start
+    from what the pool held, and which of them is kept is undefined. A row
+    with ``count == 0`` passes on what it got, and a run of such rows only
+    (the pack's dummy rows, whatever their slot) writes nothing. Returns
+    ``y`` [b, s, H, P] and the pool."""
     b, s, h, p = x.shape
     l = min(chunk, s)
     if s % l:
         raise ValueError(f"a packed row of {s} positions is no whole number "
                          f"of chunks of {l}")
     nc = s // l
+    n_slots = pool.shape[0]
     with jax.named_scope("pt.ssm.scan"):
         y_diag, local, cum = chunk_terms(*(_chunked(a, l) for a in (x, dt)),
                                          A, *(_chunked(a, l) for a in (B, C)))
         total = jnp.exp(cum[:, -1])                               # [b*nc, H]
         first = jnp.arange(nc, dtype=jnp.int32)[None, :] * l     # [1, nc]
-        slot_c = jnp.repeat(jnp.clip(slots, 0, pool.shape[0] - 1), nc)
+        slot_c = jnp.repeat(jnp.clip(slots, 0, n_slots - 1), nc)
         fresh_c = (fresh[:, None] & (first == 0)).reshape(-1)
         live_c = (count[:, None] > first).reshape(-1)
+        edge = slot_c[1:] != slot_c[:-1]
+        head = jnp.concatenate([jnp.ones((1,), bool), edge])
+        last = jnp.concatenate([edge, jnp.ones((1,), bool)])
+        init = pool[slot_c]                                 # [b*nc, H, P, N]
 
-        def pass_on(pool, inp):
-            slot, is_fresh, live, keep, add = inp
-            old = jax.lax.dynamic_index_in_dim(pool, slot, 0, keepdims=False)
+        def pass_on(carry, inp):
+            state, wrote = carry
+            is_head, is_fresh, live, keep, add, own = inp
+            old = jnp.where(is_head, own, state)
             start = jnp.where(is_fresh, 0.0, old)
             new = jnp.where(live, keep[:, None, None] * start + add, old)
-            return (jax.lax.dynamic_update_index_in_dim(pool, new, slot, 0),
-                    start)
+            wrote = live | (wrote & ~is_head)
+            return (new, wrote), (start, new, wrote)
 
-        pool, s_in = jax.lax.scan(
-            pass_on, pool, (slot_c, fresh_c, live_c, total, local))
+        _, (s_in, new, wrote) = jax.lax.scan(
+            pass_on, (jnp.zeros(pool.shape[1:], pool.dtype), False),
+            (head, fresh_c, live_c, total, local, init))
+        pool = pool.at[jnp.where(last & wrote, slot_c, n_slots)].set(
+            new, mode="drop")
         y = chunk_out(y_diag, cum, _chunked(x, l), _chunked(C, l), D, s_in)
+        # the next layer waits for the scatter: left free, the v5e
+        # compiler puts every layer's at the program's end and keeps their
+        # stacked states till then (3.6 GB of temporaries for 1.0, 23 layers)
+        y, pool = jax.lax.optimization_barrier((y, pool))
     return y.reshape(b, s, h, p), pool
 
 

@@ -259,33 +259,70 @@ def test_padded_tail_with_dt_zero_leaves_the_state():
     assert np.abs(np.asarray(final[0]) - want).max() < 2e-5
 
 
-def test_pooled_scan_chains_rows_and_spares_the_rest():
-    """The packed chunk over a pool of 4 slots: rows 0 and 2 are two chunks
-    of one sequence in slot 3 (the second resumes from the first, in this
-    call), row 1 continues slot 1 from its state over 5 of its 16
-    positions, row 3 is a dummy (count 0, slot out of range). Slots 0 and 2
-    come back untouched."""
-    a = _draw(4, 16, 7)
-    rng = np.random.default_rng(1)
-    pool = jnp.asarray(rng.normal(size=(4, H, P, N)), jnp.float32)
-    slots = jnp.asarray([3, 1, 3, 4], jnp.int32)
-    fresh = jnp.asarray([True, False, False, True])
-    count = jnp.asarray([16, 5, 16, 0], jnp.int32)
+# a pack the engine can send, a row (slot, fresh, count of 16 positions),
+# over a pool of 5 slots (slot 5: a dummy row, out of range)
+_PACKS = {
+    "a-run-of-one-row": [(2, False, 16)],
+    "a-run-of-two-rows": [(3, True, 16), (3, False, 16)],
+    "a-run-of-three-rows": [(0, False, 16), (0, False, 16), (0, False, 7)],
+    "middle-row-empty": [(1, True, 16), (1, False, 0), (1, False, 16)],
+    # a chunk that holds only the prompt's last token keeps none of it
+    "last-row-empty": [(1, True, 16), (1, False, 16), (1, False, 0)],
+    "empty-row-alone-in-its-run": [(0, False, 16), (2, True, 0),
+                                   (4, False, 9)],
+    "fresh-run-beside-one-that-resumes": [(1, True, 16), (1, False, 16),
+                                          (3, False, 16), (3, False, 5)],
+    "dummy-rows-last": [(3, True, 16), (3, False, 16), (1, False, 5),
+                        (5, True, 0)],
+    "dummy-rows-after-the-last-slot": [(4, False, 16), (4, False, 3),
+                                       (5, True, 0), (5, True, 0)],
+    # a dummy row's slot is clipped to the last one: it must not write that
+    # slot's old state over what the live run left there
+    "last-slot-first-dummy-rows-last": [(4, False, 16), (1, False, 16),
+                                        (5, True, 0)],
+    "every-slot-a-run": [(0, True, 16), (1, False, 11), (2, False, 16),
+                         (3, True, 1), (4, False, 16)],
+}
+
+
+@pytest.mark.parametrize("chunk", [16, 8], ids=["one-chunk-a-row",
+                                                "two-chunks-a-row"])
+@pytest.mark.parametrize("pack", list(_PACKS), ids=list(_PACKS))
+def test_pooled_scan_chains_rows_and_spares_the_rest(pack, chunk):
+    """The packed chunk over a pool of 5 slots, the rows BY SEQUENCE (the
+    rows of one slot adjacent, as the engine orders them): a run's first row
+    starts from the slot's state, or from zero where it is fresh, each next
+    row from what the one before it left, in this call, and the run's last
+    state is the slot's; a row with no kept position passes on what it got,
+    and a run of such rows writes nothing. ``y`` and the states against the
+    recurrence a position at a time; every slot no live row names comes
+    back to the bit."""
+    rows = _PACKS[pack]
+    a = _draw(len(rows), 16, 7)
+    pool = jnp.asarray(np.random.default_rng(1).normal(size=(5, H, P, N)),
+                       jnp.float32)
+    slots, fresh, count = (jnp.asarray(v) for v in zip(*rows))
+    count = count.astype(jnp.int32)
     dt = jnp.where(jnp.arange(16)[None, :, None] < count[:, None, None],
                    a["dt"], 0.0)
     y, new = ssd.ssd_scan_pooled(pool, a["x"], dt, a["A"], a["B"], a["C"],
-                                 a["D"], slots, fresh, count, chunk=8)
-    one = lambda i, init, n=16: _recurrence(
-        a["x"][i, :n], a["dt"][i, :n], a["A"], a["B"][i, :n], a["C"][i, :n],
-        a["D"], init)
-    y0, s0 = one(0, np.zeros((H, P, N)))
-    y2, s2 = one(2, s0)
-    y1, s1 = one(1, np.asarray(pool[1]), 5)
-    for got, want in ((y[0], y0), (y[2], y2), (y[1, :5], y1),
-                      (new[3], s2), (new[1], s1)):
-        assert np.abs(np.asarray(got) - want).max() < 5e-5
-    np.testing.assert_array_equal(np.asarray(new)[[0, 2]],
-                                  np.asarray(pool)[[0, 2]])
+                                 a["D"], slots.astype(jnp.int32), fresh,
+                                 count, chunk=chunk)
+    want = {}
+    for i, (slot, is_fresh, n) in enumerate(rows):
+        if not n:
+            continue
+        start = (np.zeros((H, P, N)) if is_fresh else
+                 want.get(slot, np.asarray(pool[slot])))
+        want_y, want[slot] = _recurrence(
+            a["x"][i, :n], a["dt"][i, :n], a["A"], a["B"][i, :n],
+            a["C"][i, :n], a["D"], start)
+        assert np.abs(np.asarray(y[i, :n]) - want_y).max() < 5e-5
+    for slot, state in want.items():
+        assert np.abs(np.asarray(new[slot]) - state).max() < 5e-5
+    spared = [i for i in range(5) if i not in want]
+    np.testing.assert_array_equal(np.asarray(new)[spared],
+                                  np.asarray(pool)[spared])
 
 
 @pytest.mark.parametrize("by", ["live", "slots"])

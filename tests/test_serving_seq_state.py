@@ -11,8 +11,8 @@ import numpy as np
 import pytest
 
 from _nemotron_h_util import (engine, reference_logits, seeded_model, serve)
-from paddle_tpu.inference.serving import (LayerStateError, PrefixCacheConfig,
-                                          Request)
+from paddle_tpu.inference.serving import (LayerStateError, PackOrderError,
+                                          PrefixCacheConfig, Request)
 from paddle_tpu.ops.paged_attention import SeqState, layer_kinds
 
 PAGE = 4
@@ -176,6 +176,93 @@ def test_long_prompts_over_several_ticks_stream_the_references(nemo, chunk):
     for r, out in zip(reqs, serve(eng, reqs)):
         _is_the_references(r.prompt, out, top, layer)
     assert eng.stats["seq_state_starts"] == 2
+
+
+def _packs_seen(eng):
+    """Spy on the packed chunk's calls: a list a call of (starts [g], the
+    rows' slots [g] or None for a model without "seq" layers)."""
+    seen, call = [], eng._call_built
+
+    def spy(name, key, fn, *args, **kw):
+        if name == "pt_prefill_chunk":
+            # (params, ids, kv, rows, starts, ..., the rows' slots, kept)
+            seen.append((np.asarray(args[4]).tolist(),
+                         np.asarray(args[-2]).tolist()
+                         if eng._seq_layers else None))
+        return call(name, key, fn, *args, **kw)
+
+    eng._call_built = spy
+    return seen
+
+
+_ONE_PACK = PrefixCacheConfig(extra_blocks=8, prefill_chunk=32, pack_rows=5)
+
+
+def test_one_packs_rows_reach_the_model_by_sequence(nemo):
+    """Prompts of three and of two chunks of 32 prefilled in ONE pack of five
+    rows: picked breadth-first, handed over by (slot, offset), the three
+    dummy rows of the bucket of 8 last; two runs, a state gathered for each;
+    the greedy streams are the reference's."""
+    model, top, layer = nemo
+    reqs = [_greedy(_ids(80, 30), 6), _greedy(_ids(50, 31), 6)]
+    eng = _big(model, prefix_cache=_ONE_PACK)
+    seen = _packs_seen(eng)
+    for r, out in zip(reqs, serve(eng, reqs)):
+        _is_the_references(r.prompt, out, top, layer)
+    assert seen == [([0, 32, 64, 0, 32, 0, 0, 0],
+                     [0, 0, 0, 1, 1] + [eng.max_batch] * 3)]
+    assert eng.stats["packed_rows"] == 5
+    assert eng.stats["seq_state_runs"] == 2
+    assert eng.stats["seq_state_starts"] == 2
+
+
+def test_runs_count_a_sequence_once_a_pack_over_several_packs(nemo):
+    """A budget of three rows a pack: the prompts of three and two chunks
+    take two packs, (slot 0 x 2, slot 1) then (slot 0, slot 1): four runs for
+    five rows, each pack by sequence."""
+    model, top, layer = nemo
+    reqs = [_greedy(_ids(80, 30), 6), _greedy(_ids(50, 31), 6)]
+    eng = _big(model, prefix_cache=PrefixCacheConfig(
+        extra_blocks=8, prefill_chunk=32, pack_rows=3))
+    seen = _packs_seen(eng)
+    for r, out in zip(reqs, serve(eng, reqs)):
+        _is_the_references(r.prompt, out, top, layer)
+    assert seen == [([0, 32, 0, 0], [0, 0, 1, eng.max_batch]),
+                    ([64, 32], [0, 1])]
+    assert eng.stats["packed_rows"] == 5
+    assert eng.stats["seq_state_runs"] == 4
+
+
+def test_a_model_without_such_layers_gets_the_rows_breadth_first():
+    """The same two prompts on a llama: no layer of kind "seq", so the pack
+    keeps the order it was picked in, one chunk a slot a pass, and counts no
+    run."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    paddle.seed(11)
+    cfg = LlamaConfig.tiny(num_hidden_layers=2)
+    eng = engine(LlamaForCausalLM(cfg), max_len=128, prefix_cache=_ONE_PACK)
+    seen = _packs_seen(eng)
+    out = serve(eng, [_greedy(_ids(80, 30), 4), _greedy(_ids(50, 31), 4)])
+    assert all(len(o) == 4 for o in out)
+    assert seen == [([0, 0, 32, 32, 64, 0, 0, 0], None)]
+    assert eng.stats["packed_rows"] == 5
+    assert eng.stats["seq_state_runs"] == 0
+
+
+@pytest.mark.parametrize("rows,at", [
+    ([(0, 0), (1, 0), (0, 32)], "slot 0"),       # the breadth-first pick
+    ([(2, 32), (2, 0)], "slot 2"), ([(2, 0), (2, 64)], "slot 2")],
+    ids=["a-slot-in-two-runs", "a-run-that-falls", "a-run-with-a-gap"])
+def test_rows_out_of_order_fail_by_name(nemo, rows, at):
+    """The engine checks on the host, before the call, what the op cannot
+    raise on: a slot's rows adjacent, each where the one before it ended."""
+    eng = _big(nemo[0], prefix_cache=_ONE_PACK)
+    req = _greedy(_ids(80, 30), 6)
+    with pytest.raises(PackOrderError, match=f"PT-SRV-011.*{at}.*adjacent "
+                                             f"and rising"):
+        eng._seq_runs([(slot, req, off) for slot, off in rows])
 
 
 def test_a_slot_reused_by_a_second_request_starts_afresh(nemo):

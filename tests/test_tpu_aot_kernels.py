@@ -242,6 +242,88 @@ def test_ssd_packed_chunk_compiles_for_v5e_with_the_pool_in_place(one_chip):
     assert ma.temp_size_in_bytes < 1.2e9
 
 
+def _while_bodies(hlo):
+    """{name: lines} of the computations some ``while`` of the compiled text
+    names as its body."""
+    import re
+
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", hlo))
+    assert bodies and bodies <= set(comps)
+    return {b: comps[b] for b in bodies}
+
+
+@pytest.mark.parametrize("rows", [8, 32])
+def test_ssd_packed_chunk_in_a_deep_program_copies_no_pool_in_a_loop(
+        rows, one_chip):
+    """What the parent's op did only INSIDE the cell's chunk program: the
+    v5e compiler moved the layer's whole pool, the carry of the scan over
+    the pack's rows, into its second memory space and back in every
+    iteration (``copy f32[32,64,64,128]{..S(1)}`` and a ``copy`` of that, a
+    Mamba layer, in the ``while`` body: 96 us each on the chip, PERF.md
+    section 6, PR 37). Alone the op never copied, nor did two mixers with
+    a matmul between, nor twelve (compiled here for the described v5e, PR
+    37): the cell's 23 Mamba-2 mixers' ``paged_chunk`` at its widths, each
+    on a pool of its own with a matmul between, reproduce the parent's 46
+    copies at 8 and at 32 rows; one mixer's weights serve all 23, so that
+    nothing of 1.8 GB is drawn here. With one sequence's state as the carry
+    no ``while`` body holds a ``copy`` or ``copy-start`` of the pool's
+    dimensions (at 32 rows the stacked states have them too), and the
+    pools still come back in place."""
+    import re
+
+    from paddle_tpu.jit.api import _Swap, _collect_state
+    from paddle_tpu.models.nemotron_h.modeling import (NemotronHConfig,
+                                                       NemotronHMamba2)
+    from paddle_tpu.ops.paged_attention import SeqState
+
+    layers, slots = 23, 32
+    cfg = NemotronHConfig(hybrid_override_pattern="M")
+    mixer = NemotronHMamba2(cfg)
+    _, tensors = _collect_state(mixer)
+    sds = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    pool = (slots, cfg.mamba_num_heads, cfg.mamba_head_dim,
+            cfg.ssm_state_size)
+    state = SeqState(sds(pool, jnp.float32),
+                     sds((slots, cfg.conv_kernel - 1, cfg.conv_width),
+                         jnp.bfloat16))
+
+    def program(params, u, w, states, seq_slots, starts, count):
+        with _Swap(tensors, params):
+            out = []
+            for st in states:
+                y, st = mixer.paged_chunk(u, st, seq_slots, starts, count)
+                u = u + jnp.matmul(y, w)
+                out.append(st)
+        return u, out
+
+    vec = sds((rows,), jnp.int32)
+    compiled = jax.jit(program, donate_argnums=(3,)).trace(
+        [sds(t._data.shape, t._data.dtype) for t in tensors],
+        sds((rows, cfg.chunk_size, cfg.hidden_size), jnp.bfloat16),
+        sds((cfg.hidden_size, cfg.hidden_size), jnp.bfloat16),
+        [state] * layers, vec, vec, vec).lower(
+        lowering_platforms=("tpu",)).compile()
+    dims = ",".join(map(str, pool))
+    moved = re.compile(rf"= \(?f32\[{dims}\]\S* .*?\b(copy|copy-start)\(")
+    copies = [line.strip()[:120]
+              for body in _while_bodies(compiled.as_text()).values()
+              for line in body if moved.search(line.split(", metadata")[0])]
+    assert not copies, copies
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= layers * int(np.prod(pool)) * 4
+    assert ma.temp_size_in_bytes < 0.7e9
+
+
 @pytest.mark.parametrize("by", ["live", "slots"])
 def test_ssd_step_compiles_for_v5e_and_updates_the_pool_in_place(by,
                                                                  one_chip):
