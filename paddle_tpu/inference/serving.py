@@ -166,20 +166,184 @@ def __getattr__(name):
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
+_CALLER = "starved_caller_s"
+_PHASES = ("starved_emit_s", "starved_admit_s", "starved_prefill_s",
+           "starved_dispatch_s", _CALLER)
+# the pt.serve.* spans that own a phase; between a step's children (and in
+# pt.serve.wait / call / build, which own none) the enclosing phase goes on,
+# and step() itself starts in the dispatch phase
+_PHASE_OF = {"serve.emit": "starved_emit_s", "serve.admit": "starved_admit_s",
+             "serve.prefill": "starved_prefill_s",
+             "serve.decode.dispatch": "starved_dispatch_s"}
+_LONG_S = 1.0       # a step, or a caller's interval, past this is a stall
+
+
+class _InFlight:
+    """The engine's ledger of what it has in flight on the device.
+
+    Every program call takes the next number (``called``); a value the host
+    later blocks on remembers the number of the call that made it. One chip
+    runs its programs in order, so when the read of call ``n`` returns,
+    every call up to ``n`` has finished (``done``), and when
+    ``done == called`` the device is EMPTY: the engine stamps that instant
+    (``stats["drains"]``). From the stamp to the start of the next call the
+    device is *starved*: that time is added, split at the boundaries of the
+    ``pt.serve.*`` spans, to the one of ``_PHASES`` the host was in, and
+    ``device_starved_s`` is kept as their sum. A wait on an older value
+    stamps nothing, and on the path without an eos id nothing is read back,
+    so the counters stand still there.
+
+    The blind spot as a number: a call whose predecessor's output is
+    already there (``is_ready()``, no blocking) with no drain stamped since
+    adds the time since that predecessor's call to
+    ``device_maybe_starved_s``: the device was empty for an unknown part of
+    it. ``device_starved_s`` is a floor of the idle time the host is at
+    fault for, and the sum of the two a ceiling.
+
+    An interval outside ``step()`` longer than ``_LONG_S`` (a profiler
+    writing its trace out, a caller that went away) goes to
+    ``caller_over_1s`` / ``caller_over_1s_s`` and into neither. An engine
+    left without work is not starved: the stamp is dropped. Every time
+    handed in is ``time.perf_counter``'s; only program calls the engine
+    makes are seen, on one chip (docs/OBSERVABILITY.md "What the device
+    waits for")."""
+
+    __slots__ = ("stats", "called", "done", "mark", "phase", "out",
+                 "out_at", "left_at")
+
+    def __init__(self, stats: dict):
+        self.stats = stats
+        self.called = self.done = 0
+        self.mark = None        # since when the device is known empty
+        self.phase = _CALLER
+        self.out = None         # the newest call's output, and its time
+        self.out_at = 0.0
+        self.left_at = None     # when step() last returned with work left
+        stats.update(dict.fromkeys(_PHASES, 0.0), device_starved_s=0.0,
+                     device_maybe_starved_s=0.0, drains=0,
+                     caller_over_1s=0, caller_over_1s_s=0.0)
+
+    def upto(self, now: float):
+        """Bring the starved counters up to ``now``."""
+        if self.mark is None:
+            return
+        st = self.stats
+        st[self.phase] += now - self.mark
+        self.mark = now
+        st["device_starved_s"] = (
+            st["starved_emit_s"] + st["starved_admit_s"]
+            + st["starved_prefill_s"] + st["starved_dispatch_s"]
+            + st[_CALLER])
+
+    def enter(self, phase: str, now: float) -> str:
+        """The host passes into ``phase`` at ``now``; returns the one it
+        left."""
+        self.upto(now)
+        left, self.phase = self.phase, phase
+        return left
+
+    def call(self, now: float):
+        """A program call starts at ``now``."""
+        if self.mark is not None:
+            self.upto(now)
+            self.mark = None
+        elif self.out is not None:
+            # one execution makes all of a call's outputs: any leaf says
+            leaf = self.out
+            while isinstance(leaf, (tuple, list)) and leaf:
+                leaf = leaf[0]
+            if not hasattr(leaf, "is_ready"):
+                leaf = next(iter(jax.tree_util.tree_leaves(leaf)), None)
+            if (hasattr(leaf, "is_ready") and not leaf.is_deleted()
+                    and leaf.is_ready()):
+                self.stats["device_maybe_starved_s"] += now - self.out_at
+        self.called += 1
+        self.out, self.out_at = None, now
+
+    def read(self, seq: int, now: float):
+        """The host's read of a value of call ``seq`` returned at ``now``."""
+        if seq > self.done:
+            self.done = seq
+        if self.done == self.called and self.mark is None:
+            self.mark = now
+            self.stats["drains"] += 1
+
+    def step_begins(self, now: float):
+        if self.left_at is None:
+            # the engine had no work: what it called since (finished()'s
+            # releases) and how long ago says nothing of a starved device
+            self.mark = self.out = None
+        elif now - self.left_at > _LONG_S:
+            self.stats["caller_over_1s"] += 1
+            self.stats["caller_over_1s_s"] += now - self.left_at
+            if self.mark is not None:
+                self.mark = now
+            # what of that interval lies after the newest call (finished()
+            # may have called inside it) is not maybe-starved time either
+            self.out_at += now - max(self.out_at, self.left_at)
+        self.enter("starved_dispatch_s", now)
+
+    def step_ends(self, now: float, has_work: bool):
+        self.enter(_CALLER, now)
+        self.left_at = now if has_work else None
+
+
+class _phase_span(program_span):
+    """A ``pt.serve.*`` span that owns a phase of the in-flight ledger."""
+
+    __slots__ = ("_flight", "_phase")
+
+    def __init__(self, engine, name: str, **args):
+        super().__init__(name, engine.tracer, engine.trace_tags, **args)
+        self._flight, self._phase = engine._flight, _PHASE_OF[name]
+
+    def __enter__(self):
+        super().__enter__()
+        self._phase = self._flight.enter(self._phase, self._t0)
+        return self
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        self._flight.enter(self._phase, self._t0 + self.elapsed_s)
+        return False
+
+
+class _call_span(program_span):
+    """``pt.serve.call``: one call of a jitted program, a leaf. ``seq`` is
+    the call's number in the in-flight ledger, ``drained`` whether the
+    device was known empty when it started."""
+
+    __slots__ = ("_flight",)
+
+    def __init__(self, engine, program: str, key: str):
+        fl = self._flight = engine._flight
+        super().__init__("serve.call", engine.tracer, engine.trace_tags,
+                         program=program, seq=fl.called + 1,
+                         drained=int(fl.mark is not None), key=key)
+
+    def __enter__(self):
+        super().__enter__()
+        self._flight.call(self._t0)
+        return self
+
+
 class _wait_span(program_span):
-    """``pt.serve.wait``: the host blocks on device values. Its wall time is
-    the engine's ``stats["device_wait_s"]``."""
+    """``pt.serve.wait``: the host blocks on a device value of call ``seq``.
+    Its wall time is the engine's ``stats["device_wait_s"]``; its end tells
+    the in-flight ledger that every call up to ``seq`` has finished."""
 
-    __slots__ = ("_stats",)
+    __slots__ = ("_stats", "_flight", "_seq")
 
-    def __init__(self, engine, what: str):
+    def __init__(self, engine, what: str, seq: int):
         super().__init__("serve.wait", engine.tracer, engine.trace_tags,
-                         what=what)
-        self._stats = engine.stats
+                         what=what, seq=seq)
+        self._stats, self._flight, self._seq = (engine.stats, engine._flight,
+                                                seq)
 
     def __exit__(self, *exc):
         super().__exit__(*exc)
         self._stats["device_wait_s"] += self.elapsed_s
+        self._flight.read(self._seq, self._t0 + self.elapsed_s)
         return False
 
 
@@ -897,9 +1061,20 @@ class ContinuousBatchingEngine:
         # filled lazily as each sharded program first dispatches — feeds
         # the serving collector and mirrors the PT-COMM contract entries
         self._mesh_programs: Dict[str, float] = {}
-        # (program, key) of every program variant called so far: a first
-        # call goes under ``pt.serve.build`` (_call_built)
-        self._built = set()
+        # (program, key) of every program variant called so far, with the
+        # key as ``pt.serve.call`` writes it: a first call goes under
+        # ``pt.serve.build`` (_call_built)
+        self._built: Dict[tuple, str] = {}
+        # what is in flight on the device, and the counters of the time it
+        # is starved (_InFlight); the steps and the caller's intervals past
+        # _LONG_S beside them: a step that built no program and took
+        # longer, with its wall, its wait and its starved time, so that a
+        # window's difference of stats says whether it held a stall and
+        # whether the device or the host held it
+        self._flight = _InFlight(self.stats)
+        self.stats.update(steps_over_1s=0, steps_over_1s_wall_s=0.0,
+                          steps_over_1s_wait_s=0.0,
+                          steps_over_1s_starved_s=0.0)
         # terminal stamps of requests whose token values are still on the
         # device (the path without eos): _drain_pending writes them once
         # the values are on the host
@@ -960,20 +1135,32 @@ class ContinuousBatchingEngine:
 
     def _span(self, name: str, **args):
         """A ``pt.<name>`` program span of this engine
-        (observability/tracing.py ``program_span``)."""
+        (observability/tracing.py ``program_span``); those of ``_PHASE_OF``
+        tell the in-flight ledger which phase the host is in."""
+        if name in _PHASE_OF:
+            return _phase_span(self, name, **args)
         return program_span(name, self.tracer, self.trace_tags, **args)
 
     def _call_built(self, program: str, key, fn, *args, **kw):
-        """Call a jitted program. The first call of each (program, key) —
-        a trace and a compile or a cache load — runs under
+        """Call a jitted program, under ``pt.serve.call`` (the in-flight
+        ledger numbers it). The first call of each (program, key) — a
+        trace and a compile or a cache load — runs under
         ``pt.serve.build`` and counts in ``stats["programs_built"]``."""
         k = (program, key)
-        if k in self._built:
-            return fn(*args, **kw)
-        with self._span("serve.build", program=program, key=str(key)):
-            out = first_call(fn, *args, **kw)
-        self._built.add(k)
-        self.stats["programs_built"] += 1
+        label = self._built.get(k)
+        if label is not None:
+            with _call_span(self, program, label):
+                out = fn(*args, **kw)
+        else:
+            # no comma in a span's argument: the profiler splits on them
+            label = "/".join(map(str, key)) if isinstance(key, tuple) \
+                else str(key)
+            with self._span("serve.build", program=program, key=str(key)):
+                with _call_span(self, program, label):
+                    out = first_call(fn, *args, **kw)
+            self._built[k] = label
+            self.stats["programs_built"] += 1
+        self._flight.out = out
         return out
 
     # ---- public API ----
@@ -1102,6 +1289,7 @@ class ContinuousBatchingEngine:
 
             self._fault_hook = maybe_inject
             self._device_loss_hook = device_loss
+        t0 = _time.perf_counter()       # a stalled step's sleep is its own
         self._step_idx += 1
         # injection sites (docs/RESILIENCE.md): `serving.stall` sleeps the
         # step past its wall-clock budget (StepWatchdog / PT-SRV-002);
@@ -1121,20 +1309,41 @@ class ContinuousBatchingEngine:
                 f"at step {self._step_idx} ({survivors} surviving) — "
                 f"engine must reshard to a narrower mesh",
                 lost=lost, survivors=survivors)
-        stats = self.stats
+        stats, flight = self.stats, self._flight
+        flight.step_begins(t0)
         wait0, built0 = stats["device_wait_s"], stats["programs_built"]
-        t0 = _time.perf_counter()
+        starved0 = stats["device_starved_s"]
+        maybe0 = stats["device_maybe_starved_s"]
         sched0 = self._sched_tokens
         self._deferred_step = False
         try:
             with self._span("serve.step", step=self._step_idx,
                             occupied=len(self._occupied),
-                            queued=len(self._queue)):
-                self._step_inner()
+                            queued=len(self._queue)) as sp:
+                try:
+                    self._step_inner()
+                finally:
+                    flight.upto(_time.perf_counter())
+                    sp.set(
+                        starved_us=round(
+                            1e6 * (stats["device_starved_s"] - starved0)),
+                        maybe_starved_us=round(
+                            1e6 * (stats["device_maybe_starved_s"] - maybe0)),
+                        wait_us=round(
+                            1e6 * (stats["device_wait_s"] - wait0)))
         finally:
-            dt = _time.perf_counter() - t0
+            t1 = _time.perf_counter()
+            dt = t1 - t0
+            flight.step_ends(t1, self.has_work())
             stats["steps"] += 1
             stats["step_wall_s"] += dt
+            if dt > _LONG_S and stats["programs_built"] == built0:
+                stats["steps_over_1s"] += 1
+                stats["steps_over_1s_wall_s"] += dt
+                stats["steps_over_1s_wait_s"] += \
+                    stats["device_wait_s"] - wait0
+                stats["steps_over_1s_starved_s"] += \
+                    stats["device_starved_s"] - starved0
             if dt > stats["step_max_s"] and \
                     stats["programs_built"] == built0:
                 stats["step_max_s"] = dt
@@ -1290,12 +1499,13 @@ class ContinuousBatchingEngine:
             if not spec:
                 n, async_ok, do_sample = self._block_plan(live)
                 out = self._dispatch_block(live, n, do_sample)
+                seq = self._flight.called
                 sp.set(n_steps=n, rows=len(live), do_sample=do_sample)
                 self.stats["decode_blocks"] += 1
                 self.stats["decode_block_steps"] += n
         if spec:
             return self._decode_spec_block(live)
-        self._book_block(live, n, async_ok, out)
+        self._book_block(live, n, async_ok, out, seq)
 
     def _block_plan(self, live):
         """(scan length, whether no row carries an eos id, whether any row
@@ -1371,10 +1581,11 @@ class ContinuousBatchingEngine:
                 st["moe_layer_steps"] += int(a.shape[0] * a.shape[1])
                 st["moe_rows_max_expert"] += int(a.max(-1).sum())
 
-    def _book_block(self, live, n: int, async_ok: bool, out):
-        """Book a dispatched block's tokens: by the schedule alone where no
-        row carries an eos id (values stay on the device until
-        ``_drain_pending``), else from the values, read back here."""
+    def _book_block(self, live, n: int, async_ok: bool, out, seq: int):
+        """Book a dispatched block's tokens (``out``, of call ``seq``): by
+        the schedule alone where no row carries an eos id (values stay on
+        the device until ``_drain_pending``), else from the values, read
+        back here."""
         if async_ok:
             entries = []
             tok_marks = [] if self.tracer is not None else None
@@ -1397,18 +1608,18 @@ class ContinuousBatchingEngine:
                        scheduled=True)
             # the rows' token progress is stamped when the values reach the
             # host (_drain_pending), never at dispatch
-            self._pending.append((out, entries, tok_marks, False))
+            self._pending.append((out, entries, tok_marks, False, seq))
             return
         # eos path: materialize (in generation order — drain older pendings
         # first so req.output stays ordered across an async->sync transition)
         self._drain_pending()
-        with _wait_span(self, "decode_block"):
+        with _wait_span(self, "decode_block", seq):
             out = np.asarray(out)
-        if out.shape[0] > self.max_batch:
-            self._book_counters(out[self.max_batch:])
         tok_marks = [] if self.tracer is not None else None
         block_tokens = 0
         with self._span("serve.emit") as sp:
+            if out.shape[0] > self.max_batch:
+                self._book_counters(out[self.max_batch:])
             finished = 0
             for i, req in live:
                 took = 0
@@ -1584,7 +1795,8 @@ class ContinuousBatchingEngine:
         self._pos[slot] = int(pos)
         # control-plane eager scatter: the decode chain reads the carry
         # from device state, and migration happens once per request
-        self._last_tok = self._last_tok.at[slot].set(
+        self._flight.call(_time.perf_counter())    # an eager program
+        self._last_tok = self._flight.out = self._last_tok.at[slot].set(
             jnp.int32(int(last_tok)))
         # spec engines re-seed the drafter ring with prompt + delivered
         # tokens (minus the last-token carry restored above) so the
@@ -1615,8 +1827,8 @@ class ContinuousBatchingEngine:
             except AttributeError:
                 pass
         tracer = self.tracer
-        for arr_dev, entries, marks, first in self._pending:
-            with _wait_span(self, "pending"):
+        for arr_dev, entries, marks, first, seq in self._pending:
+            with _wait_span(self, "pending", seq):
                 arr = np.asarray(arr_dev)
             if arr.ndim == 2 and arr.shape[0] > self.max_batch:
                 self._book_counters(arr[self.max_batch:])
@@ -1742,9 +1954,10 @@ class ContinuousBatchingEngine:
                 utops[j] = top_p
                 utopks[j] = top_k
             seeds_d, temps_d, tops_d, topks_d = self._dev_samp
-            hist_d = self._dev_hist if with_spec else jnp.zeros((1, 1),
-                                                                jnp.int32)
-            hlen_d = self._dev_hlen if with_spec else jnp.zeros(1, jnp.int32)
+            # without a drafter: host dummies (an eager jnp.zeros would be a
+            # device program of its own a flush, unseen by the ledger)
+            hist_d = self._dev_hist if with_spec else uhist
+            hlen_d = self._dev_hlen if with_spec else uhlen
             tables, self._dev_pos, self._dev_act, s, t, p, k, hist_d, \
                 hlen_d = self._call_built(
                     "pt_slot_update", (), self._jit_apply,
@@ -2081,7 +2294,8 @@ class ContinuousBatchingEngine:
                 self.caches["tables"], self._dev_pos, self._dev_act,
                 self._dev_hist, self._dev_hlen, jnp.asarray(caps))
             self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
-        with _wait_span(self, "spec_emit"):
+            seq = self._flight.called
+        with _wait_span(self, "spec_emit", seq):
             emit = np.asarray(emit_dev)     # the one sync read ([B] int32)
         # proposal counter derives from the already-synced emit vector —
         # never a second device readback per dispatch (each one stalls
@@ -2095,7 +2309,7 @@ class ContinuousBatchingEngine:
         if any_eos:
             # materialize in generation order (drain older pendings first)
             self._drain_pending()
-            with _wait_span(self, "spec_block"):
+            with _wait_span(self, "spec_block", seq):
                 out = np.asarray(out_dev)
         entries = []
         tok_marks = [] if self.tracer is not None else None
@@ -2108,7 +2322,7 @@ class ContinuousBatchingEngine:
             # here only when the values are on the host
             self.tracer.tokens_batch(tok_marks, tags=self.trace_tags)
         if entries:
-            self._pending.append((out_dev, entries, tok_marks, False))
+            self._pending.append((out_dev, entries, tok_marks, False, seq))
 
     def _book_spec(self, live, emit, out, entries, tok_marks) -> int:
         """Book one speculative dispatch's per-row emission; returns the
@@ -2655,19 +2869,21 @@ class ContinuousBatchingEngine:
             jnp.asarray(rows), self._last_tok, jnp.asarray(ints),
             jnp.asarray(floats))
         self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
+        seq = self._flight.called
         any_eos = any(r.eos_token_id is not None for _, r in ready)
         firsts = None
         if any_eos:
-            with _wait_span(self, "first_token"):
+            with _wait_span(self, "first_token", seq):
                 firsts = np.asarray(firsts_dev)
         with self._span("serve.emit", tokens=len(ready)) as sp:
-            self._emit_first(ready, firsts, firsts_dev)
+            self._emit_first(ready, firsts, firsts_dev, seq)
             sp.set(finished=sum(1 for _, r in ready if r.done))
 
-    def _emit_first(self, ready, firsts, firsts_dev):
-        """Book an admission wave's first tokens (values in ``firsts`` when
-        an eos id made the engine read them, else still on the device),
-        register the prompts' blocks and promote the slots to decoding."""
+    def _emit_first(self, ready, firsts, firsts_dev, seq: int):
+        """Book an admission wave's first tokens, of call ``seq`` (values
+        in ``firsts`` when an eos id made the engine read them, else still
+        on the device), register the prompts' blocks and promote the slots
+        to decoding."""
         entries = []
         ft_marks = [] if self.tracer is not None else None
         for row, (slot, req) in enumerate(ready):
@@ -2713,7 +2929,7 @@ class ContinuousBatchingEngine:
                 self._mark_done(req)
                 self._release_slot(slot)
         if entries:
-            self._pending.append((firsts_dev, entries, ft_marks, True))
+            self._pending.append((firsts_dev, entries, ft_marks, True, seq))
 
     def _admit_legacy(self):
         """Admit queued requests into free slots — ONE batched prefill call
@@ -2751,18 +2967,19 @@ class ContinuousBatchingEngine:
             with self._span("serve.prefill", tokens=padded * len(grp),
                             rows=len(grp)):
                 firsts_dev = self._prefill_group(padded, grp)
+                seq = self._flight.called
             firsts = None
             if any(r.eos_token_id is not None for _, r in grp):
-                with _wait_span(self, "first_token"):
+                with _wait_span(self, "first_token", seq):
                     firsts = np.asarray(firsts_dev)
             with self._span("serve.emit", tokens=len(grp)) as sp:
-                self._emit_group(grp, firsts, firsts_dev)
+                self._emit_group(grp, firsts, firsts_dev, seq)
                 sp.set(finished=sum(1 for _, r in grp if r.done))
 
-    def _emit_group(self, grp, firsts, firsts_dev):
-        """Book a legacy admission group's first tokens and occupy its
-        slots (``firsts``: the values when an eos id made the engine read
-        them, else None — they stay on the device)."""
+    def _emit_group(self, grp, firsts, firsts_dev, seq: int):
+        """Book a legacy admission group's first tokens, of call ``seq``,
+        and occupy its slots (``firsts``: the values when an eos id made
+        the engine read them, else None — they stay on the device)."""
         entries = []
         ft_marks = [] if self.tracer is not None else None
         for row, (slot, req) in enumerate(grp):
@@ -2802,7 +3019,7 @@ class ContinuousBatchingEngine:
                 self._mark_done(req)
                 self._release_slot(slot)
         if entries:
-            self._pending.append((firsts_dev, entries, ft_marks, True))
+            self._pending.append((firsts_dev, entries, ft_marks, True, seq))
 
     def _bucket(self, n: int) -> int:
         if not self.prompt_buckets:

@@ -541,9 +541,10 @@ _open = threading.local()      # per thread: names of the open program spans
 class program_span:
     """``with program_span("serve.wait", tracer, tags, what="decode"):``
     writes the span ``pt.serve.wait`` twice: as a
-    ``jax.profiler.TraceAnnotation`` (host plane of a profiler trace,
-    aligned with the device plane; an inactive ``TraceMe`` when no profiler
-    session is open) and, when ``recorder`` is a :class:`TraceRecorder`, on
+    ``jax.profiler.TraceAnnotation`` (host plane of a profiler trace; whether
+    it shares the device plane's clock is checked where both are read,
+    ``chipbench/metrics/_inflight.py``; an inactive ``TraceMe`` when no
+    profiler session is open) and, when ``recorder`` is a :class:`TraceRecorder`, on
     its engine lane with ``parent`` = the enclosing program span of this
     thread. Always written: there is no switch. ``set(**args)`` adds what
     is only known at the end; ``elapsed_s`` holds the wall time after

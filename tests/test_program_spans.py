@@ -91,6 +91,10 @@ def test_step_spans_nest_and_name_their_parent(served):
     for e in spans:
         if e["name"] == "pt.serve.step":
             continue
+        if e["name"] == "pt.serve.call" and "parent" not in e["args"]:
+            # finished() lands the queued releases outside any step
+            assert e["args"]["program"] == "pt_slot_update"
+            continue
         parent = e["args"]["parent"]
         assert parent.startswith("pt.serve."), e
         assert any(p["ts"] <= e["ts"] + 1e-3
@@ -252,6 +256,304 @@ def test_program_span_is_an_inactive_traceme_without_a_session():
         with program_span("serve.step"):
             raise RuntimeError("boom")
     assert tracing._open.stack == []
+
+
+# ---- the in-flight ledger (docs/OBSERVABILITY.md "What the device waits
+# for"): starved time by host phase, one leaf span a program call ----------
+
+_NEW = ("device_starved_s", "starved_emit_s", "starved_admit_s",
+        "starved_prefill_s", "starved_dispatch_s", "starved_caller_s",
+        "drains", "caller_over_1s", "caller_over_1s_s", "steps_over_1s",
+        "steps_over_1s_wall_s", "steps_over_1s_wait_s",
+        "steps_over_1s_starved_s")
+
+
+def _phase_sum(st):
+    return (st["starved_emit_s"] + st["starved_admit_s"]
+            + st["starved_prefill_s"] + st["starved_dispatch_s"]
+            + st["starved_caller_s"])
+
+
+def test_phase_counters_sum_to_the_starved_time_in_every_step(model):
+    cfg, m = model
+    eng = _engine(m)
+    for r in _requests(cfg, EOS):
+        eng.add_request(r)
+    while eng.has_work():
+        eng.step()
+        assert _phase_sum(eng.stats) == eng.stats["device_starved_s"]
+        eng.finished()              # as the benchmark's driver polls
+        assert _phase_sum(eng.stats) == eng.stats["device_starved_s"]
+    st = eng.stats
+    assert st["device_starved_s"] > 0.0 and st["drains"] > 0
+    assert all(st[k] >= 0.0 for k in _NEW)
+    # every phase the engine works in with the device empty got its part
+    assert all(st[k] > 0.0 for k in ("starved_emit_s", "starved_prefill_s",
+                                     "starved_dispatch_s", "starved_caller_s"))
+    # floor and ceiling fit in the time there was
+    assert st["device_starved_s"] + st["device_maybe_starved_s"] \
+        < st["step_wall_s"] + st["starved_caller_s"] + 1.0
+
+
+def test_drains_follow_the_reads_of_the_newest_call(model, monkeypatch):
+    """A drain is stamped when the read of the NEWEST call returns (the
+    block's, the first tokens') and never by ``_drain_pending``'s read of
+    an older value."""
+    from paddle_tpu.inference import serving
+
+    cfg, m = model
+    rec = TraceRecorder()
+    eng = _engine(m, tracer=rec)
+    reads = []
+    read = serving._InFlight.read
+
+    def spy(self, seq, now):
+        before = self.stats["drains"]
+        read(self, seq, now)
+        reads.append((seq, self.called, self.stats["drains"] - before))
+
+    monkeypatch.setattr(serving._InFlight, "read", spy)
+    rng = np.random.default_rng(2)
+    # without an eos id first: its block's values stay on the device ...
+    eng.add_request(Request(rng.integers(3, cfg.vocab_size, 9).astype(
+        np.int32), max_new_tokens=24, seed=1))
+    eng.step()
+    eng.step()
+    assert eng._pending and not reads and eng.stats["drains"] == 0
+    # ... then one with: the next block is read back, the older values first
+    eng.add_request(Request(rng.integers(3, cfg.vocab_size, 9).astype(
+        np.int32), max_new_tokens=6, eos_token_id=EOS, seed=2))
+    while eng.has_work():
+        eng.step()
+    waits = [e["args"] for e in _pt(rec) if e["name"] == "pt.serve.wait"]
+    assert [w["seq"] for w in waits] == [seq for seq, _, _ in reads]
+    older = [(seq, called, d) for seq, called, d in reads if seq < called]
+    assert older and all(d == 0 for _, _, d in older)
+    assert {w["what"] for w in waits if w["seq"] in {s for s, _, _ in older}
+            } == {"pending"}
+    newest = [(seq, d) for seq, called, d in reads if seq == called]
+    assert newest and all(d == 1 for _, d in newest)
+    kinds = {w["what"] for w in waits
+             if w["seq"] in {seq for seq, _ in newest}}
+    assert {"decode_block", "first_token"} <= kinds
+    assert eng.stats["drains"] == len(newest)
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["scan", "speculative"])
+def test_without_eos_nothing_is_read_and_the_ledger_stands_still(model, spec):
+    cfg, m = model
+    eng = _engine(m, **(dict(speculative=SpecConfig(k=2)) if spec else {}))
+    reqs = [Request(np.tile(np.arange(3, 9, dtype=np.int32), 3),
+                    max_new_tokens=8, seed=i + 1) for i in range(2)]
+    for r in reqs:
+        eng.add_request(r)
+    while eng.has_work():
+        eng.step()
+    if spec:
+        # the emit vector of a speculative dispatch IS read back, eos or not
+        assert eng.stats["spec_steps"] > 0 and eng.stats["drains"] > 0
+    else:
+        assert eng._flight.called > 0 and eng._flight.done == 0
+        assert all(eng.stats[k] == 0 for k in _NEW), eng.stats
+    eng.finished()
+    assert all(r.done and len(r.output) == 8 for r in reqs)
+    assert _phase_sum(eng.stats) == eng.stats["device_starved_s"]
+
+
+def test_a_known_gap_between_drain_and_call_goes_to_each_phase():
+    """On a hand-made clock: the device drains at 10 ms, the next program
+    is called at 15 ms, and the 5 ms between lie 1 ms in the step's own
+    time, 1.5 in emit, 0.5 in admit and 2 in prefill."""
+    from paddle_tpu.inference.serving import _InFlight
+
+    st = {}
+    fl = _InFlight(st)
+    ms = 1e-3
+    fl.step_begins(0.0)
+    fl.enter("starved_dispatch_s", 0.5 * ms)
+    fl.call(1 * ms)                              # seq 1: the decode block
+    fl.enter("starved_dispatch_s", 2 * ms)       # the span's exit
+    assert st["device_starved_s"] == 0.0 and st["drains"] == 0
+    fl.read(1, 10 * ms)                          # its read returns
+    assert st["drains"] == 1
+    left = fl.enter("starved_emit_s", 11 * ms)
+    fl.enter(left, 12.5 * ms)
+    left = fl.enter("starved_admit_s", 12.5 * ms)
+    fl.enter(left, 13 * ms)
+    left = fl.enter("starved_prefill_s", 13 * ms)
+    fl.call(15 * ms)                             # seq 2: the chunk
+    fl.enter(left, 16 * ms)
+    assert st["starved_dispatch_s"] == pytest.approx(1 * ms)
+    assert st["starved_emit_s"] == pytest.approx(1.5 * ms)
+    assert st["starved_admit_s"] == pytest.approx(0.5 * ms)
+    assert st["starved_prefill_s"] == pytest.approx(2 * ms)
+    assert st["starved_caller_s"] == 0.0
+    assert st["device_starved_s"] == pytest.approx(5 * ms)
+    assert _phase_sum(st) == st["device_starved_s"]
+    # a read of the older call while a newer one flies stamps nothing
+    fl.call(17 * ms)                             # seq 3
+    fl.read(2, 18 * ms)
+    assert st["drains"] == 1 and fl.done == 2
+    fl.read(3, 19 * ms)
+    fl.read(3, 19.5 * ms)                        # a second value of it
+    assert st["drains"] == 2
+    # the caller's poll with the device empty is the caller's ...
+    fl.step_ends(20 * ms, has_work=True)
+    fl.step_begins(24 * ms)
+    assert st["starved_caller_s"] == pytest.approx(4 * ms)
+    assert st["starved_dispatch_s"] == pytest.approx(2 * ms)   # 19 -> 20
+    # ... unless it took over a second: then it is nobody's starved time
+    fl.step_ends(25 * ms, has_work=True)
+    fl.step_begins(25 * ms + 3.0)
+    assert st["caller_over_1s"] == 1
+    assert st["caller_over_1s_s"] == pytest.approx(3.0)
+    assert st["starved_caller_s"] == pytest.approx(4 * ms)
+    fl.call(25 * ms + 3.0 + 2 * ms)
+    assert st["device_starved_s"] == pytest.approx(13 * ms)
+    assert _phase_sum(st) == st["device_starved_s"]
+    # and an engine left without work is not starved while it has none
+    fl.read(4, 3.1)
+    fl.step_ends(3.2, has_work=False)
+    fl.step_begins(9.0)
+    fl.call(9.001)
+    assert st["caller_over_1s"] == 1
+    assert st["device_starved_s"] == pytest.approx(13 * ms + 0.1)
+    assert st["device_maybe_starved_s"] == 0.0
+
+
+def test_a_finished_predecessor_without_a_drain_is_the_blind_spot():
+    """A call whose predecessor's output is ready, with no drain stamped
+    since, adds the time since that call to ``device_maybe_starved_s``."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference.serving import _InFlight
+
+    st = {}
+    fl = _InFlight(st)
+    fl.step_begins(0.0)
+    fl.call(0.001)
+    fl.out = [(jnp.zeros(2).block_until_ready(), None)]   # as a kv list
+    fl.call(0.003)
+    assert st["device_maybe_starved_s"] == pytest.approx(0.002)
+    assert st["device_starved_s"] == 0.0 and st["drains"] == 0
+    fl.call(0.004)                      # no output noted: nothing is known
+    assert st["device_maybe_starved_s"] == pytest.approx(0.002)
+    # a caller away for over a second, finished() calling inside that
+    # interval: only the time outside it counts
+    fl.out = ready = jnp.zeros(2).block_until_ready()
+    fl.step_ends(0.005, has_work=True)
+    fl.call(0.006)                      # finished()'s releases
+    assert st["device_maybe_starved_s"] == pytest.approx(0.004)     # 4 -> 6
+    fl.out = ready
+    fl.step_begins(2.005)
+    fl.call(2.006)
+    assert st["caller_over_1s_s"] == pytest.approx(2.0)
+    assert st["device_maybe_starved_s"] == pytest.approx(0.005)     # 2.005 ->
+
+
+def test_call_spans_are_leaves_under_the_phase_that_called(served):
+    eng, rec, _, s0, s1 = served
+    spans = _pt(rec)
+    calls = [e for e in spans if e["name"] == "pt.serve.call"]
+    assert calls
+    parents = {}
+    for e in calls:
+        a = e["args"]
+        assert {"program", "key", "seq", "drained"} <= set(a)
+        assert a["drained"] in (0, 1)
+        parents.setdefault(a["program"], set()).add(a.get("parent"))
+    assert parents["pt_decode_block"] == {"pt.serve.decode.dispatch"}
+    assert parents["pt_prefill_chunk"] == parents["pt_first_token"] \
+        == {"pt.serve.prefill"}
+    assert parents["pt_cow_copy"] == {"pt.serve.admit"}
+    assert parents["pt_slot_update"] <= {"pt.serve.decode.dispatch", None}
+    # numbered one by one, in order; no span lies inside a call
+    seqs = [e["args"]["seq"] for e in calls]
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
+    assert seqs[-1] == eng._flight.called
+    assert not [e for e in spans if e["args"].get("parent") == "pt.serve.call"]
+    # a wait names the call whose value it read, a step what it was starved
+    by_seq = {e["args"]["seq"]: e["args"]["program"] for e in calls}
+    for w in (e["args"] for e in spans if e["name"] == "pt.serve.wait"):
+        assert by_seq[w["seq"]] == {"decode_block": "pt_decode_block",
+                                    "first_token": "pt_first_token"}[w["what"]]
+    steps = [e["args"] for e in spans if e["name"] == "pt.serve.step"]
+    assert all({"starved_us", "maybe_starved_us", "wait_us"} <= set(a)
+               for a in steps)
+    in_steps = sum(a["starved_us"] for a in steps) * 1e-6
+    whole = s1["device_starved_s"] - s0["device_starved_s"]
+    caller = s1["starved_caller_s"] - s0["starved_caller_s"]
+    assert in_steps == pytest.approx(whole - caller, rel=0.05, abs=1e-4)
+    assert sum(a["wait_us"] for a in steps) * 1e-6 == pytest.approx(
+        s1["device_wait_s"] - s0["device_wait_s"], rel=0.05, abs=1e-4)
+
+
+def test_steps_over_the_cut_are_counted_with_what_held_them(model, monkeypatch):
+    """``steps_over_1s``: a step that built no program and took longer than
+    the cut (lowered here; the ``serving.stall`` fault site sleeps the
+    step), not a step that built one, however long."""
+    import time
+
+    from paddle_tpu.distributed.resilience.faults import FaultPlan, FaultSpec
+    from paddle_tpu.inference import serving
+
+    cfg, m = model
+    cut, nap = 0.25, 0.3
+    monkeypatch.setattr(serving, "_LONG_S", cut)
+    stall = [FaultSpec("serving.stall", "stall", at=0, arg=nap)]
+    eng = _engine(m)
+
+    def long_one():
+        return Request(np.arange(3, 12, dtype=np.int32), max_new_tokens=16,
+                       eos_token_id=EOS, seed=1)
+
+    with FaultPlan(specs=stall):
+        _run(eng, [long_one()])                    # the first step builds
+    assert eng.stats["programs_built"] > 0
+    assert eng.stats["steps_over_1s"] == 0
+    _run(eng, [long_one()])                        # every program met
+    built = eng.stats["programs_built"]
+    eng.add_request(long_one())
+    eng.step()
+    with FaultPlan(specs=stall):
+        eng.step()
+    st = eng.stats
+    assert st["programs_built"] == built
+    assert st["steps_over_1s"] == 1
+    assert nap <= st["steps_over_1s_wall_s"] < nap + cut
+    # the device was empty and the host asleep: the host held the step
+    assert st["steps_over_1s_starved_s"] >= nap > st["steps_over_1s_wait_s"]
+    assert st["starved_dispatch_s"] >= nap
+    # a caller that stays away past the cut is counted apart
+    caller = st["starved_caller_s"]
+    time.sleep(nap)
+    eng.step()
+    assert st["caller_over_1s"] == 1 and st["caller_over_1s_s"] >= nap
+    assert st["starved_caller_s"] == caller
+    _run(eng, [])
+    assert st["steps_over_1s"] == 1
+
+
+def test_a_program_call_costs_microseconds_without_a_session(model):
+    """What the ledger and ``pt.serve.call`` add to a program call with no
+    profiler session and no recorder: under 10 us (about 4 on this CPU)."""
+    import time
+
+    import jax.numpy as jnp
+
+    cfg, m = model
+    eng = _engine(m)
+    x = jnp.zeros(4).block_until_ready()
+    eng._built[("pt_probe", (4, True))] = "4/True"
+
+    def loop(n=2000):
+        t = time.perf_counter()
+        for _ in range(n):
+            eng._call_built("pt_probe", (4, True), lambda: x)
+        return (time.perf_counter() - t) / n
+
+    assert min(loop() for _ in range(5)) < 10e-6
+    assert eng._flight.called == 10000
 
 
 def _host_names(trace_dir):
