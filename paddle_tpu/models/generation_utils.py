@@ -41,6 +41,79 @@ def validate_sampling(temperature, top_p, top_k=0):
         raise ValueError(f"top_k must be >= 0, got {top_k}")
 
 
+def _least(holds, nbits, rows):
+    """The least ``x`` in ``[0, 2**nbits)`` (uint32 ``[rows]``) at which
+    ``holds(x)`` is true, for a ``holds`` that stays true as ``x`` rises and
+    is true at the top of the range: a bisection, one bit and one call of
+    ``holds`` a pass, highest bit first. (One threshold a pass is what the
+    chip timed fastest: the passes run out of the chip's vector memory, so
+    several thresholds a pass cost their compares and save no reads;
+    PERF.md section 6, PR 39.)"""
+
+    def one_pass(i, lo):
+        bit = jnp.uint32(1) << (nbits - 1 - i).astype(jnp.uint32)
+        return jnp.where(holds(lo + (bit - 1)), lo, lo + bit)
+
+    return jax.lax.fori_loop(0, nbits, one_pass, jnp.zeros(rows, jnp.uint32))
+
+
+def _float_at(x):
+    """The float32 at place ``x`` (uint32) of the floats' own order, held to
+    ``[-inf, inf]``: the places below and above hold only NaNs."""
+    x = jnp.clip(x, jnp.uint32(0x007FFFFF), jnp.uint32(0xFF800000))
+    bits = jnp.where(x >> 31 == 1, x ^ jnp.uint32(0x80000000), ~x)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def nucleus_threshold(lg, top_ps, top_ks):
+    """What ``sample_rows`` keeps of each row of ``lg`` (logits over the
+    temperature), as ``(t, n_ties, e, e_t)``: every token with ``lg > t``
+    and, of those with ``lg == t``, the first ``n_ties`` by id; ``e`` is
+    ``exp(lg - max)`` and ``e_t`` its value at ``t`` (0 where ``t`` is so far
+    below the max, or ``-inf``, that a tie weighs nothing).
+
+    That is the prefix of the stable descending order in which the mass
+    BEFORE a token is within ``top_p`` and fewer than ``top_k`` tokens lie
+    before it: the mass above a value and the count above it both fall as
+    the value rises, so the kept values are those from one threshold up. The
+    threshold is searched for over the float32 order itself (32 passes, one
+    compare-select-reduce over ``[rows, V]`` each), exact at any nucleus
+    size; nothing is sorted.
+
+    Named without an underscore because it is the seam the tests read
+    (``tests/test_sampler.py`` holds the kept set to its reference's support
+    through it); ``sample_rows`` is its one caller in the program."""
+    rows, V = lg.shape
+    # behind a barrier like lg (sample_rows says why): one e for every pass
+    e = jax.lax.optimization_barrier(
+        jnp.exp(lg - jnp.max(lg, -1, keepdims=True)))
+    budget = top_ps * jnp.sum(e, -1)
+    kk = jnp.where(top_ks > 0, top_ks, V)
+
+    def above(t):
+        m = lg > t[:, None]
+        return (jnp.sum(jnp.where(m, e, 0.0), -1),
+                jnp.sum(m, -1, dtype=jnp.int32))
+
+    def holds(x):
+        mass, count = above(_float_at(x))
+        return (mass <= budget) & (count < kk)
+
+    t = _float_at(_least(holds, 32, rows))
+    mass, count = above(t)
+    tie = lg == t[:, None]
+    e_t = jnp.max(jnp.where(tie, e, 0.0), -1)
+    # the ties at t in id order: the r-th is kept while mass + r * e_t is
+    # within the budget and count + r under top_k; the first is, by what t is
+    fit = jnp.floor((budget - mass) / jnp.where(e_t > 0, e_t, 1.0))
+    fit = jnp.where(mass + fit * e_t > budget, fit - 1, fit)
+    fit = jnp.where(mass + (fit + 1) * e_t <= budget, fit + 1, fit)
+    by_mass = jnp.where(e_t > 0, jnp.clip(fit, 0, V) + 1, V).astype(jnp.int32)
+    n_ties = jnp.clip(jnp.minimum(by_mass, kk - count), 1,
+                      jnp.sum(tie, -1, dtype=jnp.int32))
+    return t, n_ties, e, e_t
+
+
 @functools.partial(jax.named_call, name="pt.sampler")
 def sample_rows(logits, keys, temps, top_ps, top_ks):
     """Row-vectorized sampling: per-row temperature/top-p/top-k/key.
@@ -53,32 +126,56 @@ def sample_rows(logits, keys, temps, top_ps, top_ks):
     logits [b, V] f32; keys: typed PRNG key array [b]; temps/top_ps [b] f32;
     top_ks [b] int32 (0 = disabled). temperature<=0 rows take argmax.
 
-    One stable sort a call gives the sorted logits and their token ids
-    together; the kept tokens are a prefix of that order, so one uniform a
-    row against the cumulative sum draws from the kept, renormalised
-    probabilities. Nothing else is V wide but elementwise passes and
-    reductions (no gather, no field of random bits); the trace readers count
-    a decode block's token steps by this one ``sort``.
+    ``nucleus_threshold`` finds the kept tokens (a search for their
+    threshold, no sort of the vocabulary), and one uniform a row draws from
+    their renormalised probabilities by the inverse CDF in ID order: the
+    least id at which the kept mass up to it passes ``u`` x the whole kept
+    mass, found by a second search, over ids (a pass a bit of ``V - 1``), and
+    held to the highest kept id. Nothing is V wide but elementwise passes
+    fused into reductions: no sort, no cumulative sum, no gather, no field
+    of random bits; a token of no mass (``-inf``, or underflowed) is never
+    drawn.
     """
-    V = logits.shape[-1]
+    rows, V = logits.shape
     greedy = jnp.argmax(logits, -1).astype(jnp.int32)
-    lg = logits / jnp.maximum(temps[:, None], 1e-6)
-    ids = jax.lax.broadcasted_iota(jnp.int32, lg.shape, lg.ndim - 1)
-    neg_sorted, sort_idx = jax.lax.sort((-lg, ids), dimension=-1, num_keys=1,
-                                        is_stable=True)
-    p = jax.nn.softmax(-neg_sorted, -1)
-    cum = jnp.cumsum(p, -1)
-    keep = (cum - p) <= top_ps[:, None]
-    kk = jnp.where(top_ks > 0, top_ks, V)
-    keep = keep & (ids < kk[:, None])
-    # keep is a prefix (cum - p never falls, top_k cuts a prefix): the kept
-    # mass is cum at its last entry, and u * mass lands in one kept interval
-    n_keep = jnp.sum(keep, -1, dtype=jnp.int32)
-    mass = jnp.take_along_axis(cum, n_keep[:, None] - 1, -1)
+    # ONE lg for every pass below. Without the barrier the compiler
+    # recomputes the division inside each fusion of a decode block that
+    # reads lg (five of them), and the searches need every pass to see the
+    # same float32s: a threshold found on one copy has to equal an entry of
+    # the next. On the v5e the copies are NOT equal (a probe read the loop's
+    # threshold 1,000 float32 places or more from the maximum another fusion
+    # computed), and the block dropped the best token at temperature 1e-6,
+    # where a place of lg is half a logit x 1e6 (PERF.md section 6, PR 39;
+    # tests/test_tpu_aot_kernels.py holds the compiled block to one division
+    # and one exp).
+    lg = jax.lax.optimization_barrier(
+        logits / jnp.maximum(temps[:, None], 1e-6))
+    t, n_ties, e, e_t = nucleus_threshold(lg, top_ps, top_ks)
+    # one array for the draw's passes: a token above t weighs its e, a tie
+    # -1; ties that weigh nothing are not waited for
+    w = jnp.where(lg > t[:, None], e, jnp.where(lg == t[:, None], -1.0, 0.0))
+    n_ties = jnp.where(e_t > 0, n_ties, 0)
+    ids = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+
+    def upto(j):
+        """Of the ids <= j: the mass above t, the count above t, the ties."""
+        le = ids <= j[:, None]
+        return (jnp.sum(jnp.where(le & (w > 0), w, 0.0), -1),
+                jnp.sum(le & (w > 0), -1, dtype=jnp.int32),
+                jnp.sum(le & (w < 0), -1, dtype=jnp.int32))
+
+    mass_above, n_above, _ = upto(jnp.full((rows,), V - 1, jnp.int32))
     u = jax.vmap(lambda k: jax.random.uniform(k, (1,), jnp.float32))(keys)
-    choice = jnp.minimum(jnp.sum(cum <= u * mass, -1, dtype=jnp.int32),
-                         n_keep - 1)
-    sampled = jnp.take_along_axis(sort_idx, choice[:, None], -1)[:, 0]
+    target = u[:, 0] * (mass_above + n_ties * e_t)
+
+    def reached(x):
+        # the second clause is counts, so exact: true from the highest kept
+        # id on, whatever rounding does to the sums of the first
+        mass, above, ties = upto(jnp.minimum(x, V - 1).astype(jnp.int32))
+        return ((mass + jnp.minimum(ties, n_ties) * e_t > target)
+                | ((above >= n_above) & (ties >= n_ties)))
+
+    sampled = _least(reached, max(V - 1, 1).bit_length(), rows)
     return jnp.where(temps <= 0.0, greedy, sampled.astype(jnp.int32))
 
 

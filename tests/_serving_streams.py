@@ -4,9 +4,10 @@ pages of 4, blocks of 4), greedy and seeded-sampled requests mixed over
 shared prefixes. ``tests/data/serving_llama_streams.json`` holds them: the
 greedy streams (every fourth request) and the cache's counters as the parent
 commit of PR 28 produced them (``python tests/_serving_streams.py <out>`` in
-a checkout of it), the sampled streams as PR 29 produced them (``sample_rows``
-draws one uniform a row there, another random stream from the same key; the
-greedy streams were checked equal to the older file when it was re-recorded);
+a checkout of it), the sampled streams as PR 39 produced them (``sample_rows``
+draws its one uniform a row against the kept tokens in ID order there, in
+sorted order from PR 29 on, so a key draws another token; the greedy streams
+were checked equal to the older file each time it was re-recorded);
 ``test_serving_state.py`` holds every later tree to them byte for byte."""
 
 import json

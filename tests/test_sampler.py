@@ -2,9 +2,13 @@
 its program must keep (tier 1: ``tests/test_serving_sampling.py`` is slow as
 a whole).
 
-The benchmark's readers count a decode block's token steps by the sampler's
-one ``sort`` (``chipbench/metrics/_scopes.py::token_steps``); the structure
-tests hold that line, and that nothing vocabulary-wide is gathered or drawn.
+Since PR 39 the sampler sorts nothing: the kept tokens are found by a search
+for their threshold and drawn in id order. The structure tests hold that no
+``sort`` is left (``chipbench/metrics/_scopes.py::token_steps`` counted a
+decode block's token steps by it and reads nothing now), a ceiling on the
+passes over the vocabulary, and that nothing vocabulary-wide is gathered or
+drawn; section (f) holds the kept SET against ``nucleus()`` at the cells'
+widths.
 """
 
 import jax
@@ -13,7 +17,8 @@ import numpy as np
 import pytest
 
 from paddle_tpu.models import generation_utils
-from paddle_tpu.models.generation_utils import fold_keys, sample_rows
+from paddle_tpu.models.generation_utils import (fold_keys, nucleus_threshold,
+                                                sample_rows)
 
 DRAWS = 8192
 # twelve tokens, two exact ties (ids 3, 7 and ids 5, 9), nothing so unlikely
@@ -131,21 +136,27 @@ def test_same_key_same_token_other_key_other_stream():
     assert len(set(np.asarray(sample_rows(logits, same_key, *args)))) == 1
 
 
-@pytest.mark.parametrize("u,which", [(np.nextafter(np.float32(1), np.float32(0)),
-                                      "last"), (np.float32(0), "first")],
-                         ids=["largest-u", "u-zero"])
-@pytest.mark.parametrize("temperature,top_p,top_k", PARAMS)
-def test_the_ends_of_u_choose_the_ends_of_the_kept_prefix(
-        monkeypatch, u, which, temperature, top_p, top_k):
+def _rig_u(monkeypatch, u):
     monkeypatch.setattr(
         generation_utils.jax.random, "uniform",
         lambda key, shape=(), dtype=jnp.float32, **kw: jnp.full(shape, u, dtype))
+
+
+LARGEST_U = np.nextafter(np.float32(1), np.float32(0))
+
+
+@pytest.mark.parametrize("u,which", [(LARGEST_U, "highest"),
+                                     (np.float32(0), "lowest")],
+                         ids=["largest-u", "u-zero"])
+@pytest.mark.parametrize("temperature,top_p,top_k", PARAMS)
+def test_the_ends_of_u_choose_the_lowest_and_the_highest_kept_id(
+        monkeypatch, u, which, temperature, top_p, top_k):
+    """The draw is the inverse CDF in ID order, held to the kept ids."""
+    _rig_u(monkeypatch, u)
     for row in (ROW_A, ROW_B):
-        want = nucleus(row, temperature, top_p, top_k)
-        order = np.argsort(-row, kind="stable")
-        kept = [t for t in order if want[t] > 0]
+        kept = np.flatnonzero(nucleus(row, temperature, top_p, top_k))
         tok = int(draw(row, temperature, top_p, top_k, n=1)[0])
-        assert tok == (kept[-1] if which == "last" else kept[0])
+        assert tok == (kept[-1] if which == "highest" else kept[0])
 
 
 # ---- (e) the serving cell's width -------------------------------------------
@@ -163,16 +174,29 @@ def test_a_vocabulary_of_92544_runs():
     assert want[toks[1]] > 0
 
 
-# ---- the structure the benchmark's readers depend on ------------------------
+# ---- the structure: no sort, few passes, nothing V-wide gathered, drawn ----
 
-def _eqns(jaxpr):
+def _eqns(jaxpr, trips=1):
+    """Every equation with how often it runs a call: the product of the
+    lengths of the scans round it (a ``fori_loop`` of fixed length is one)."""
     for e in jaxpr.eqns:
-        yield e
+        yield e, trips
+        inside = trips * e.params["length"] if e.primitive.name == "scan" \
+            else trips
         for v in e.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else (v,)):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    yield from _eqns(inner)
+                    yield from _eqns(inner, inside)
+
+
+def _names(jaxpr):
+    return [e.primitive.name for e, _ in _eqns(jaxpr)]
+
+
+def _search_passes(V):
+    """The passes of the two searches: a float32's 32 bits, an id's bits."""
+    return 32, (V - 1).bit_length()
 
 
 @pytest.fixture(scope="module")
@@ -187,21 +211,43 @@ def sampler_eqns():
     return V, list(_eqns(closed.jaxpr))
 
 
-def test_exactly_one_sort_with_two_operands(sampler_eqns):
-    _, eqns = sampler_eqns
-    sorts = [e for e in eqns if e.primitive.name == "sort"]
-    assert len(sorts) == 1
-    (sort,) = sorts
-    assert len(sort.invars) == 2 and len(sort.outvars) == 2
-    assert sort.params["num_keys"] == 1 and sort.params["is_stable"]
-    assert not [e for e in eqns if "top_k" in e.primitive.name]
+# a pass = one trip of a search's loop (its reductions share their operands
+# and fuse into one read of [rows, V]) or one V-wide reduction outside them
+PASS_CEILING = 64
+
+
+def test_no_sort_and_a_ceiling_on_vocabulary_wide_passes(sampler_eqns):
+    V, eqns = sampler_eqns
+    names = {e.primitive.name for e, _ in eqns}
+    assert not {n for n in names if "sort" in n or "top_k" in n
+                or n.startswith("cum") or "reduce_window" in n}
+    loops = [e for e, _ in eqns if e.primitive.name == "scan"]
+    assert sorted(e.params["length"] for e in loops) == sorted(
+        _search_passes(V))
+    wide = [(e, trips) for e, trips in eqns
+            if e.primitive.name.startswith(("reduce_", "arg"))
+            and any(V in v.aval.shape for v in e.invars)]
+    assert {trips for _, trips in wide} == {1, *_search_passes(V)}
+    # a pass of the threshold's search is a mass and a count, one of the
+    # draw's those and the ties' count: nothing else reads the vocabulary there
+    loops.sort(key=lambda e: -e.params["length"])
+    for loop, reductions in zip(loops, (2, 3)):
+        inner = [e for e, _ in _eqns(loop.params["jaxpr"].jaxpr)
+                 if any(V in v.aval.shape for v in e.invars)
+                 and e.primitive.name.startswith(("reduce_", "arg"))]
+        assert len(inner) == reductions
+    outside = sum(1 for _, trips in wide if trips == 1)
+    assert sum(_search_passes(V)) + outside <= PASS_CEILING
 
 
 @pytest.mark.parametrize("prim", ["gather", "random_bits"])
 def test_nothing_vocabulary_wide_is_gathered_or_drawn(sampler_eqns, prim):
     V, eqns = sampler_eqns
-    found = [e for e in eqns if e.primitive.name == prim]
-    assert found                                    # the small ones are there
+    found = [e for e, _ in eqns if e.primitive.name == prim]
+    if prim == "gather":
+        assert not found                # no gather at all is left (PR 39)
+        return
+    assert found                        # the one uniform a row
     for e in found:
         for out in e.outvars:
             assert V not in out.aval.shape, (prim, out.aval.shape)
@@ -209,17 +255,18 @@ def test_nothing_vocabulary_wide_is_gathered_or_drawn(sampler_eqns, prim):
 
 @pytest.mark.parametrize("do_sample", [False, True],
                          ids=["all-greedy", "sampling"])
-def test_a_decode_block_sorts_once_a_token_step_or_not_at_all(do_sample):
-    """A ``do_sample=False`` block holds no sort (the readers then count no
-    token steps and read nothing); a sampling block holds one, inside its
-    scan's body, so it runs once a token step."""
+def test_a_decode_block_searches_once_a_token_step_or_not_at_all(do_sample):
+    """Neither block holds a sort. A ``do_sample=False`` block holds no
+    search either; a sampling block holds the two searches inside its scan's
+    body, so they run once a token step, and none outside it."""
     import paddle_tpu as paddle
     from paddle_tpu.inference.serving import (ContinuousBatchingEngine,
                                               PrefixCacheConfig)
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
     paddle.seed(11)
-    m = LlamaForCausalLM(LlamaConfig.tiny(num_hidden_layers=1))
+    cfg = LlamaConfig.tiny(num_hidden_layers=1)
+    m = LlamaForCausalLM(cfg)
     eng = ContinuousBatchingEngine(
         m, max_batch=4, max_len=64, page_size=8, block_size=4,
         prefix_cache=PrefixCacheConfig(prefill_chunk=16, extra_blocks=8))
@@ -229,9 +276,100 @@ def test_a_decode_block_sorts_once_a_token_step_or_not_at_all(do_sample):
         lambda *a: step(*a, n_steps=2, do_sample=do_sample))(
         eng._params, eng._last_tok, eng.caches["kv"], eng.caches["tables"],
         eng._dev_pos, eng._dev_act, seeds, temps, tops, topks)
-    (scan,) = [e for e in _eqns(closed.jaxpr) if e.primitive.name == "scan"]
-    assert scan.params["length"] == 2
-    n_sorts = lambda j: sum(e.primitive.name == "sort"      # noqa: E731
-                            for e in _eqns(j))
-    assert n_sorts(scan.params["jaxpr"].jaxpr) == int(do_sample)
-    assert n_sorts(closed.jaxpr) == int(do_sample)          # none outside it
+    assert "sort" not in _names(closed.jaxpr)
+    scans = [(e, trips) for e, trips in _eqns(closed.jaxpr)
+             if e.primitive.name == "scan"]
+    (block,) = [e for e, trips in scans if trips == 1]
+    assert block.params["length"] == 2
+    searches = sorted(e.params["length"] for e, trips in scans if trips == 2)
+    want = sorted(_search_passes(cfg.vocab_size)) if do_sample else []
+    assert searches == want and len(scans) == 1 + len(want)
+
+
+# ---- (f) the kept SET is nucleus()'s support, at the cells' widths ----------
+# Float32 sums of 92,544 terms are good to some 1e-7 of the whole and a token
+# at the edge of the nucleus weighs 1e-6 to 1e-5 of it, so each case moves its
+# top_p into the middle of the gap between the mass before the last kept token
+# and before the first dropped one (for the rounded logits: in the middle of
+# the run of ties that straddles the edge) and then asks for EXACT equality.
+
+GRID = [(p, k) for p in (0.7, 0.95, 1.0) for k in (0, 1, 50)]
+KINDS = {"float32": 0.7, "bf16-ties": 1.0, "minus-inf": 0.7,
+         "temperature-1e-6": 1e-6}
+
+
+def _rows(kind, V, seed):
+    rng = np.random.default_rng(seed)
+    rows = rng.standard_normal((len(GRID), V), dtype=np.float32)
+    if kind == "bf16-ties":         # some 170 equal values a step of bf16
+        rows = np.asarray(jnp.asarray(rows).astype(jnp.bfloat16)
+                          .astype(jnp.float32))
+    if kind == "minus-inf":
+        rows[:, rng.integers(0, V, 5000)] = -np.inf
+        rows[:, -1] = -np.inf       # the highest id: never to be drawn
+    return rows
+
+
+def _kept64(row, temperature, top_p, top_k, in_a_run):
+    """``nucleus()``'s rule in float64 with ``top_p`` moved off the edge:
+    (kept mask, the tokens of any mass, the moved top_p, ties kept of ties)."""
+    lg = row.astype(np.float64) / max(temperature, 1e-6)
+    order = np.argsort(-lg, kind="stable")
+    p = np.exp(lg[order] - lg[order][0])
+    p /= p.sum()
+    before = np.cumsum(p) - p
+    n = int((before <= top_p).sum())
+    ties = (1, 1)
+    if n < len(row) and top_p < 1.0:
+        if in_a_run:
+            run = np.flatnonzero(lg[order] == lg[order][n])
+            n = int(run[len(run) // 2])
+            ties = (n - int(run[0]), len(run))
+        top_p = np.float32(0.5 * (before[n - 1] + before[n]))
+        assert before[n] - before[n - 1] > 5e-7
+    keep = before <= top_p
+    if top_k > 0:
+        keep &= np.arange(len(row)) < top_k
+    mask = np.zeros(len(row), bool)
+    mask[order[keep]] = True
+    some_mass = np.zeros(len(row), bool)
+    some_mass[order] = p > 0
+    return mask, some_mass, np.float32(top_p), ties
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+@pytest.mark.parametrize("V", [92544, 65536])
+def test_the_kept_set_is_the_nucleus_exactly(monkeypatch, V, kind):
+    """Rows of the grid top_p 0.7 / 0.95 / 1.0 x top_k 0 / 1 / 50 in one
+    call. A token of no mass (``-inf``; far below the best at temperature
+    1e-6) is in neither support and is not compared; nothing else differs."""
+    temperature = KINDS[kind]
+    rows = _rows(kind, V, seed=V % 1000 + len(kind))
+    want = [_kept64(rows[i], temperature, p, k, kind == "bf16-ties")
+            for i, (p, k) in enumerate(GRID)]
+    temps = jnp.full((len(GRID),), temperature, jnp.float32)
+    top_ps = jnp.asarray([w[2] for w in want], jnp.float32)
+    top_ks = jnp.asarray([k for _, k in GRID], jnp.int32)
+    lg = jnp.asarray(rows) / jnp.maximum(temps[:, None], 1e-6)
+    t, n_ties, _, _ = map(np.asarray, jax.jit(nucleus_threshold)(lg, top_ps,
+                                                                 top_ks))
+    lg = np.asarray(lg)
+    inside_a_run = 0
+    for i, (mask, some_mass, _, (ties_kept, ties)) in enumerate(want):
+        tie = lg[i] == t[i]
+        got = (lg[i] > t[i]) | (tie & (np.cumsum(tie) <= n_ties[i]))
+        assert np.array_equal(got & some_mass, mask & some_mass), GRID[i]
+        assert (mask & some_mass).any()
+        if GRID[i][1] == 0 and 1 < ties_kept < ties:
+            assert (n_ties[i], tie.sum()) == (ties_kept, ties)
+            inside_a_run += 1
+    if kind == "bf16-ties":
+        assert inside_a_run == 2        # top_p 0.7 and 0.95 without a top_k
+    # and the draw stays inside it at both ends of u, in id order
+    for u, end in ((LARGEST_U, -1), (np.float32(0), 0)):
+        _rig_u(monkeypatch, u)
+        toks = np.asarray(sample_rows(
+            jnp.asarray(rows), jax.random.split(jax.random.key(2), len(GRID)),
+            temps, top_ps, top_ks))
+        for i, (mask, some_mass, _, _) in enumerate(want):
+            assert toks[i] == np.flatnonzero(mask & some_mass)[end], GRID[i]

@@ -5,9 +5,11 @@ masked inactive rows, prompt-packing prefill, and O(active) host
 bookkeeping. One family at every ``max_batch`` since PR 30.
 
 The contract under test: token streams are BYTE-IDENTICAL to
-``generate()`` (greedy) and to the streams the legacy per-slot step
-programs produced at PR 30's parent (greedy AND seeded:
-``tests/data/serving_legacy_wave_streams.json``), at any slot count,
+``generate()`` (greedy) and to recorded streams
+(``tests/data/serving_legacy_wave_streams.json``: the greedy ones as the
+legacy per-slot step programs produced them at PR 30's parent, the seeded
+ones as a 2-slot engine of PR 39's tree did, whose sampler draws in id
+order), at any slot count,
 prefix cache on or off, warm or cold, across COW divergence and crash
 replay. The 128-slot acceptance pin (ISSUE 10) is slow-marked; every
 behavior has a fast 8-slot pin here.
@@ -36,10 +38,11 @@ def model():
 
 @pytest.fixture(scope="module")
 def recorded():
-    """What the legacy family (a 2-slot engine, ``fused=False``) served at
-    PR 30's parent for ``_wave`` and the eos request: the sampled streams
-    have no other reference (``generate()`` splits its key, the engine
-    folds (seed, position))."""
+    """What a 2-slot engine served for ``_wave`` and the eos request: the
+    legacy family (``fused=False``) at PR 30's parent and, for the two seeded
+    streams, PR 39's tree (the file says which). The sampled streams have no
+    other reference (``generate()`` splits its key, the engine folds (seed,
+    position))."""
     with open(os.path.join(os.path.dirname(__file__), "data",
                            "serving_legacy_wave_streams.json")) as f:
         return json.load(f)

@@ -99,6 +99,41 @@ def test_what_does_not_fold_lowers_to_the_gather(shape, one_chip,
     assert "tpu_custom_call" not in text
 
 
+def _lowered_sampling_block(eng, one_chip, n_steps=4):
+    """An engine's sampling decode block, traced and lowered for the
+    described v5e."""
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    tree = lambda t: jax.tree_util.tree_map(sds, t)
+    vec = lambda dt: jax.ShapeDtypeStruct((eng.max_batch,), dt,
+                                          sharding=one_chip)
+    return eng._build_mega_jit().trace(
+        tree(eng._params), vec(jnp.int32), tree(eng.caches["kv"]),
+        sds(eng.caches["tables"]), vec(jnp.int32), vec(jnp.bool_),
+        vec(jnp.int32), vec(jnp.float32), vec(jnp.float32), vec(jnp.int32),
+        n_steps=n_steps, do_sample=True).lower(lowering_platforms=("tpu",))
+
+
+def _one_lg_and_one_e(hlo, rows, V):
+    """The sampler's passes all read ONE ``lg`` and ONE ``e``: left to
+    itself the compiler recomputes the division by the temperature and the
+    ``exp`` inside every fusion of a block that reads them (five and three
+    in the LFM2 block), and on the chip the copies are not equal (a probe
+    read them 1,000 float32 places or more apart), which at temperature 1e-6
+    dropped the best token (PR 39, before its ``optimization_barrier``s:
+    ``served_token_logit_gap`` 5.9-6.4). And no
+    ``sort`` over the vocabulary is left (a router's is not one)."""
+    import re
+
+    wide = [body for body in re.findall(
+        r"(?ms)^%?[\w.\-]+ \([^\n]*\) -> [^\n]*\{\n(.*?)^\}", hlo)
+        if f"f32[{rows},{V}]" in body]
+    assert wide, "the sampler's fusions appear in the compiled block"
+    assert sum(" divide(" in body for body in wide) == 1
+    assert sum(" exponential(" in body for body in wide) == 1
+    assert not [line for line in hlo.splitlines()
+                if " sort(" in line and f"[{rows},{V}]" in line]
+
+
 def test_lfm2_decode_block_holds_the_kernel_and_no_pool_copy(one_chip,
                                                              monkeypatch):
     """The decode block of an LFM2 engine at the chat-batch-64 cell's head
@@ -106,7 +141,10 @@ def test_lfm2_decode_block_holds_the_kernel_and_no_pool_copy(one_chip,
     small experts and vocabulary), lowered and compiled for the described v5e: one
     ``pt_paged_decode`` call an attention layer, the pools appended in place
     in the default layout, and no pool-shaped ``copy`` (the layout
-    conversions PR 30 found round a scatter that XLA lays out slot-major)."""
+    conversions PR 30 found round a scatter that XLA lays out slot-major).
+
+    And the sampler's passes all read one ``lg`` and one ``e``
+    (``_one_lg_and_one_e``)."""
     import re
 
     from _lfm2_util import TINY, engine
@@ -123,15 +161,7 @@ def test_lfm2_decode_block_holds_the_kernel_and_no_pool_copy(one_chip,
     assert len(pools) == 2 and eng.stats["paged_kernel_layers"] == 2
     shape = tuple(pools[0][0].shape)
     assert shape[1:] == (4, 16, 128)
-    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-    tree = lambda t: jax.tree_util.tree_map(sds, t)
-    B = eng.max_batch
-    vec = lambda dt: jax.ShapeDtypeStruct((B,), dt, sharding=one_chip)
-    lowered = eng._build_mega_jit().trace(
-        tree(eng._params), vec(jnp.int32), tree(eng.caches["kv"]),
-        sds(eng.caches["tables"]), vec(jnp.int32), vec(jnp.bool_),
-        vec(jnp.int32), vec(jnp.float32), vec(jnp.float32), vec(jnp.int32),
-        n_steps=4, do_sample=True).lower(lowering_platforms=("tpu",))
+    lowered = _lowered_sampling_block(eng, one_chip)
     text = lowered.as_text()
     assert text.count("tpu_custom_call") == 2
     assert text.count("pt_paged_decode") >= 2
@@ -145,6 +175,44 @@ def test_lfm2_decode_block_holds_the_kernel_and_no_pool_copy(one_chip,
     # copy-done may move a pool this small between memory spaces
     assert {layout for layout, _ in pool_ops} == {"3,2,1,0"}, pool_ops
     assert "copy" not in {op for _, op in pool_ops}, pool_ops
+    _one_lg_and_one_e(hlo, eng.max_batch, cfg["vocab_size"])
+
+
+def _llama_engine():
+    """The chat-batch cell's family at a small width: heads 4/2 of 128, two
+    layers, bf16 (the lm head's logits are bf16 widened, as in the cell)."""
+    from _lfm2_util import engine
+    from chipbench.adapters import llama_block
+
+    cfg = dict(vocab_size=4096, hidden_size=512, intermediate_size=1024,
+               num_hidden_layers=2, num_attention_heads=4,
+               num_key_value_heads=2, rms_norm_eps=1e-5, rope_theta=1e6)
+    model = llama_block.build_model(cfg, max_positions=512, dtype="bfloat16")
+    return engine(model, max_batch=8, max_len=512, page_size=16,
+                  block_size=4), cfg["vocab_size"]
+
+
+def _nemotron_engine():
+    """The chat-short-batch-32 cell's family, all three mixers, tiny."""
+    from _nemotron_h_util import TINY, engine
+    from chipbench.adapters import nemotron_h_block
+
+    model = nemotron_h_block.build_model(TINY, max_positions=128,
+                                         dtype="bfloat16")
+    return engine(model, max_batch=8, max_len=64, page_size=4,
+                  block_size=4), TINY["vocab_size"]
+
+
+@pytest.mark.parametrize("family", [_llama_engine, _nemotron_engine],
+                         ids=["llama", "nemotron_h"])
+def test_a_sampling_decode_block_computes_lg_and_e_once(family, one_chip):
+    """The other two families' sampling blocks, compiled for the described
+    v5e (plain paths: what is held here is the sampler's fusions beside a
+    model's, not a kernel): one division and one ``exp`` over ``[rows, V]``,
+    and no sort of the vocabulary."""
+    eng, V = family()
+    hlo = _lowered_sampling_block(eng, one_chip).compile().as_text()
+    _one_lg_and_one_e(hlo, eng.max_batch, V)
 
 
 # (id, chunk rows, chunk tokens, kv heads, head_dim, pages a row, pages asked)
@@ -375,3 +443,22 @@ def test_relu2_dense_arm_compiles_for_v5e_at_32_rows_of_16_experts(one_chip):
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes >= 2 * 16 * 2688 * 1856 * 2
     assert ma.temp_size_in_bytes < 64e6
+
+
+@pytest.mark.parametrize("rows,V", [(24, 92544), (64, 65536), (32, 16384)],
+                         ids=["chat-batch", "chat-batch-64",
+                              "chat-short-batch-32"])
+def test_sampler_compiles_for_v5e_as_two_loops_and_no_sort(rows, V, one_chip):
+    """``sample_rows`` at the serving cells' shapes: the two searches stay
+    loops (32 and ``(V - 1).bit_length()`` trips, not unrolled) and nothing
+    is sorted (the sort alone took 13-22 s to compile, PR 39)."""
+    from paddle_tpu.models.generation_utils import fold_keys, sample_rows
+
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    text = jax.jit(lambda lg, seeds, pos, t, p, k: sample_rows(
+        lg, fold_keys(seeds, pos), t, p, k)).trace(
+        sds((rows, V), jnp.float32), sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32), sds((rows,), jnp.float32),
+        sds((rows,), jnp.float32), sds((rows,), jnp.int32)).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert " sort(" not in text and text.count(" while(") == 2
