@@ -136,6 +136,9 @@ class KVChainCodec:
         (``withdraw_active``) only after the bytes are safely out."""
         if engine.prefix_cache is None:
             raise ValueError("KV-chain export needs a prefix-cache engine")
+        engine._refuse_over_groups(
+            ("chain export (KVChainCodec: PTKV1 carries one chain of pages "
+             "a layer)", True))
         slot = engine.slot_of(rid)
         if slot is None:
             raise KeyError(f"rid {rid} holds no active slot")
@@ -311,6 +314,9 @@ class KVChainCodec:
             self._verify(hdr, payload)
         if engine.prefix_cache is None:
             raise ValueError("KV-chain splice needs a prefix-cache engine")
+        engine._refuse_over_groups(
+            ("the KV-chain splice (import_chain: PTKV1 carries one chain of "
+             "pages a layer)", True))
         kv = engine.caches["kv"]
         require_kv_layers(kv, "the KV-chain splice (import_chain)")
         # the chain is in the logical order; a lane-dense pool folds it on

@@ -85,6 +85,17 @@ block holds position L-1 k/v written by the first-token re-step's decode
 program, so a LONGER prompt extending that chain reads re-step k/v where
 its own cold prefill would have run the chunk-prefill program — the values
 are mathematically equal but may differ in the last ulp under bf16 on TPU.
+
+**A page group a layer kind** (docs/SERVING.md "Window and full layers: a
+page group a kind"): a model whose attention layers read different spans of
+their history (``kv_groups()``: the full group, then a window a kind) gets a
+pool, an allocator, a parking page and a device table a group from
+``ops.paged_attention.PageGroups``, which the constructor, admission, the
+packed prefill, the decode block and ``_release_slot`` ask; a window
+group's pool does not depend on ``max_len``, a sequence maps its pages
+ahead of each program and gives back those behind its window after it, and
+a prefix hit needs both groups' cover. Every other model has the one group
+and the programs it always had.
 """
 
 from __future__ import annotations
@@ -120,7 +131,7 @@ def _greedy(logits):
 # host-side page bookkeeping lives next to the paged kernels; re-exported
 # here as the serving-facing API surface
 from ..ops.paged_attention import (BlockAllocator, LayerStateError,
-                                   RadixPrefixCache, copy_layer_pages,
+                                   PageGroups, RadixPrefixCache,
                                    kernel_layers, layer_kinds,
                                    page_append_layers, pool_num_pages,
                                    state_bytes)
@@ -417,7 +428,9 @@ class PrefixCacheConfig:
       rows per packed prefill call (default ``max(8, min(max_batch, 32))``;
       the pack always covers at least one chunk per mid-prefill slot, so
       this only bounds the EXTRA rows that let short prompts finish in one
-      call)."""
+      call). With a window page group (docs/SERVING.md "Window and full
+      layers") one slot takes ``PageGroups.slot_rows`` of them at most: its
+      window pages are mapped that far ahead and no further."""
 
     prefill_chunk: Optional[int] = None
     extra_blocks: int = 0
@@ -838,6 +851,7 @@ class ContinuousBatchingEngine:
         self._mesh = None
         self._mesh_axis = None
         if mesh is not None:
+            self._refuse_over_groups(("a tp mesh", True))
             if prefix_cache is None:
                 raise ValueError(
                     "mesh-sharded serving needs a prefix cache "
@@ -867,8 +881,21 @@ class ContinuousBatchingEngine:
         if prefix_cache is not None:
             c = prefix_cache.prefill_chunk or min(max_len, 8 * page_size)
             self._chunk_tokens = -(-int(c) // page_size) * page_size
-            n_blocks = (max_batch * self._maxp
-                        + max(0, int(prefix_cache.extra_blocks)))
+            # one page group a layer kind that keeps K and V (PageGroups);
+            # a model that declares none has the one full group, sized
+            # max_batch * pages a sequence + extra_blocks as ever
+            self._pack_rows = (max(8, min(max_batch, 32))
+                               if prefix_cache.pack_rows is None
+                               else max(1, int(prefix_cache.pack_rows)))
+            self._groups = PageGroups(
+                getattr(model, "kv_groups", lambda: [("full", None)])(),
+                max_batch=max_batch, max_len=max_len, page_size=page_size,
+                chunk=self._chunk_tokens, block=self.block_size,
+                extra_blocks=prefix_cache.extra_blocks)
+            self._refuse_over_groups(
+                ("speculative decoding", self._spec is not None),
+                ("an int8 KV pool", self._kv_dtype == "int8"))
+            n_blocks = self._groups.full.num_blocks
             # +1 page: parked decode rows (free / still-prefilling slots)
             # write their dummy token into a dedicated parking page, never
             # into a block another request may share. The pools come back
@@ -880,25 +907,33 @@ class ContinuousBatchingEngine:
             self.caches = model._init_paged_caches(
                 max_batch, max_len, page_size, num_blocks=n_blocks + 1,
                 kv_dtype=self._kv_dtype,
-                kv_shards=1 if mesh is None else int(mesh.tp))
-            self._park = n_blocks
-            self._alloc = BlockAllocator(n_blocks)
-            self._radix = RadixPrefixCache(page_size, self._alloc)
-            # the device table starts all-parked; only _flush_updates'
-            # scatters write it afterwards (no host table exists)
+                kv_shards=1 if mesh is None else int(mesh.tp),
+                **({} if self._groups.single else
+                   {"group_blocks": self._groups.pool_pages()}))
+            # the FULL group's: what every engine had before there were
+            # groups, and what the fault drills and the benchmark's
+            # kv_pool_used_share read
+            self._park = self._groups.full.park
+            self._alloc = self._groups.full.alloc
+            self._radix = self._groups.radix
+            # the device tables (one a group; one array with one group)
+            # start all-parked; only _flush_updates' scatters write them
+            # afterwards (no host table exists)
             self.caches = {"kv": self.caches["kv"],
-                           "tables": jnp.full((max_batch, self._maxp),
-                                              self._park, jnp.int32)}
+                           "tables": jax.tree_util.tree_map(
+                               jnp.asarray,
+                               self._groups.parked(max_batch))}
             self._slot_rows: List[Optional[np.ndarray]] = [None] * max_batch
             self._slot_blocks: List[Optional[List[int]]] = [None] * max_batch
             self._prefill_next: Dict[int, int] = {}
             self._jit_chunk: Dict[int, object] = {}
             self._jit_first: Dict[tuple, object] = {}
             self._jit_cow_batch: Dict[int, object] = {}
-            self._pack_rows = (max(8, min(max_batch, 32))
-                               if prefix_cache.pack_rows is None
-                               else max(1, int(prefix_cache.pack_rows)))
         else:
+            self._groups = None
+            self._refuse_over_groups(
+                ("an engine without a prefix cache (slot-owned pages of one "
+                 "size)", True))
             self.caches = model._init_paged_caches(max_batch, max_len,
                                                    page_size,
                                                    kv_dtype=self._kv_dtype)
@@ -1057,6 +1092,33 @@ class ContinuousBatchingEngine:
                       "seq_state_starts": 0,
                       "seq_state_runs": 0,
                       "prefix_declined_admissions": 0}
+        if self._groups is not None:
+            # the page groups (one a layer kind that keeps K and V): how
+            # many, each group's pool and the pages live sequences map
+            # (a gauge, set a step), its layers the kernel reads; window
+            # pages mapped fresh and given back by a LIVING sequence;
+            # window_pages_in_use_steps sums the window groups' pages in
+            # use over the steps (over steps x their pools: the mean
+            # share); hits honoured shorter than the trie matched
+            self._layer_groups = getattr(
+                model, "kv_layer_groups",
+                lambda: [0] * len(self.caches["kv"]))()
+            self.stats["kv_groups"] = len(self._groups.groups)
+            self._group_gauges = [(f"kv_pages_in_use.{g.kind}", g)
+                                  for g in self._groups.groups]
+            for gi, g in enumerate(self._groups.groups):
+                self.stats[f"kv_pool_pages.{g.kind}"] = g.num_blocks
+                self.stats[f"kv_pages_in_use.{g.kind}"] = 0
+                # a family of its own: the unlabeled ``paged_kernel_layers``
+                # is the sum, and one family would count the layers twice
+                self.stats[f"paged_kernel_layers_by_group.{g.kind}"] = \
+                    kernel_layers([e for e, at in zip(
+                        self.caches["kv"], self._layer_groups)
+                        if at == gi])[0]
+            self.stats.update(window_pages_released=0,
+                              window_pages_allocated=0,
+                              window_pages_in_use_steps=0,
+                              prefix_hits_shortened=0)
         # per-program collective census (label -> per-dispatch wire bytes),
         # filled lazily as each sharded program first dispatches — feeds
         # the serving collector and mirrors the PT-COMM contract entries
@@ -1164,6 +1226,20 @@ class ContinuousBatchingEngine:
         return out
 
     # ---- public API ----
+    def _refuse_over_groups(self, *whats):
+        """``LayerStateError`` naming the kinds, for each ``(what, asked)``
+        that a model with more than one page group was asked for."""
+        decl = getattr(self.model, "kv_groups", list)()
+        if len(decl) < 2:
+            return
+        for what, asked in whats:
+            if asked:
+                raise LayerStateError(
+                    f"PT-SRV-009: {type(self.model).__name__} keeps layers "
+                    f"of kinds {[k for k, _ in decl]} (a page group a kind, "
+                    f"windows {[w for _, w in decl]}); they cannot be "
+                    f"served by {what}")
+
     def add_request(self, req: Request) -> int:
         if self.max_queue is not None and len(self._queue) >= self.max_queue:
             raise EngineSaturated(
@@ -1179,12 +1255,12 @@ class ContinuousBatchingEngine:
                 f"prompt {len(req.prompt)} exceeds largest prompt bucket "
                 f"{self.prompt_buckets[-1]}")
         if self.prefix_cache is not None:
-            need = self._pages_needed(len(req.prompt), req.max_new_tokens)
-            if need > self._alloc.num_blocks:
+            short = self._groups.shortfall(
+                self._pages_needed(len(req.prompt), req.max_new_tokens))
+            if short:
                 raise ValueError(
-                    f"request needs {need} KV blocks but the pool holds "
-                    f"{self._alloc.num_blocks} — raise "
-                    "PrefixCacheConfig.extra_blocks or shrink the request")
+                    f"{short} — raise PrefixCacheConfig.extra_blocks or "
+                    "shrink the request")
         # family-specific length limits (e.g. GPT's learned position table) —
         # the same validation generate() applies
         validate = getattr(self.model, "_validate_generate", None)
@@ -1315,6 +1391,8 @@ class ContinuousBatchingEngine:
         starved0 = stats["device_starved_s"]
         maybe0 = stats["device_maybe_starved_s"]
         sched0 = self._sched_tokens
+        windowed = self._groups is not None and self._groups.windowed
+        released0 = self._groups.released if windowed else 0
         self._deferred_step = False
         try:
             with self._span("serve.step", step=self._step_idx,
@@ -1323,6 +1401,9 @@ class ContinuousBatchingEngine:
                 try:
                     self._step_inner()
                 finally:
+                    if windowed:
+                        sp.set(window_pages_released=self._book_window()
+                               - released0)
                     flight.upto(_time.perf_counter())
                     sp.set(
                         starved_us=round(
@@ -1355,6 +1436,25 @@ class ContinuousBatchingEngine:
                                    else 0.7 * self._ema_tok_s + 0.3 * rate)
             if self._brownout_cfg is not None:
                 self._brownout_tick()
+
+    def _book_groups(self):
+        """The page groups' gauges into ``stats``, once a step, where pages
+        are mapped (inside ``pt.serve.admit``)."""
+        stats = self.stats
+        for key, group in self._group_gauges:
+            stats[key] = group.in_use
+        if self._groups.windowed:
+            stats["window_pages_in_use_steps"] += sum(
+                g.in_use for g in self._groups.windowed)
+
+    def _book_window(self) -> int:
+        """The window groups' running counts into ``stats``, at a step's
+        end; returns the pages given back so far."""
+        stats, groups = self.stats, self._groups
+        stats["window_pages_released"] = groups.released
+        stats["window_pages_allocated"] = groups.allocated
+        stats["prefix_hits_shortened"] = groups.shortened
+        return groups.released
 
     def _brownout_tick(self):
         """Hysteretic brownout state machine (docs/SERVING.md), evaluated
@@ -1420,6 +1520,8 @@ class ContinuousBatchingEngine:
         with self._span("serve.admit") as sp:
             q0 = len(self._queue)
             self._admit()
+            if self._groups is not None:
+                self._book_groups()
             sp.set(admitted=q0 - len(self._queue),
                    deferred=int(self._deferred_step))
 
@@ -1479,12 +1581,15 @@ class ContinuousBatchingEngine:
             # never rebuilds or uploads a [max_batch, pages] table. Rows of
             # free and still-prefilling slots stay on the parking page, so
             # the scan's dummy append can never touch a shared block
-            self._flush_updates()
             # O(active): the decode set comes from the occupied dict (in
             # slot order), never a max_batch scan
             live = [(i, r) for i, r in sorted(self._occupied.items())
                     if not (self.prefix_cache is not None
                             and i in self._prefill_next)]
+            if live and self._groups is not None \
+                    and not self._groups.single:
+                self._reserve_ahead(live)
+            self._flush_updates()
             if not live:
                 return
             # all-greedy block with verify-window headroom on every row
@@ -1507,6 +1612,36 @@ class ContinuousBatchingEngine:
             return self._decode_spec_block(live)
         self._book_block(live, n, async_ok, out, seq)
 
+    def _reserve_ahead(self, live):
+        """Window groups: map pages for the positions the next decode block
+        may write (a block never runs past ``advance``, ``_block_plan``),
+        ``advance`` positions ahead at a time, so a row's table is rewritten
+        once in ``advance / block`` blocks: the new row rides the slot's
+        next update with the position, the flag and the sampling it has."""
+        groups = self._groups
+        horizon = (groups.advance if self._async_block(live)
+                   else min(self.block_size, groups.advance))
+        for i, req in live:
+            pos = int(self._pos[i])
+            end = min(self.max_len, pos + req.max_new_tokens - req._n_out)
+            if groups.reserved(i) >= min(end, pos + horizon):
+                continue
+            got = groups.reserve(i, min(end, pos + groups.advance))
+            if got is None:
+                raise RuntimeError(
+                    f"PT-SRV-012: no window page for rid={req.rid} (slot "
+                    f"{i}) at position {pos}: a window group's pool holds "
+                    f"what every slot may map at once, so pages are held "
+                    f"outside the engine's account")
+            if got:
+                self._queue_update(i, groups.rows(i, self._slot_rows[i]),
+                                   pos, True, req.seed, req.temperature,
+                                   req.top_p, req.top_k)
+
+    @staticmethod
+    def _async_block(live) -> bool:
+        return all(r.eos_token_id is None for _, r in live)
+
     def _block_plan(self, live):
         """(scan length, whether no row carries an eos id, whether any row
         samples) of the next decode block."""
@@ -1514,8 +1649,11 @@ class ContinuousBatchingEngine:
         # engine max_len (pages beyond the table would clamp-corrupt)
         cap = min(min(r.max_new_tokens - r._n_out for _, r in live),
                   min(self.max_len - int(self._pos[i]) for i, _ in live))
+        if self._groups is not None and not self._groups.single:
+            # a window group maps pages ``advance`` positions ahead
+            cap = min(cap, self._groups.advance)
         n = min(self.block_size, cap)
-        async_ok = all(r.eos_token_id is None for _, r in live)
+        async_ok = self._async_block(live)
         if async_ok:
             # run toward the next completion event; allowed scan lengths are
             # block_size * 2^k so the compiled-program set stays O(log) in
@@ -1604,6 +1742,8 @@ class ContinuousBatchingEngine:
                         finished += 1
                         self._mark_done(req)
                         self._release_slot(i)   # slot + pages are free again
+                    else:
+                        self._moved_on(i)
                 sp.set(tokens=sum(e[2] for e in entries), finished=finished,
                        scheduled=True)
             # the rows' token progress is stamped when the values reach the
@@ -1642,9 +1782,21 @@ class ContinuousBatchingEngine:
                     finished += 1
                     self._mark_done(req)
                     self._release_slot(i)   # slot + its pages are free again
+                else:
+                    self._moved_on(i)
             sp.set(tokens=block_tokens, finished=finished)
         if tok_marks:
             self.tracer.tokens_batch(tok_marks, tags=self.trace_tags)
+
+    def _moved_on(self, slot: int, pos: Optional[int] = None):
+        """A living slot moved on: its next query sits at ``pos`` (a
+        decoding slot's at ``_pos - 1``, where its next token is written),
+        and the window-group pages that no query from there on reads go
+        back to their pool (the device table keeps the stale entries; no
+        reader looks behind the window)."""
+        if self._groups is not None and not self._groups.single:
+            self._groups.release_behind(
+                slot, int(self._pos[slot]) - 1 if pos is None else pos)
 
     def run_until_done(self, max_steps: int = 100000):
         steps = 0
@@ -1763,6 +1915,9 @@ class ContinuousBatchingEngine:
             raise ValueError("KV-chain splice needs a prefix-cache engine "
                              "(dynamic block tables over the refcounted "
                              "pool)")
+        self._refuse_over_groups(
+            ("a migrated chain (admit_migrated: PTKV1 carries one chain of "
+             "pages a layer)", True))
         if self._seq_layers:
             raise LayerStateError(
                 f"PT-SRV-009: layers {self._seq_layers} are of kind 'seq' "
@@ -1872,6 +2027,7 @@ class ContinuousBatchingEngine:
             self._slot_blocks[i] = None
             self._slot_rows[i] = None
             self._prefill_next.pop(i, None)
+            self._groups.release(i)
         self._queue_update(i, None, 0, False)
 
     # -- mega-step machinery (module docstring / docs/SERVING.md) ----------
@@ -1887,8 +2043,7 @@ class ContinuousBatchingEngine:
         ``hist`` (spec engines) is the slot's drafter seed ``(ring_row,
         hlen)`` — None resets the ring (release / non-spec engines ignore
         it)."""
-        self._upd[slot] = (None if row is None else np.asarray(row, np.int32),
-                           int(pos), bool(act), int(seed), float(temp),
+        self._upd[slot] = (row, int(pos), bool(act), int(seed), float(temp),
                            float(top_p), int(top_k), hist)
 
     def _flush_updates(self):
@@ -1908,7 +2063,9 @@ class ContinuousBatchingEngine:
                                hist, hlen, idx, urows, upos, uact, useeds,
                                utemps, utops, utopks, uhist, uhlen):
                 if with_tables:
-                    tables = tables.at[idx].set(urows)
+                    # one table a page group (a lone array with one group)
+                    tables = jax.tree_util.tree_map(
+                        lambda t, u: t.at[idx].set(u), tables, urows)
                 if with_spec:
                     hist = hist.at[idx].set(uhist)
                     hlen = hlen.at[idx].set(uhlen)
@@ -1929,7 +2086,7 @@ class ContinuousBatchingEngine:
             # the apply program ignores urows, so don't build/upload the
             # [W, maxp] buffer at all (a 1-element dummy keeps the
             # signature); same for the drafter ring on non-spec engines
-            urows = (np.full((W, self._maxp), self._park, np.int32)
+            urows = (self._groups.parked(W)
                      if with_tables else np.zeros((1, 1), np.int32))
             uhist = (np.zeros((W, H), np.int32) if with_spec
                      else np.zeros((1, 1), np.int32))
@@ -1944,7 +2101,7 @@ class ContinuousBatchingEngine:
                            hist_seed)) in enumerate(batch):
                 idx[j] = slot
                 if with_tables and row is not None:
-                    urows[j] = row
+                    self._groups.put(urows, j, row)
                 if with_spec and hist_seed is not None:
                     uhist[j], uhlen[j] = hist_seed
                 upos[j] = pos
@@ -2432,24 +2589,29 @@ class ContinuousBatchingEngine:
         W = 1
         while W < len(pairs):
             W *= 2
+        groups = self._groups
         fn = self._jit_cow_batch.get(W)
         if fn is None:
             def pt_cow_copy(kv, src, dst):
-                return [copy_layer_pages(e, src, dst) for e in kv]
+                return groups.copy_pages(kv, self._layer_groups, src, dst)
 
             fn = self._jit_cow_batch[W] = jax.jit(pt_cow_copy)
             self._note_compiled()
-        src = np.full(W, self._park, np.int32)
-        dst = np.full(W, self._park, np.int32)
-        for j, (s, d) in enumerate(pairs):
-            src[j] = s
-            dst[j] = d
+        # a pair is (src, dst) of the full group, then of each window group
+        src = [np.full(W, g.park, np.int32) for g in groups.groups]
+        dst = [x.copy() for x in src]
+        for j, pair in enumerate(pairs):
+            for gi, (s, d) in enumerate(pair):
+                src[gi][j] = s
+                dst[gi][j] = d
+        as_args = lambda xs: jax.tree_util.tree_map(jnp.asarray,
+                                                    groups.parts(xs))
         self.caches = {"kv": self._call_built("pt_cow_copy", W, fn,
                                               self.caches["kv"],
-                                              jnp.asarray(src),
-                                              jnp.asarray(dst)),
+                                              as_args(src), as_args(dst)),
                        "tables": self.caches["tables"]}
-        self._alloc.decref([s for s, _ in pairs])
+        for gi, g in enumerate(groups.groups):
+            g.alloc.decref([pair[gi][0] for pair in pairs])
 
     def _pages_needed(self, prompt_len: int, max_new: int) -> int:
         return -(-(prompt_len + max_new) // self.page_size)
@@ -2525,6 +2687,12 @@ class ContinuousBatchingEngine:
         matched = (self._radix.match(prompt[: n_full * page])
                    if n_full and not self._brownout_active
                    and not self._seq_layers else [])
+        if matched:
+            # what every page group still covers of it where the request
+            # will read (PageGroups.honour: all of it with one group)
+            matched = self._groups.honour(matched)
+            if not matched:
+                self.stats["prefix_declined_admissions"] += 1
         if self._seq_layers:
             # no page carries the state a hit would resume from: the trie is
             # neither asked nor fed (_emit_first), and the prompt prefills
@@ -2551,6 +2719,15 @@ class ContinuousBatchingEngine:
         if fresh is None:
             self._alloc.decref(pinned)
             return False                       # pool exhausted — defer
+        # the window groups' pages: the hit's, and fresh ones as far as the
+        # first packed call writes (the rest as the sequence goes)
+        cached = (len(matched) + (cow_src is not None)) * page
+        window_cow = self._groups.admit(
+            slot, matched, cow_src,
+            min(need * page, cached + self._groups.advance))
+        if window_cow is None:
+            self._alloc.decref(pinned + fresh)
+            return False                       # a window group is short
         # int8 block hygiene BEFORE any write (incl. the COW copy below,
         # which overwrites its dst wholesale anyway): recycled pages must
         # not leak their previous occupant's absmax scale into this
@@ -2562,7 +2739,7 @@ class ContinuousBatchingEngine:
             # the whole admission wave's COW copies batch into one program
             # (_cow_copy_batch); the source stays pinned until that
             # dispatch so eviction cannot reclaim it first
-            cow_wave.append((cow_src, dst))
+            cow_wave.append([(cow_src, dst)] + [c[0] for c in window_cow])
             self.stats["cow_copies"] += 1
             blocks = matched + [dst] + fresh[1:]
             cached = len(prompt)
@@ -2657,10 +2834,13 @@ class ContinuousBatchingEngine:
         admission path. Pads inside the final partially-filled prompt page
         still land there (same bytes on every path: pad k/v depends only
         on the pad token id and its absolute position)."""
-        row = np.full(self._maxp, self._park, np.int32)
-        n_real = -(-len(req.prompt) // self.page_size)
-        row[:n_real] = self._slot_rows[s][:n_real]
-        return row
+        return self._groups.prompt_rows(
+            self._rows_of(s), -(-len(req.prompt) // self.page_size))
+
+    def _rows_of(self, slot: int):
+        """A slot's table rows as the programs take them: the full group's
+        [maxp] row, or with window groups a tuple of one row a group."""
+        return self._groups.rows(slot, self._slot_rows[slot])
 
     def _chunk_fn(self, g: int):
         """The compiled prefill-chunk program for ``g`` rows
@@ -2722,6 +2902,12 @@ class ContinuousBatchingEngine:
         C = self._chunk_tokens
         budget = max(len(group), self._pack_rows)
         offs = {s: self._prefill_next[s] for s, _ in group}
+        # window groups: a row's window pages are mapped before it is
+        # taken, never more than ``advance`` ahead (a slot takes slot_rows
+        # rows a call), and a slot a group has no page for this step waits
+        windowed = not self._groups.single
+        takes = {s: self._groups.slot_rows if windowed else budget
+                 for s, _ in group}
         rows = []
         progress = True
         while len(rows) < budget and progress:
@@ -2729,10 +2915,18 @@ class ContinuousBatchingEngine:
             for s, req in group:
                 if len(rows) >= budget:
                     break
-                if offs[s] < len(req.prompt):
+                if offs[s] < len(req.prompt) and takes[s] > 0:
+                    end = min(offs[s] + C, len(req.prompt))
+                    if windowed and self._groups.reserve(s, end) is None:
+                        takes[s] = 0
+                        self._deferred_step = True
+                        continue
+                    takes[s] -= 1
                     rows.append((s, req, offs[s]))
-                    offs[s] = min(offs[s] + C, len(req.prompt))
+                    offs[s] = end
                     progress = True
+        if not rows:
+            return
         if self._seq_layers:
             rows.sort(key=lambda row: (row[0], row[2]))
             self.stats["seq_state_runs"] += self._seq_runs(rows)
@@ -2748,7 +2942,7 @@ class ContinuousBatchingEngine:
         # token, which the first-token program steps at its true position
         seq_slot = np.full(g, self.max_batch, np.int32)
         seq_keep = np.zeros(g, np.int32)
-        trows = np.full((g, self._maxp), self._park, np.int32)
+        trows = self._groups.parked(g)
         for r, (s, req, off) in enumerate(rows):
             if off % self.page_size:
                 raise PageAlignmentError(
@@ -2761,10 +2955,11 @@ class ContinuousBatchingEngine:
             real[r] = len(chunk)
             seq_slot[r] = s
             seq_keep[r] = min(len(chunk), len(req.prompt) - 1 - off)
-            trows[r] = self._prefill_row(s, req)
+            self._groups.put(trows, r, self._prefill_row(s, req))
         new_kv = self._call_built(
             "pt_prefill_chunk", g, self._chunk_fn(g), self._params,
-            jnp.asarray(ids), self.caches["kv"], jnp.asarray(trows),
+            jnp.asarray(ids), self.caches["kv"],
+            jax.tree_util.tree_map(jnp.asarray, trows),
             jnp.asarray(starts),
             *([jnp.asarray(real)] if self._state_layers else []),
             *([jnp.asarray(seq_slot), jnp.asarray(seq_keep)]
@@ -2778,9 +2973,24 @@ class ContinuousBatchingEngine:
             nxt = self._prefill_next[s]
             if offs[s] > nxt:
                 self._prefill_next[s] = offs[s]
+                if windowed:
+                    self._prompt_written(s, req, offs[s])
                 if self.tracer is not None:
                     self.tracer.prefill_chunk(req.rid, t0_tr, offs[s] - nxt,
                                               tags=self.trace_tags)
+
+    def _prompt_written(self, slot: int, req: "Request", upto: int):
+        """Window groups, after a packed call wrote the prompt as far as
+        ``upto``: the whole pages before the prompt's last token go into
+        the trie (the last token's page waits for the first-token program,
+        which rewrites it: ``_emit_first``), and only then does the slot
+        let go of the window pages the rest of the prompt will not read."""
+        page = self.page_size
+        n = min(upto, len(req.prompt) - 1) // page
+        if n and not self._brownout_active and not self._seq_layers:
+            self._groups.written(slot, req.prompt[: n * page],
+                                 self._slot_blocks[slot][:n])
+        self._moved_on(slot, min(upto, len(req.prompt) - 1))
 
     def _seq_runs(self, rows) -> int:
         """The runs of adjacent rows of one slot among a pack's rows
@@ -2819,7 +3029,7 @@ class ContinuousBatchingEngine:
             g *= 2
         do_sample = any(r.temperature > 0.0 for _, r in ready)
         last = np.zeros(g, np.int32)
-        rows = np.full((g, self._maxp), self._park, np.int32)
+        rows = self._groups.parked(g)
         ints = np.zeros((g, 4), np.int32)
         ints[:, 0] = 1                       # dummy rows re-step position 0
         ints[:, 3] = self.max_batch          # dummy scatter index: dropped
@@ -2827,7 +3037,7 @@ class ContinuousBatchingEngine:
         floats[:, 1] = 1.0
         for r, (s, req) in enumerate(ready):
             last[r] = req.prompt[-1]
-            rows[r] = self._slot_rows[s]
+            self._groups.put(rows, r, self._rows_of(s))
             ints[r] = (len(req.prompt), req.seed, req.top_k, s)
             floats[r] = (req.temperature, req.top_p)
         fn = self._jit_first.get((g, do_sample))
@@ -2866,8 +3076,8 @@ class ContinuousBatchingEngine:
         firsts_dev, new_kv, self._last_tok = self._call_built(
             "pt_first_token", (g, do_sample), fn,
             self._params, jnp.asarray(last), self.caches["kv"],
-            jnp.asarray(rows), self._last_tok, jnp.asarray(ints),
-            jnp.asarray(floats))
+            jax.tree_util.tree_map(jnp.asarray, rows), self._last_tok,
+            jnp.asarray(ints), jnp.asarray(floats))
         self.caches = {"kv": new_kv, "tables": self.caches["tables"]}
         seq = self._flight.called
         any_eos = any(r.eos_token_id is not None for _, r in ready)
@@ -2895,19 +3105,21 @@ class ContinuousBatchingEngine:
                 # these writes; first writer wins on duplicate chains.
                 # Brownout skips registration: blocks must return to the
                 # pool the moment the request finishes, not linger cached.
-                self._radix.insert(req.prompt[: n_full * self.page_size],
-                                   self._slot_blocks[slot][:n_full])
+                self._groups.written(
+                    slot, req.prompt[: n_full * self.page_size],
+                    self._slot_blocks[slot][:n_full])
             del self._prefill_next[slot]
             req._n_out += 1
             self._sched_tokens += 1
             if ft_marks is not None:
                 ft_marks.append((req.rid, req._n_out))
             self._pos[slot] = len(req.prompt) + 1
+            self._moved_on(slot)
             # activation rides the next traced scatter: table row,
             # position, active flag, sampling params — and on spec engines
             # the drafter ring seeded with the prompt — in one update (the
             # device table is authoritative)
-            self._queue_update(slot, self._slot_rows[slot],
+            self._queue_update(slot, self._rows_of(slot),
                                len(req.prompt) + 1, True, req.seed,
                                req.temperature, req.top_p, req.top_k,
                                hist=(self._spec_seed(req.prompt)

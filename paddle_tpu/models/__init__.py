@@ -6,12 +6,14 @@ semi_auto_llama.py:33, test/auto_parallel GPT tests). Here the model families ar
 first-class: mesh-aware (logical-axis sharding), remat-capable, jit-first.
 """
 
+from . import afmoe  # noqa: F401
 from . import bert  # noqa: F401
 from . import gpt  # noqa: F401
 from . import lfm2  # noqa: F401
 from . import llama  # noqa: F401
 from . import nemotron_h  # noqa: F401
 from . import unet  # noqa: F401
+from .afmoe import AfmoeConfig, AfmoeForCausalLM  # noqa: F401
 from .bert import BertConfig, BertForMaskedLM, BertForSequenceClassification  # noqa: F401
 from .gpt import GPTConfig, GPTForCausalLM  # noqa: F401
 from .lfm2 import Lfm2Config, Lfm2ForCausalLM  # noqa: F401

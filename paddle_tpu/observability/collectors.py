@@ -62,14 +62,16 @@ __all__ = ["checkpoint_collector", "engine_collector", "fleet_collector",
 
 def _stat_families(prefix: str, stats: dict, kinds: dict,
                    **labels) -> List[MetricFamily]:
-    out = []
+    out = {}
     for key, val in stats.items():
         if not isinstance(val, (int, float)):
             continue
-        name = f"{prefix}_{key}"
-        kind = kinds.get(key, "counter")
-        out.append(MetricFamily(name, kind).add(float(val), **labels))
-    return out
+        # "kv_pool_pages.sliding": one family, a sample a page group
+        key, _, group = key.partition(".")
+        fam = out.setdefault(key, MetricFamily(
+            f"{prefix}_{key}", kinds.get(key, "counter")))
+        fam.add(float(val), **labels, **({"group": group} if group else {}))
+    return list(out.values())
 
 
 # stats-dict keys that are level readings, not monotonic totals
@@ -77,7 +79,12 @@ _ENGINE_GAUGE_KEYS = {"compile_cache_entries", "step_max_s",
                       "step_max_wait_s", "state_snapshot_bytes",
                       "seq_state_bytes",
                       "kv_layers", "paged_kernel_layers",
-                      "page_append_layers"}
+                      "page_append_layers",
+                      # the page groups (docs/SERVING.md "Window and full
+                      # layers"): how many, and a group's pool, pages in
+                      # use and kernel layers under a ``group`` label
+                      "kv_groups", "kv_pool_pages", "kv_pages_in_use",
+                      "paged_kernel_layers_by_group"}
 # stats-dict keys NOT exported from engine.stats: "evictions" is a lagging
 # copy of radix.evictions (synced only at admit/brownout time) and the
 # collector already exports the live value as pt_radix_evictions_total —

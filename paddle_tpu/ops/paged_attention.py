@@ -45,6 +45,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -463,8 +464,10 @@ def _gather_pages(cache, tables, d):
 # ---------------------------------------------------------------------------
 
 def paged_decode_reference(q, k_cache, v_cache, block_tables, context_lens,
-                           scale=None):
-    """Dense-gather paged decode: q [b, hq, d] -> out [b, hq, d]."""
+                           scale=None, window: Optional[int] = None):
+    """Dense-gather paged decode: q [b, hq, d] -> out [b, hq, d]. With a
+    ``window`` the query (at position ``len - 1``) sees the last ``window``
+    positions only, its own among them."""
     b, hq, d = q.shape
     page = k_cache.shape[2]
     hkv = k_cache.shape[1] * _pool_fold(k_cache, d)
@@ -483,7 +486,10 @@ def paged_decode_reference(q, k_cache, v_cache, block_tables, context_lens,
     qf = q.reshape(b, hkv, group, d).astype(jnp.float32)
     s = jnp.einsum("bhgd,bhld->bhgl", qf, kg.astype(jnp.float32)) * scale
     pos = jnp.arange(max_pages * page)[None, None, None, :]
-    s = jnp.where(pos < context_lens[:, None, None, None], s, NEG_INF)
+    keep = pos < context_lens[:, None, None, None]
+    if window is not None:
+        keep &= pos >= context_lens[:, None, None, None] - window
+    s = jnp.where(keep, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgl,bhld->bhgd", p, vg.astype(jnp.float32))
     # zero-length rows (freed/parked slots) return zeros, not garbage
@@ -516,13 +522,19 @@ def _decode_chunk_pages(max_pages, hkv, page, d, itemsize):
 
 def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
                          k_buf, v_buf, sem, slot_ref, *, page, C, max_pages,
-                         scale, batch):
+                         scale, batch, window=None):
     bi = pl.program_id(0)
     hkv, group, d = q_ref.shape[1:]
     CT = C * page
 
+    def first_page(row):
+        """The first page a windowed row's walk reads, the one that holds
+        position ``len - window``: the pages behind it are not read."""
+        return jnp.maximum(lens_ref[row] - window, 0) // page
+
     def row_pages(row):
-        return jnp.minimum((lens_ref[row] + page - 1) // page, max_pages)
+        n = jnp.minimum((lens_ref[row] + page - 1) // page, max_pages)
+        return n if window is None else n - first_page(row)
 
     def copies(slot, pidx, g):
         return (pltpu.make_async_copy(k_hbm.at[pidx], k_buf.at[slot, :, g],
@@ -534,7 +546,10 @@ def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         """Fetch the first ``n`` pages of chunk ``c`` of ``row``: one DMA a
         page and pool side, [kv_heads, page, d] each."""
         def body(g, _):
-            pidx = jnp.maximum(tables_ref[row * max_pages + c * C + g], 0)
+            at = row * max_pages + c * C + g
+            if window is not None:
+                at = at + first_page(row)
+            pidx = jnp.maximum(tables_ref[at], 0)
             for cp in copies(slot, pidx, g):
                 cp.start()
             return 0
@@ -591,7 +606,14 @@ def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
         pos = c * CT + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos < ctx, s, NEG_INF)                # [hkv, group, CT]
+        if window is None:
+            keep = pos < ctx
+        else:
+            # the walk starts mid-window's first page: the positions of it
+            # that lie behind the window are masked
+            pos = pos + first_page(bi) * page
+            keep = (pos < ctx) & (pos >= ctx - window)
+        s = jnp.where(keep, s, NEG_INF)                     # [hkv, group, CT]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
@@ -622,13 +644,14 @@ def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 @functools.lru_cache(maxsize=None)
 def _decode_call(b, hkv, group, w, page, C, max_pages, scale, q_dtype,
-                 k_dtype, v_dtype, interpret):
+                 k_dtype, v_dtype, interpret, window=None):
     """The ``pallas_call`` of one shape class, made once: every layer and
     every program of an engine calls the same object, so jax traces the
     kernel's body once a class and not once a call."""
     kernel = functools.partial(
         _paged_decode_kernel, page=page, C=C, max_pages=max_pages,
-        scale=scale, batch=b)
+        scale=scale, batch=b, **({} if window is None else
+                                 {"window": int(window)}))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
@@ -679,8 +702,16 @@ def _kernel_takes(pool) -> bool:
 
 
 def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
-                           scale=None, interpret: bool = False):
+                           scale=None, interpret: bool = False,
+                           window: Optional[int] = None):
     """One-token-per-sequence paged decode.
+
+    ``window`` (static; None: the whole history): the query, at position
+    ``len - 1``, sees positions ``>= len - window`` only. The kernel starts
+    its walk at the page that holds ``len - window`` and masks what lies
+    behind the window in that page: the pages behind it are not read, and
+    their table entries may be stale (a sequence gives those pages back
+    while it lives: docs/SERVING.md "Window and full layers").
 
     q: [batch, q_heads, head_dim]; caches [num_pages, kv_heads, page, d] or
     lane-dense [num_pages, kv_heads // f, page, 128], f = 128 // d, which
@@ -712,7 +743,7 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
     if isinstance(k_cache, QuantizedKVPool) or not interpret and (
             jax.default_backend() != "tpu" or not _kernel_takes(k_cache)):
         return paged_decode_reference(q, k_cache, v_cache, block_tables,
-                                      context_lens, scale)
+                                      context_lens, scale, window=window)
     f = _pool_fold(k_cache, d)
     _, hkv, page, w = k_cache.shape       # hkv head groups of f heads each
     group = hq // hkv                     # query rows a head group
@@ -727,7 +758,8 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
 
     call = _decode_call(b, hkv, group, w, page, C, max_pages, float(scale),
                         jnp.dtype(q.dtype), jnp.dtype(k_cache.dtype),
-                        jnp.dtype(v_cache.dtype), interpret)
+                        jnp.dtype(v_cache.dtype), interpret,
+                        *(() if window is None else (int(window),)))
     # the kernel's name reaches the HLO instruction and the scope its name
     # stack: traces find the kernel by name, not by a shape
     with jax.named_scope("pt_paged_decode"):
@@ -744,8 +776,16 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, context_lens,
 # chunk prefill over cached history (prefix cache / chunked-prefill path)
 # ---------------------------------------------------------------------------
 
+def window_chunk_pages(window: int, s: int, page: int, max_pages: int) -> int:
+    """Pages of a row's table that a chunk of ``s`` queries on a layer with
+    a ``window`` can see: from the page of ``start - window + 1`` to the
+    page of ``start + s - 1``, ``ceil((window + s) / page) + 1`` at most,
+    and never more than the table."""
+    return min(-(-(window + s) // page) + 1, max_pages)
+
+
 def paged_prefill_attention(q, k_cache, v_cache, block_tables, chunk_starts,
-                            scale=None):
+                            scale=None, window: Optional[int] = None):
     """Attention for a prefill CHUNK whose rows sit at per-row absolute
     offsets inside already-partially-filled paged caches.
 
@@ -770,7 +810,13 @@ def paged_prefill_attention(q, k_cache, v_cache, block_tables, chunk_starts,
     same program, bit-identical to sequential chunk calls.
     Stays an XLA gather+einsum (no Pallas
     kernel): prefill is projection/MLP-bound at serving chunk sizes and this
-    runs once per admitted chunk, unlike the per-token decode kernel."""
+    runs once per admitted chunk, unlike the per-token decode kernel.
+
+    ``window`` (static; None: the whole history): query ``p`` sees keys
+    ``p - window < j <= p``, and a row gathers and scores only the
+    ``window_chunk_pages`` pages its chunk can see, starting at the page of
+    ``start - window + 1``, not the table's extent: the entries behind it
+    may be stale."""
     b, s, hq, d = q.shape
     page = k_cache.shape[2]
     hkv = k_cache.shape[1] * _pool_fold(k_cache, d)
@@ -779,6 +825,15 @@ def paged_prefill_attention(q, k_cache, v_cache, block_tables, chunk_starts,
         scale = d ** -0.5
     max_pages = block_tables.shape[1]
     L = max_pages * page
+    if window is not None:
+        n = window_chunk_pages(int(window), s, page, max_pages)
+        L = n * page
+        first = jnp.maximum(chunk_starts - (int(window) - 1), 0) // page
+        idx = first[:, None] + jnp.arange(n)             # [b, n] page index
+        # past the table's end: any page, its positions lie past every query
+        block_tables = jnp.take_along_axis(
+            block_tables, jnp.minimum(idx, max_pages - 1), axis=1)
+        key_pos = (first * page)[:, None] + jnp.arange(L)[None, :]
     safe_tables = jnp.maximum(block_tables, 0)
     kg = jnp.swapaxes(_gather_pages(k_cache, safe_tables, d),
                       2, 3).reshape(b, L, hkv, d)
@@ -790,8 +845,12 @@ def paged_prefill_attention(q, k_cache, v_cache, block_tables, chunk_starts,
     qf = jnp.transpose(qf, (0, 2, 3, 1, 4))              # [b, hkv, g, s, d]
     sc = jnp.einsum("bhgsd,bhld->bhgsl", qf, kg) * scale
     q_pos = chunk_starts[:, None] + jnp.arange(s)        # [b, s] absolute
-    keep = (jnp.arange(L)[None, None, :]
-            <= q_pos[:, :, None])                        # [b, s, L]
+    if window is None:
+        keep = (jnp.arange(L)[None, None, :]
+                <= q_pos[:, :, None])                    # [b, s, L]
+    else:
+        keep = ((key_pos[:, None, :] <= q_pos[:, :, None])
+                & (key_pos[:, None, :] > q_pos[:, :, None] - int(window)))
     sc = jnp.where(keep[:, None, None, :, :], sc, NEG_INF)
     p = jax.nn.softmax(sc, axis=-1)
     out = jnp.einsum("bhgsl,bhld->bhgsd", p, vg)
@@ -800,7 +859,7 @@ def paged_prefill_attention(q, k_cache, v_cache, block_tables, chunk_starts,
 
 
 def paged_verify_attention(q, k_cache, v_cache, block_tables, row_starts,
-                           scale=None):
+                           scale=None, window: Optional[int] = None):
     """Speculative-decode VERIFY attention: score a K+1-token draft window
     per row in ONE pass (inference/serving.py speculative mega-step).
 
@@ -828,7 +887,7 @@ def paged_verify_attention(q, k_cache, v_cache, block_tables, row_starts,
     that shared body; changing only this wrapper changes tests, not
     serving."""
     return paged_prefill_attention(q, k_cache, v_cache, block_tables,
-                                   row_starts, scale)
+                                   row_starts, scale, window=window)
 
 
 def copy_pages(k_cache, v_cache, src, dst):
@@ -959,7 +1018,7 @@ class BlockAllocator:
 
 
 class _RadixNode:
-    __slots__ = ("children", "block", "parent", "key", "last_used")
+    __slots__ = ("children", "block", "parent", "key", "last_used", "depth")
 
     def __init__(self, parent=None, key=None, block=None):
         self.children: Dict[tuple, "_RadixNode"] = {}
@@ -967,6 +1026,7 @@ class _RadixNode:
         self.key = key
         self.block = block
         self.last_used = 0
+        self.depth = 0 if parent is None else parent.depth + 1
 
 
 class RadixPrefixCache:
@@ -990,6 +1050,9 @@ class RadixPrefixCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        # called with a node's block as the node leaves the trie
+        # (PageGroups: the pages the other groups keep for that node)
+        self.on_evict: Optional[Callable[[int], None]] = None
         allocator.is_cached = self.has_block
 
     def __len__(self) -> int:
@@ -997,6 +1060,24 @@ class RadixPrefixCache:
 
     def has_block(self, block: int) -> bool:
         return block in self._by_block
+
+    def chain(self, tokens) -> List[int]:
+        """The blocks of the nodes along ``tokens``' full pages, as far as
+        the trie holds them; counts and touches nothing."""
+        node, out = self.root, []
+        for key in self._chunks(tokens):
+            node = node.children.get(key)
+            if node is None:
+                break
+            out.append(node.block)
+        return out
+
+    def recency(self, block: int) -> tuple:
+        """``(last_used, -depth)`` of the node that holds ``block``: the
+        order in which another group's pages of the trie are given up
+        (least recently used first, the deepest of a path first)."""
+        node = self._by_block[block]
+        return node.last_used, -node.depth
 
     def _chunks(self, tokens) -> List[tuple]:
         p = self.page_size
@@ -1057,10 +1138,350 @@ class RadixPrefixCache:
             victim = min(victims, key=lambda nd: nd.last_used)
             victim.parent.children.pop(victim.key)
             del self._by_block[victim.block]
+            if self.on_evict is not None:
+                self.on_evict(victim.block)
             self.allocator.free_cached(victim.block)
             self.evictions += 1
             freed += 1
         return freed
+
+
+class PageGroup:
+    """The pages of one layer KIND: its window (None: the whole history),
+    its own page count, allocator and parking page."""
+
+    __slots__ = ("kind", "window", "num_blocks", "alloc", "park",
+                 "slot_pages")
+
+    def __init__(self, kind: str, window: Optional[int], num_blocks: int,
+                 slot_pages: int):
+        self.kind = kind
+        self.window = None if window is None else int(window)
+        self.num_blocks = int(num_blocks)
+        self.alloc = BlockAllocator(self.num_blocks)
+        self.park = self.num_blocks        # the page after the allocator's
+        self.slot_pages = int(slot_pages)  # the most a sequence holds
+
+    @property
+    def in_use(self) -> int:
+        """Pages mapped by a live sequence (held and cached-idle pages are
+        not in use)."""
+        return len(self.alloc._ref)
+
+
+def group_table(tables, g: int):
+    """Group ``g``'s part of what ``PageGroups`` hands a program: with one
+    group the one array, otherwise a sequence of one a group."""
+    return tables[g] if isinstance(tables, (tuple, list)) else tables
+
+
+class PageGroups:
+    """The page groups of an engine with a prefix cache, one a layer kind
+    that keeps K and V (docs/SERVING.md "Window and full layers: a page
+    group a kind"): the ONE place that knows a model has more than one.
+
+    A model declares ``kv_groups() -> [(kind, window), ...]``, the full
+    group (window None) first; a model without the method has the one full
+    group, and every method here then does what the engine did before
+    there were groups. Group 0 is sized by ``max_len``:
+    ``max_batch * ceil(max_len / page) + extra_blocks`` pages. A window
+    group never is: ``max_batch * slot_pages`` plus its share of
+    ``extra_blocks``, with ``slot_pages = ceil((window + advance) / page)
+    + 1``, where ``advance`` is the most positions one program moves a
+    sequence: ``slot_rows`` rows of a packed call, and no less than a chunk
+    or a decode block. The rule is a trade between two costs a row more
+    brings: a slot mid-prefill decodes nothing, and it stays mid-prefill
+    ``prompt / (slot_rows * chunk)`` packed calls; every row costs each
+    slot ``chunk / page`` pages of every window pool, held whether a
+    prompt is being written or not. ``slot_rows`` is what a quarter of the
+    (least) window holds in whole chunks: the pool is then at most a
+    quarter larger than the window's own pages, and a prompt of ``n``
+    windows is ``4 n`` calls away from its first token, not ``window /
+    chunk`` times ``n``. (One row a slot and four were both read on the
+    chip at one traffic mix: PERF.md section 6, PR 40.)
+
+    Tables stay indexed by absolute page (``position // page``) in every
+    group, so the appends, copy-on-write and the chunk's gather address
+    alike. A sequence's window-group row is filled as it goes: ``reserve``
+    maps fresh pages ahead of the next program, ``release_behind`` gives
+    back (decref) the pages that lie wholly behind ``pos - window`` after
+    it; their entries stay in the row, stale, because no reader looks
+    behind the window. ``slot_pages`` is what a sequence holds between the
+    two at most, so with every slot busy ``reserve`` still finds pages
+    (cached-idle ones are evicted for it).
+
+    The trie stays the full group's (``RadixPrefixCache`` over its
+    allocator, a node a page); what a window group keeps for a node hangs
+    beside it by the node's block (``_side``). A window group's cached
+    pages are given up on their own, least recently used first and the
+    deepest of a path first, so a chain may lack them in the middle: a hit
+    of ``n`` pages is honoured where the window group still covers pages
+    ``[max(0, n * page - window) // page, n)`` (``honour``)."""
+
+    def __init__(self, decl, *, max_batch: int, max_len: int, page_size: int,
+                 chunk: int, block: int, extra_blocks: int = 0):
+        decl = [(str(k), None if w is None else int(w)) for k, w in decl]
+        if not decl or decl[0][1] is not None or any(
+                w is None or w < 1 for _, w in decl[1:]):
+            raise ValueError(
+                f"kv_groups must name the full group (window None) first "
+                f"and a window for every other: {decl}")
+        self.page = int(page_size)
+        self.maxp = -(-int(max_len) // self.page)
+        windows = [w for _, w in decl[1:]]
+        self.slot_rows = max(1, min(windows) // 4 // int(chunk)) \
+            if windows else None
+        self.advance = max(int(chunk) * (self.slot_rows or 1), int(block))
+        extra = max(0, int(extra_blocks))
+        self.groups: List[PageGroup] = []
+        for kind, window in decl:
+            if window is None:
+                per, n = self.maxp, max_batch * self.maxp + extra
+            else:
+                per = min(self.maxp,
+                          -(-(window + self.advance) // self.page) + 1)
+                n = max_batch * per + -(-extra * per // self.maxp)
+            self.groups.append(PageGroup(kind, window, n, per))
+        self.full = self.groups[0]
+        self.windowed = self.groups[1:]
+        self.radix = RadixPrefixCache(self.page, self.full.alloc)
+        # per window group: full block of a trie node -> the group's block
+        # for that node, and back; per slot: absolute page -> block held,
+        # the table row, and the pages reserved from page 0 on
+        self._side = [dict() for _ in self.windowed]
+        self._back = [dict() for _ in self.windowed]
+        self._held = [[None] * max_batch for _ in self.windowed]
+        self._rows = [[None] * max_batch for _ in self.windowed]
+        self._front = [[0] * max_batch for _ in self.windowed]
+        self.released = 0          # window pages given back by a live slot
+        self.allocated = 0         # window pages mapped fresh
+        self.shortened = 0         # hits honoured shorter than matched
+        for gi, g in enumerate(self.windowed):
+            g.alloc.is_cached = self._back[gi].__contains__
+        if self.windowed:
+            self.radix.on_evict = self._node_left
+
+    # -- what the engine builds from ---------------------------------------
+    @property
+    def single(self) -> bool:
+        return not self.windowed
+
+    def pool_pages(self) -> List[int]:
+        """Pages each group's pools are asked for: the allocator's and the
+        parking page."""
+        return [g.num_blocks + 1 for g in self.groups]
+
+    def parts(self, per_group):
+        """One value a group as the programs take them: the value itself
+        with one group (what the engine kept before there were groups), a
+        tuple otherwise. With ``group_table``, its inverse, the only place
+        that tells the two apart."""
+        return per_group[0] if self.single else tuple(per_group)
+
+    def _each(self, fn, *parts):
+        """``parts`` of ``fn(group, *the group's part of each argument)``."""
+        return self.parts([fn(g, *(group_table(p, i) for p in parts))
+                           for i, g in enumerate(self.groups)])
+
+    def parked(self, n: Optional[int] = None):
+        """Table rows that map every page to the parking page: ``[maxp]``
+        (``n`` None) or ``[n, maxp]`` int32."""
+        shape = (self.maxp,) if n is None else (n, self.maxp)
+        return self._each(lambda g: np.full(shape, g.park, np.int32))
+
+    def put(self, dst, j: int, rows) -> None:
+        """``dst[j] = rows`` for what ``parked(n)`` and ``rows`` return."""
+        self._each(lambda g, d, r: d.__setitem__(j, r), dst, rows)
+
+    def rows(self, slot: int, full_row):
+        """A slot's table rows: the full group's as the engine keeps it and
+        each window group's as reserved so far."""
+        return self.parts([full_row] + [r[slot].copy() for r in self._rows])
+
+    def prompt_rows(self, rows, n_real: int):
+        """``rows`` with everything past the prompt's ``n_real`` pages
+        parked (the engine's ``_prefill_row``)."""
+        def cut(g, row):
+            out = np.full(self.maxp, g.park, np.int32)
+            out[:n_real] = row[:n_real]
+            return out
+
+        return self._each(cut, rows)
+
+    def copy_pages(self, kv, layer_groups, src, dst):
+        """``copy_layer_pages`` over every layer, each with its group's part
+        of ``src`` / ``dst`` (``parts`` of one [width] vector a group)."""
+        return [copy_layer_pages(e, group_table(src, g), group_table(dst, g))
+                for e, g in zip(kv, layer_groups)]
+
+    # -- the hit rule -------------------------------------------------------
+    def _first_seen(self, g: PageGroup, n_pages: int) -> int:
+        """The first page a query at position ``n_pages * page`` reads in a
+        window group."""
+        return max(0, n_pages * self.page - g.window) // self.page
+
+    def honour(self, matched: List[int]) -> List[int]:
+        """The longest head of a matched chain that every window group
+        still covers where the request will read it."""
+        n = len(matched)
+        for gi, g in enumerate(self.windowed):
+            side = self._side[gi]
+            gap, last_gap = [], -1         # last page < i that g lacks
+            for b in matched[:n]:
+                gap.append(last_gap)
+                if b not in side:
+                    last_gap = len(gap) - 1
+            gap.append(last_gap)
+            while n and gap[n] >= self._first_seen(g, n):
+                n -= 1
+        if n < len(matched):
+            self.shortened += 1
+        return matched[:n]
+
+    # -- a slot's window pages ---------------------------------------------
+    def _evict(self, gi: int, n: int) -> int:
+        """Give up to ``n`` of window group ``gi``'s cached-idle pages back
+        to its free list."""
+        g, back = self.windowed[gi], self._back[gi]
+        idle = [b for b in back if g.alloc.refcount(b) == 0]
+        idle.sort(key=lambda b: self.radix.recency(back[b]))
+        for b in idle[:n]:
+            del self._side[gi][back.pop(b)]
+            g.alloc.free_cached(b)
+        return min(n, len(idle))
+
+    def _node_left(self, full_block: int) -> None:
+        """The trie gave a node up: what the window groups kept for it goes
+        with it (a page a live sequence still maps goes when that lets it
+        go)."""
+        for gi, g in enumerate(self.windowed):
+            b = self._side[gi].pop(full_block, None)
+            if b is not None:
+                del self._back[gi][b]
+                if g.alloc.refcount(b) == 0:
+                    g.alloc.free_cached(b)
+
+    def admit(self, slot: int, matched: List[int], cow_src: Optional[int],
+              upto: int):
+        """Map a newly admitted slot's window pages: the honoured chain's
+        (shared), a private copy of ``cow_src``'s where the whole prompt
+        hit, and fresh ones for positions below ``upto``. Returns the
+        ``(src, dst)`` page copies the window groups need, one list a
+        group, or None where a group is short of pages (nothing is kept:
+        the admission defers)."""
+        n = len(matched)
+        copies = []
+        for gi, g in enumerate(self.windowed):
+            side = self._side[gi]
+            lo = self._first_seen(g, n + (cow_src is not None))
+            held = {i: side[matched[i]] for i in range(min(lo, n), n)}
+            pinned = list(held.values())
+            if cow_src is not None:
+                pinned.append(side[cow_src])
+            g.alloc.incref(pinned)
+            self._held[gi][slot] = held
+            row = np.full(self.maxp, g.park, np.int32)
+            for i, b in held.items():
+                row[i] = b
+            self._rows[gi][slot] = row
+            self._front[gi][slot] = n
+            if not self._reserve(gi, slot, upto):
+                g.alloc.decref(pinned)
+                self._held[gi][slot] = self._rows[gi][slot] = None
+                for gj in range(gi):
+                    self._drop(gj, slot, copies[gj])
+                return None
+            copies.append([] if cow_src is None else
+                          [(side[cow_src], self._held[gi][slot][n])])
+        return copies
+
+    def _drop(self, gi: int, slot: int, copies=()) -> None:
+        g = self.windowed[gi]
+        g.alloc.decref(list(self._held[gi][slot].values())
+                       + [s for s, _ in copies])
+        self._held[gi][slot] = self._rows[gi][slot] = None
+        self._front[gi][slot] = 0
+
+    def _reserve(self, gi: int, slot: int, upto: int) -> bool:
+        g = self.windowed[gi]
+        front = self._front[gi][slot]
+        need = min(-(-int(upto) // self.page), self.maxp) - front
+        if need <= 0:
+            return True
+        fresh = g.alloc.alloc(need, evict=lambda k: self._evict(gi, k))
+        if fresh is None:
+            return False
+        held, row = self._held[gi][slot], self._rows[gi][slot]
+        for i, b in enumerate(fresh, front):
+            held[i] = row[i] = b
+        self._front[gi][slot] = front + need
+        self.allocated += need
+        return True
+
+    def reserve(self, slot: int, upto: int) -> Optional[bool]:
+        """Map fresh window pages for the slot's positions below ``upto``.
+        True: rows changed; False: nothing to do; None: a group is short of
+        pages (what the earlier groups got stays theirs)."""
+        changed = False
+        for gi in range(len(self.windowed)):
+            before = self._front[gi][slot]
+            if not self._reserve(gi, slot, upto):
+                return None
+            changed |= self._front[gi][slot] != before
+        return changed
+
+    def reserved(self, slot: int) -> int:
+        """Positions from 0 on that every window group has a page for."""
+        return min((f[slot] for f in self._front), default=self.maxp) \
+            * self.page
+
+    def release_behind(self, slot: int, pos: int) -> int:
+        """Give back the slot's window pages that no query at ``pos`` or
+        later reads: those wholly below ``pos - window + 1``. Their row
+        entries stay, stale."""
+        n = 0
+        for gi, g in enumerate(self.windowed):
+            held = self._held[gi][slot]
+            first = max(0, int(pos) - g.window + 1) // self.page
+            gone = [i for i in held if i < first]
+            if gone:
+                g.alloc.decref([held.pop(i) for i in gone])
+                n += len(gone)
+        self.released += n
+        return n
+
+    def release(self, slot: int) -> None:
+        """The slot is done: every window page it still maps."""
+        for gi in range(len(self.windowed)):
+            if self._held[gi][slot] is not None:
+                self._drop(gi, slot)
+
+    def written(self, slot: int, tokens, full_blocks) -> None:
+        """Register the pages of ``tokens`` (whole pages of a prompt, all
+        written) in the trie, each group's page under the node: the full
+        group's first writer wins as before, and a node that lacks a window
+        group's page takes this slot's."""
+        self.radix.insert(tokens, full_blocks)
+        if self.single:
+            return
+        chain = self.radix.chain(tokens)
+        for gi in range(len(self.windowed)):
+            held, side, back = (self._held[gi][slot], self._side[gi],
+                                self._back[gi])
+            for i, node_block in enumerate(chain):
+                b = held.get(i)
+                if b is not None and node_block not in side \
+                        and b not in back:
+                    side[node_block] = b
+                    back[b] = node_block
+
+    def shortfall(self, need_full: int) -> Optional[str]:
+        """Why no admission can ever serve a request of ``need_full`` pages,
+        or None."""
+        if need_full > self.full.num_blocks:
+            return (f"request needs {need_full} KV blocks but the pool holds "
+                    f"{self.full.num_blocks}")
+        return None
 
 
 # ---------------------------------------------------------------------------

@@ -536,7 +536,14 @@ def test_steps_over_the_cut_are_counted_with_what_held_them(model, monkeypatch):
 
 def test_a_program_call_costs_microseconds_without_a_session(model):
     """What the ledger and ``pt.serve.call`` add to a program call with no
-    profiler session and no recorder: under 10 us (about 4 on this CPU)."""
+    profiler session and no recorder: microseconds (about 4 on this CPU).
+    Counted on the calling thread's CPU clock: under six test workers the
+    wall clock also counts the time the thread waits for a core (19.7 us a
+    call read so where the CPU clock keeps to 3.5-3.8 with ten busy
+    processes beside it), and that is no cost of the ledger's. Judged
+    beside a bare call's cost in the same process, the same loop around
+    the program's lookup and call without the ledger: under 10 us, or
+    under 60 bare calls (some 0.13 us each) where the machine is slower."""
     import time
 
     import jax.numpy as jnp
@@ -544,15 +551,24 @@ def test_a_program_call_costs_microseconds_without_a_session(model):
     cfg, m = model
     eng = _engine(m)
     x = jnp.zeros(4).block_until_ready()
-    eng._built[("pt_probe", (4, True))] = "4/True"
+    key = ("pt_probe", (4, True))
+    eng._built[key] = "4/True"
+    fn = lambda: x
 
-    def loop(n=2000):
-        t = time.perf_counter()
+    def bare_call(program, k, f):
+        eng._built.get((program, k))
+        return f()
+
+    def loop(call, n=2000):
+        t = time.thread_time()
         for _ in range(n):
-            eng._call_built("pt_probe", (4, True), lambda: x)
-        return (time.perf_counter() - t) / n
+            call("pt_probe", (4, True), fn)
+        return (time.thread_time() - t) / n
 
-    assert min(loop() for _ in range(5)) < 10e-6
+    # alternate, so that a burst of load falls on both
+    pairs = [(loop(eng._call_built), loop(bare_call)) for _ in range(5)]
+    cost, bare = (min(p[i] for p in pairs) for i in (0, 1))
+    assert cost < max(10e-6, 60 * bare), (cost, bare)
     assert eng._flight.called == 10000
 
 
