@@ -132,7 +132,8 @@ def _greedy(logits):
 # here as the serving-facing API surface
 from ..ops.paged_attention import (BlockAllocator, LayerStateError,
                                    PageGroups, RadixPrefixCache,
-                                   kernel_layers, layer_kinds,
+                                   chunk_kernel_layers, kernel_layers,
+                                   layer_kinds,
                                    page_append_layers, pool_num_pages,
                                    state_bytes)
 
@@ -1055,6 +1056,13 @@ class ContinuousBatchingEngine:
                           page_append_layers(self.caches["kv"],
                                              self._chunk_tokens)
                           if prefix_cache is not None else 0),
+                      # and those whose chunk form runs pt_paged_chunk
+                      # (ops.paged_attention._chunk_kernel_takes): none off
+                      # the TPU, for an int8 pool or where no chunk is packed
+                      "chunk_kernel_layers": (
+                          chunk_kernel_layers(self.caches["kv"],
+                                              self._chunk_tokens)
+                          if prefix_cache is not None else 0),
                       "compile_cache_entries": 0, "shed": 0,
                       "retry_attempts": 0, "retry_giveups": 0,
                       "fused_updates": 0,
@@ -1111,10 +1119,12 @@ class ContinuousBatchingEngine:
                 self.stats[f"kv_pages_in_use.{g.kind}"] = 0
                 # a family of its own: the unlabeled ``paged_kernel_layers``
                 # is the sum, and one family would count the layers twice
+                mine = [e for e, at in zip(self.caches["kv"],
+                                           self._layer_groups) if at == gi]
                 self.stats[f"paged_kernel_layers_by_group.{g.kind}"] = \
-                    kernel_layers([e for e, at in zip(
-                        self.caches["kv"], self._layer_groups)
-                        if at == gi])[0]
+                    kernel_layers(mine)[0]
+                self.stats[f"chunk_kernel_layers_by_group.{g.kind}"] = \
+                    chunk_kernel_layers(mine, self._chunk_tokens)
             self.stats.update(window_pages_released=0,
                               window_pages_allocated=0,
                               window_pages_in_use_steps=0,
@@ -2884,7 +2894,7 @@ class ContinuousBatchingEngine:
 
         Safe by the same absolute-position-masking argument as chunked
         prefill (``ops.paged_prefill_attention``): every row's k/v is
-        appended before any row's attention gathers, and a query attends
+        appended before any row's attention reads, and a query attends
         exactly the keys at positions <= its own — so a later chunk of
         the same prompt reads the earlier chunk's pages written IN THE
         SAME program, bit-identical to running the chunks sequentially.

@@ -270,8 +270,9 @@ class LlamaAttention(Layer):
         (prefix-cache / chunked-prefill serving path). x: [b, s, h] — row b
         holds tokens at absolute positions [starts[b], starts[b]+s);
         cos/sin [b, s, d] gathered per row. The chunk's k/v scatter into the
-        pages first, then attention gathers the FULL table extent with an
-        absolute-position causal mask — see paged_prefill_attention for the
+        pages first, then attention reads each row's own pages up to its
+        last query under an absolute-position causal mask, in key blocks
+        aligned to absolute positions — see paged_prefill_attention for the
         bit-identity-across-chunkings argument. ``page_aligned`` (static):
         every ``starts[b]`` is a multiple of the page, so the append may
         write whole pages (``ops.append_paged_chunk``); the packed prefill

@@ -79,12 +79,13 @@ _ENGINE_GAUGE_KEYS = {"compile_cache_entries", "step_max_s",
                       "step_max_wait_s", "state_snapshot_bytes",
                       "seq_state_bytes",
                       "kv_layers", "paged_kernel_layers",
-                      "page_append_layers",
+                      "page_append_layers", "chunk_kernel_layers",
                       # the page groups (docs/SERVING.md "Window and full
                       # layers"): how many, and a group's pool, pages in
                       # use and kernel layers under a ``group`` label
                       "kv_groups", "kv_pool_pages", "kv_pages_in_use",
-                      "paged_kernel_layers_by_group"}
+                      "paged_kernel_layers_by_group",
+                      "chunk_kernel_layers_by_group"}
 # stats-dict keys NOT exported from engine.stats: "evictions" is a lagging
 # copy of radix.evictions (synced only at admit/brownout time) and the
 # collector already exports the live value as pt_radix_evictions_total —

@@ -35,6 +35,22 @@ chat-batch cell's shapes, 80% of the time its bytes take at 819 GB/s):
     size, KV heads, head_dim, the pool's dtype, pages a row, a VMEM budget.
   - all heads of a chunk are two batched dots, `q.K^T` and `p.V`, with
     `group` query rows a KV head.
+
+Chunk kernel design (`pt_paged_chunk`, the packed prefill chunk's attention;
+PERF.md section 6, PR 42): the decode kernel's walk with `s` queries a row.
+  - grid (rows,); a row's query block is `[kv_heads, group * s, d]`, its
+    walk the pages from that of `start - window + 1` (0 on a full layer) to
+    that of its last query, fetched a block ahead into the same double
+    buffer with the chain carried from row to row. Nothing past a row's
+    last query or behind its window is fetched.
+  - key blocks are aligned to ABSOLUTE positions, block `j` holding
+    `[j * CT, (j + 1) * CT)`, and a block that is wholly masked for a query
+    leaves its running maximum, sum and accumulator bit for bit alone: a
+    query meets the same blocks in the same order however its prompt was
+    chunked (the warm == cold guarantee, `paged_prefill_attention`).
+  - the KV heads are looped inside a block; the running maximum, sum and
+    accumulator of every query row live in VMEM scratch; a block every
+    query of the row sees whole takes a path without the mask's passes.
 """
 
 from __future__ import annotations
@@ -85,8 +101,8 @@ class QuantizedKVPool:
     :func:`paged_prefill_attention` / :func:`paged_verify_attention`), so
     attention math stays fp32. Pool bytes drop ~itemsize-fold (bf16 -> int8
     halves them), doubling effective slots and radix prefix-cache reach at
-    equal memory. The Pallas decode kernel does not yet carry the dequant
-    (int8 routes to the XLA reference path — open TPU-kernel work)."""
+    equal memory. The Pallas kernels do not yet carry the dequant (int8
+    routes to the XLA reference paths — open TPU-kernel work)."""
 
     __slots__ = ("data", "scale")
 
@@ -507,7 +523,8 @@ _DECODE_VMEM_BUDGET = 4 << 20
 _DECODE_MAX_CHUNK_TOKENS = 512
 
 
-def _decode_chunk_pages(max_pages, hkv, page, d, itemsize):
+def _decode_chunk_pages(max_pages, hkv, page, d, itemsize,
+                        max_tokens=_DECODE_MAX_CHUNK_TOKENS):
     """Pages in one unit of the decode kernel's work, from what the call can
     see: as many as fit the VMEM budget (every page brings all its KV heads,
     for K and for V, double buffered), no more than
@@ -516,8 +533,17 @@ def _decode_chunk_pages(max_pages, hkv, page, d, itemsize):
     bf16) chunks of 128 / 256 / 512 tokens ran 121 / 107 / 105 us a call."""
     page_bytes = 4 * hkv * page * d * itemsize
     fit = min(_DECODE_VMEM_BUDGET // page_bytes,
-              _DECODE_MAX_CHUNK_TOKENS // page, max_pages)
+              max_tokens // page, max_pages)
     return max(fit, 1)
+
+
+def _page_copies(k_hbm, v_hbm, k_buf, v_buf, sem, slot, pidx, g):
+    """The two DMAs of page ``pidx``, [kv_heads, page, d] each, into place
+    ``g`` of buffer slot ``slot``: K and V on the slot's two semaphores."""
+    return (pltpu.make_async_copy(k_hbm.at[pidx], k_buf.at[slot, :, g],
+                                  sem.at[slot, 0]),
+            pltpu.make_async_copy(v_hbm.at[pidx], v_buf.at[slot, :, g],
+                                  sem.at[slot, 1]))
 
 
 def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -536,11 +562,7 @@ def _paged_decode_kernel(lens_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         n = jnp.minimum((lens_ref[row] + page - 1) // page, max_pages)
         return n if window is None else n - first_page(row)
 
-    def copies(slot, pidx, g):
-        return (pltpu.make_async_copy(k_hbm.at[pidx], k_buf.at[slot, :, g],
-                                      sem.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[pidx], v_buf.at[slot, :, g],
-                                      sem.at[slot, 1]))
+    copies = functools.partial(_page_copies, k_hbm, v_hbm, k_buf, v_buf, sem)
 
     def start_chunk(slot, row, c, n):
         """Fetch the first ``n`` pages of chunk ``c`` of ``row``: one DMA a
@@ -784,39 +806,14 @@ def window_chunk_pages(window: int, s: int, page: int, max_pages: int) -> int:
     return min(-(-(window + s) // page) + 1, max_pages)
 
 
-def paged_prefill_attention(q, k_cache, v_cache, block_tables, chunk_starts,
+def paged_prefill_reference(q, k_cache, v_cache, block_tables, chunk_starts,
                             scale=None, window: Optional[int] = None):
-    """Attention for a prefill CHUNK whose rows sit at per-row absolute
-    offsets inside already-partially-filled paged caches.
-
-    q: [b, s, hq, d] — queries for tokens at absolute positions
-    ``chunk_starts[b] + i`` (i in [0, s)); the chunk's own k/v must already
-    be appended into the pages (append-then-gather, so within-chunk keys and
-    the cached prefix are read through ONE code path). Returns [b, s, hq, d].
-
-    Keys are gathered densely from the block table (full ``max_pages*page``
-    extent) and masked by absolute position: query at position p attends
-    keys at positions <= p. The mask depends only on ABSOLUTE positions and
-    the gathered extent is fixed per engine, so GIVEN the same cached k/v
-    bytes a token's output is bit-identical no matter how the prompt is
-    chunked or how much of it came from the prefix cache — the property the
-    serving engine's warm==cold token-equality guarantee rests on (the
-    engine's module docstring scopes what "same bytes" means at re-stepped
-    block-final positions). Rows are independent, so several rows may SHARE
-    one sequence's block table at different ``chunk_starts`` — the fused
-    engine's prompt-packing prefill flattens (slot, chunk) pairs into the
-    rows of one call; because every row's k/v is appended before any row's
-    gather, a later chunk reads an earlier chunk's pages written in the
-    same program, bit-identical to sequential chunk calls.
-    Stays an XLA gather+einsum (no Pallas
-    kernel): prefill is projection/MLP-bound at serving chunk sizes and this
-    runs once per admitted chunk, unlike the per-token decode kernel.
-
-    ``window`` (static; None: the whole history): query ``p`` sees keys
-    ``p - window < j <= p``, and a row gathers and scores only the
-    ``window_chunk_pages`` pages its chunk can see, starting at the page of
-    ``start - window + 1``, not the table's extent: the entries behind it
-    may be stale."""
+    """:func:`paged_prefill_attention` as a dense gather and one float32
+    softmax (tests, the CPU, int8 pools, whatever Mosaic cannot slice): a
+    full layer gathers and scores the table's whole ``max_pages * page``
+    extent for every row, a window layer the ``window_chunk_pages`` pages
+    from the page of ``start - window + 1``; the mask is by absolute
+    position."""
     b, s, hq, d = q.shape
     page = k_cache.shape[2]
     hkv = k_cache.shape[1] * _pool_fold(k_cache, d)
@@ -858,6 +855,304 @@ def paged_prefill_attention(q, k_cache, v_cache, block_tables, chunk_starts,
     return out.astype(q.dtype)
 
 
+#: the longest block of keys the chunk kernel scores at once (a function of
+#: nothing a call's ``s`` or row count changes: the blocks are part of what
+#: a query's bits depend on; on the v5e blocks of 256 / 512 keys ran 1.62 /
+#: 1.33 ms a call on trinity's full layer and 1.08 / 0.96 on its window
+#: layer: PERF.md section 6, PR 42), the query rows of a KV head scored at
+#: once (what bounds the float32 scores in VMEM: 4 MB a tile), the longest
+#: chunk the kernel takes and the VMEM it may take (32 query heads x 512
+#: queries hold 42 MB of it: the row's q, output, accumulator, maximum, sum)
+_CHUNK_MAX_BLOCK_TOKENS = 512
+_CHUNK_QUERY_TILE = 2048
+_CHUNK_MAX_QUERIES = 512
+_CHUNK_VMEM_LIMIT = 64 << 20
+
+
+def _paged_chunk_kernel(starts_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
+                        k_buf, v_buf, sem, slot_ref, m_ref, l_ref, acc_ref,
+                        *, page, C, max_pages, scale, batch, s, window):
+    """One grid step a chunk row: ``s`` queries at positions ``start + i``,
+    ``R = group * s`` query rows a KV head (row ``r`` is query ``r % s``),
+    against the row's own pages, block ``j`` of the walk holding the
+    ABSOLUTE positions ``[j * CT, (j + 1) * CT)``."""
+    bi = pl.program_id(0)
+    hkv, R, w = q_ref.shape[1:]
+    CT = C * page
+
+    def span(row):
+        """``(first, end)``: the pages ``[first, end)`` a row's walk reads,
+        from the page of ``start - window + 1`` (0 on a full layer) to the
+        page of its last query."""
+        start = starts_ref[row]
+        end = jnp.minimum((start + s + page - 1) // page, max_pages)
+        if window is None:
+            return 0, end
+        return jnp.maximum(start - (window - 1), 0) // page, end
+
+    copies = functools.partial(_page_copies, k_hbm, v_hbm, k_buf, v_buf, sem)
+
+    def block_pages(row, j, on=True):
+        """The pages of ``row``'s walk that lie in block ``j`` (none where
+        ``on`` is false)."""
+        first, end = span(row)
+        lo = jnp.maximum(j * C, first)
+        return lo, jnp.where(on, jnp.maximum(jnp.minimum((j + 1) * C, end),
+                                             lo), lo)
+
+    def start_block(slot, row, j, on=True):
+        def body(pg, _):
+            pidx = jnp.maximum(tables_ref[row * max_pages + pg], 0)
+            for cp in copies(slot, pidx, pg - j * C):
+                cp.start()
+            return 0
+        jax.lax.fori_loop(*block_pages(row, j, on), body, 0)
+
+    def wait_block(slot, row, j):
+        def body(pg, _):
+            for cp in copies(slot, 0, pg - j * C):
+                cp.wait()
+            return 0
+        jax.lax.fori_loop(*block_pages(row, j), body, 0)
+
+    def first_block(row):
+        return span(row)[0] // C
+
+    @pl.when(bi == 0)
+    def _():
+        # as the decode kernel: nobody fetched for the first row, and what a
+        # block's buffer holds beside the pages fetched meets p == 0, which
+        # makes 0 only of a finite number
+        slot_ref[0] = 0
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        start_block(0, 0, first_block(0))
+
+    start = starts_ref[bi]
+    j0 = first_block(bi)
+    j1 = (span(bi)[1] + C - 1) // C                 # one past the last block
+    slot0 = slot_ref[0]
+    nxt_row = jnp.minimum(bi + 1, batch - 1)
+    nxt_j0 = first_block(nxt_row)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    q_pos = start + jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0), s)
+
+    def heads(slot, key0, masked):
+        if masked:
+            key = key0 + jax.lax.broadcasted_iota(jnp.int32, (1, CT), 1)
+        for h in range(hkv):
+            k = k_buf[slot, h].reshape(CT, w)
+            v = v_buf[slot, h].reshape(CT, w).astype(jnp.float32)
+            for t in range(0, R, _CHUNK_QUERY_TILE):
+                tile(h, slice(t, min(t + _CHUNK_QUERY_TILE, R)), k, v,
+                     key if masked else None)
+
+    def tile(h, rows, k, v, key):
+        """A tile of a head's query rows against one block of keys; ``key``
+        the keys' positions on the path that masks, else None."""
+        # bf16 q and K go to the MXU as stored (float32 accumulation);
+        # anything else is computed from float32. p.V from float32 p and V,
+        # as in the decode kernel
+        q = q_ref[0, h, rows]                               # [TQ, w]
+        if not q.dtype == k.dtype == jnp.bfloat16:
+            q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        if key is not None:
+            keep = key <= q_pos[rows]                       # [TQ, CT]
+            if window is not None:
+                keep &= key > q_pos[rows] - window
+            sc = jnp.where(keep, sc, NEG_INF)
+        # the running maximum is of the scores BEFORE the scale (which is
+        # positive), and the scale multiplies the difference: a visible key
+        # then goes through the same subtract, multiply and exp on the
+        # masked and on the unmasked path, with no product beside a sum for
+        # a compiler to contract on one of them alone
+        m_prev = m_ref[h, rows]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp((sc - m_new) * scale)
+        if key is not None:
+            # a block that is wholly masked for a query row leaves that
+            # row's m, l and accumulator bit for bit alone: m_new is m_prev,
+            # alpha 1 and p 0 (exp(NEG_INF - NEG_INF) is 1)
+            p = jnp.where(keep, p, 0.0)
+        alpha = jnp.exp((m_prev - m_new) * scale)
+        l_ref[h, rows] = (l_ref[h, rows] * alpha
+                          + jnp.sum(p, axis=-1, keepdims=True))
+        acc_ref[h, rows] = acc_ref[h, rows] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h, rows] = m_new
+
+    def block_step(j, _):
+        slot = (slot0 + j - j0) % 2
+        # fetch what is computed next while this block computes: the row's
+        # next block, or after its last one the next row's first
+        last = j + 1 == j1
+        start_block(1 - slot, jnp.where(last, nxt_row, bi),
+                    jnp.where(last, nxt_j0, j + 1),
+                    jnp.logical_or(jnp.logical_not(last), bi + 1 < batch))
+        wait_block(slot, bi, j)
+        key0 = j * CT
+        # every key of the block seen by every query of the row: no mask
+        inner = key0 + CT - 1 <= start
+        if window is not None:
+            inner &= key0 > start + s - 1 - window
+        pl.when(inner)(lambda: heads(slot, key0, False))
+        pl.when(jnp.logical_not(inner))(lambda: heads(slot, key0, True))
+        return 0
+
+    jax.lax.fori_loop(j0, j1, block_step, 0)
+    slot_ref[0] = (slot0 + j1 - j0) % 2
+    for h in range(hkv):
+        l = l_ref[h]
+        o_ref[0, h] = (acc_ref[h] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_call(b, hkv, R, w, s, page, C, max_pages, scale, q_dtype, k_dtype,
+                v_dtype, interpret, window):
+    """The ``pallas_call`` of one shape class, made once (``_decode_call``)."""
+    kernel = functools.partial(
+        _paged_chunk_kernel, page=page, C=C, max_pages=max_pages, scale=scale,
+        batch=b, s=s, window=window)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, hkv, R, w), lambda bi, *_: (bi, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, hkv, R, w), lambda bi, *_: (bi, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, hkv, C, page, w), k_dtype),
+            pltpu.VMEM((2, hkv, C, page, w), v_dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            # the online softmax of a row: running maximum, sum, accumulator
+            pltpu.VMEM((hkv, R, 1), jnp.float32),
+            pltpu.VMEM((hkv, R, 1), jnp.float32),
+            pltpu.VMEM((hkv, R, w), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        name="pt_paged_chunk",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, R, w), q_dtype),
+        # "arbitrary": the prefetch chain runs from row to row
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_CHUNK_VMEM_LIMIT),
+        interpret=interpret)
+
+
+def _chunk_kernel_takes(pool, s: int) -> bool:
+    """Whether ``pt_paged_chunk`` serves a chunk of ``s`` queries a row over
+    this pool: a pool the decode kernel reads (``_kernel_takes``) and an
+    ``s`` of whole sublane tiles (a speculative verify window of ``K + 1``
+    positions goes to the reference) whose row state fits the VMEM."""
+    return (_kernel_takes(pool) and s % 8 == 0
+            and s <= _CHUNK_MAX_QUERIES)
+
+
+def chunk_kernel_layers(kv, chunk_tokens: int) -> int:
+    """Layers that keep K and V whose chunk form runs ``pt_paged_chunk`` at
+    ``chunk_tokens`` queries a row: none off the TPU."""
+    if jax.default_backend() != "tpu":
+        return 0
+    return sum(_chunk_kernel_takes(e[0], chunk_tokens) for e in kv
+               if _kind(e) == "kv")
+
+
+def paged_prefill_attention(q, k_cache, v_cache, block_tables, chunk_starts,
+                            scale=None, window: Optional[int] = None,
+                            interpret: bool = False):
+    """Attention for a prefill CHUNK whose rows sit at per-row absolute
+    offsets inside already-partially-filled paged caches.
+
+    q: [b, s, hq, d] — queries for tokens at absolute positions
+    ``chunk_starts[b] + i`` (i in [0, s)); the chunk's own k/v must already
+    be appended into the pages (append-then-read, so within-chunk keys and
+    the cached prefix are read through ONE code path). Returns [b, s, hq, d].
+
+    A query at position p attends keys at positions <= p, and with a
+    ``window`` (static; None: the whole history) keys ``p - window < j <=
+    p``. On a TPU, for a pool the decode kernel reads and an ``s`` of whole
+    tiles (``_chunk_kernel_takes``), the Pallas kernel ``pt_paged_chunk``
+    walks each row's OWN pages with an online softmax, as ``pt_paged_decode``
+    does for one query: from the page of ``start - window + 1`` (0 on a
+    full layer) to the page of the row's last query, K and V pages fetched
+    by DMA a block ahead, scores kept in VMEM. Nothing past a row's last
+    query is fetched and nothing behind the window: those table entries may
+    be stale. Everything else (the CPU, int8 pools, pools Mosaic cannot
+    slice) runs :func:`paged_prefill_reference`, the dense gather.
+
+    What the serving engine's warm == cold token-equality guarantee rests
+    on: **the kernel's key blocks are aligned to ABSOLUTE positions** (block
+    ``j`` holds positions ``[j * CT, (j + 1) * CT)``, ``CT`` a function of
+    the pool's shape alone), the mask depends on absolute positions only,
+    and a block wholly masked for a query leaves its running maximum, sum
+    and accumulator bit for bit alone. So a query meets the same blocks in
+    the same order however its prompt was chunked and however much of it
+    the prefix cache supplied: GIVEN the same cached k/v bytes its output
+    is bit-identical (the reference has the property for its own reason: one
+    softmax over an extent fixed per engine). The engine's module docstring
+    scopes what "same bytes" means at re-stepped block-final positions.
+    Rows are independent, so several rows may SHARE one sequence's block
+    table at different ``chunk_starts`` — the engine's prompt-packing
+    prefill flattens (slot, chunk) pairs into the rows of one call; because
+    every row's k/v is appended before any row reads, a later chunk reads an
+    earlier chunk's pages written in the same program, bit-identical to
+    sequential chunk calls.
+
+    ``interpret`` reaches the kernel off the TPU (the tests)."""
+    b, s, hq, d = q.shape
+    if scale is None:
+        scale = d ** -0.5
+    # the kernel takes its running maximum before the scale: a positive one
+    if isinstance(k_cache, QuantizedKVPool) or scale <= 0 or (
+            not interpret and (jax.default_backend() != "tpu"
+                               or not _chunk_kernel_takes(k_cache, s))):
+        return paged_prefill_reference(q, k_cache, v_cache, block_tables,
+                                       chunk_starts, scale, window=window)
+    f = _pool_fold(k_cache, d)
+    _, hkv, page, w = k_cache.shape       # hkv head groups of f heads each
+    group = hq // hkv                     # query heads a head group
+    # [b, hkv, f, group // f, s, d]: a head group's query rows, query i of
+    # each head at row r with r % s == i
+    qt = jnp.transpose(q.reshape(b, s, hkv, f, group // f, d),
+                       (0, 2, 3, 4, 1, 5))
+    if f > 1:
+        # lane-dense pools, as the decode kernel reads them: head j*f + i of
+        # group j has its query rows in lanes i*d .. (i+1)*d, zeros beside
+        eye = jnp.eye(f, dtype=q.dtype)[:, None, None, :, None]
+        qt = qt[..., None, :] * eye
+    max_pages = block_tables.shape[1]
+    C = _decode_chunk_pages(max_pages, hkv, page, w,
+                            jnp.dtype(k_cache.dtype).itemsize,
+                            _CHUNK_MAX_BLOCK_TOKENS)
+    call = _chunk_call(b, hkv, group * s, w, s, page, C, max_pages,
+                       float(scale), jnp.dtype(q.dtype),
+                       jnp.dtype(k_cache.dtype), jnp.dtype(v_cache.dtype),
+                       interpret, None if window is None else int(window))
+    # the kernel's name reaches the HLO instruction and the scope its name
+    # stack: traces find the kernel by name, not by a shape
+    with jax.named_scope("pt_paged_chunk"):
+        out = call(chunk_starts, block_tables.reshape(-1),
+                   qt.reshape(b, hkv, group * s, w), k_cache, v_cache)
+    # of a head's rows, the lanes that hold its own V
+    out = out.reshape(b, hkv, f, group // f, s, f, d)
+    out = jnp.stack([out[:, :, i, :, :, i] for i in range(f)], axis=2)
+    return jnp.transpose(out, (0, 4, 1, 2, 3, 5)).reshape(b, s, hq, d)
+
+
 def paged_verify_attention(q, k_cache, v_cache, block_tables, row_starts,
                            scale=None, window: Optional[int] = None):
     """Speculative-decode VERIFY attention: score a K+1-token draft window
@@ -866,25 +1161,28 @@ def paged_verify_attention(q, k_cache, v_cache, block_tables, row_starts,
     q: [b, s, hq, d] — queries for the window [last_token, draft_1..draft_K]
     whose rows sit at per-row absolute offsets ``row_starts[b] + i`` inside
     already-partially-filled paged caches. The window's own k/v must
-    already be appended (append-then-gather), exactly the
+    already be appended (append-then-read), exactly the
     :func:`paged_prefill_attention` machinery — which is what this
     delegates to: the absolute-position mask means window position i
     attends the cached prefix plus drafts 0..i, so the logits at position
-    i are IDENTICAL (same gather extent, same masked softmax) to what a
-    sequential ``paged_token_step`` at that position would compute given
-    the same cache bytes — the greedy byte-identity guarantee of
-    speculative decoding rests here. Rejected drafts' appended k/v needs
-    no explicit rollback: positions past the accepted prefix sit beyond
-    the advanced context length, are never attended, and are overwritten
-    as decode proceeds (the engine's standard pad-append invariant).
-    int8 pools dequantize in the gather like every other read path.
+    i are what a sequential ``paged_token_step`` at that position would
+    compute given the same cache bytes (the same keys under the same mask;
+    bit for bit where both run the reference, as on the CPU) — the greedy
+    byte-identity guarantee of speculative decoding rests here. Rejected
+    drafts' appended k/v needs no explicit rollback: positions past the
+    accepted prefix sit beyond the advanced context length, are never
+    attended, and are overwritten as decode proceeds (the engine's standard
+    pad-append invariant). int8 pools dequantize in the gather like every
+    other read path.
 
     NOTE this is a NAMED THIN DELEGATION: the production verify program
     (``paged_verify_step`` -> layer ``paged_prefill_chunk``) dispatches
-    the shared :func:`paged_prefill_attention` body directly — verify and
-    chunk prefill are deliberately ONE implementation, which is what the
-    byte-identity argument above rests on. Behavioral changes belong in
-    that shared body; changing only this wrapper changes tests, not
+    the shared :func:`paged_prefill_attention` directly — verify and chunk
+    prefill are deliberately ONE implementation and ONE dispatch: a window
+    of ``K + 1`` positions is no whole tile of queries, so it runs
+    :func:`paged_prefill_reference` everywhere (``_chunk_kernel_takes``),
+    a chunk of whole tiles the kernel on a TPU. Behavioral changes belong
+    in that shared body; changing only this wrapper changes tests, not
     serving."""
     return paged_prefill_attention(q, k_cache, v_cache, block_tables,
                                    row_starts, scale, window=window)

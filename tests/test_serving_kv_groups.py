@@ -93,6 +93,10 @@ def test_the_window_pool_does_not_depend_on_max_len(model, eng):
     assert s["paged_kernel_layers_by_group.full"] \
         + s["paged_kernel_layers_by_group.sliding"] \
         == s["paged_kernel_layers"]
+    # the chunk kernel serves no layer off the TPU, by group as in all
+    assert s["chunk_kernel_layers_by_group.full"] \
+        == s["chunk_kernel_layers_by_group.sliding"] \
+        == s["chunk_kernel_layers"] == 0
     # the pools: a window layer's is the smaller
     full_pages = eng.caches["kv"][3][0].shape[0]
     window_pages = eng.caches["kv"][0][0].shape[0]

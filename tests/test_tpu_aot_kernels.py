@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from paddle_tpu.ops.paged_attention import (PageState, append_paged_chunk,
                                             kv_pool_shape, page_state_write,
                                             paged_decode_attention,
+                                            paged_prefill_attention,
                                             pool_pages)
 
 
@@ -96,6 +97,54 @@ def test_what_does_not_fold_lowers_to_the_gather(shape, one_chip,
         sds((b, hq, d), dtype), sds(pool, dtype), sds(pool, dtype),
         sds((b, maxp), jnp.int32),
         sds((b,), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in text
+
+
+# (id, rows, queries a row, q heads, kv heads, head_dim, pages a row, window,
+#  dtype): the packed chunk's attention in the four serving cells
+_PAGED_CHUNK_SHAPES = [
+    ("trinity-full-gqa32x4", 16, 128, 32, 4, 128, 512, None, jnp.bfloat16),
+    ("trinity-window-2048", 16, 128, 32, 4, 128, 512, 2048, jnp.bfloat16),
+    ("internlm2-gqa16x8", 16, 128, 16, 8, 128, 128, None, jnp.bfloat16),
+    ("lfm2-gqa32x8-d64", 64, 128, 32, 8, 64, 160, None, jnp.bfloat16),
+    ("nemotron-gqa32x2", 32, 128, 32, 2, 128, 96, None, jnp.bfloat16),
+    # the longest chunk the kernel takes: a head's rows in two tiles
+    ("chunk-of-512", 4, 512, 32, 4, 128, 128, None, jnp.bfloat16),
+    # one row, a chunk of one page of queries, float32 pools
+    ("f32-one-row", 1, 16, 8, 2, 128, 40, 100, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("shape", _PAGED_CHUNK_SHAPES, ids=lambda s: s[0])
+def test_paged_chunk_compiles_for_v5e(shape, one_chip, monkeypatch):
+    _, b, s, hq, hkv, d, maxp, window, dtype = shape
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sds = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    pool = kv_pool_shape(b * maxp // 4 + 17, hkv, 16, d, dtype)
+    assert pool[-1] == 128
+    fn = functools.partial(paged_prefill_attention, window=window)
+    lowered = jax.jit(fn).trace(
+        sds((b, s, hq, d), dtype), sds(pool, dtype), sds(pool, dtype),
+        sds((b, maxp), jnp.int32),
+        sds((b,), jnp.int32)).lower(lowering_platforms=("tpu",))
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1, "one Pallas call, no fallback"
+    assert "pt_paged_chunk" in text
+    # no float32 score tensor over the table's extent is left beside it
+    assert lowered.compile().memory_analysis().temp_size_in_bytes < (
+        b * s * hq * maxp * 16 * 4) // 8
+
+
+def test_a_verify_window_lowers_to_the_reference(one_chip, monkeypatch):
+    """``K + 1`` positions are no whole tile of queries: the one dispatch
+    sends them to the dense gather on a TPU too."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sds = lambda sh, dt: jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+    pool = (64, 8, 16, 128)
+    text = jax.jit(paged_prefill_attention).trace(
+        sds((8, 5, 16, 128), jnp.bfloat16), sds(pool, jnp.bfloat16),
+        sds(pool, jnp.bfloat16), sds((8, 8), jnp.int32),
+        sds((8,), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" not in text
 
 
